@@ -298,5 +298,7 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for row in run()[0]:
         print(row)

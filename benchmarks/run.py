@@ -103,6 +103,8 @@ def main() -> int:
                     help="comma-separated module names to exclude")
     skip = {s.strip() for s in ap.parse_args().skip.split(",") if s.strip()}
     mods = [m for m in mods if m.__name__.rsplit(".", 1)[-1] not in skip]
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for mod in mods:
         try:

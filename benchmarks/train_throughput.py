@@ -62,7 +62,6 @@ from repro.analysis.hlo import collective_bytes
 from repro.distributed.compression import wire_format_for
 from repro.distributed.sharding import diloco_specs, param_specs, \\
     shardings_for
-from repro.launch.dryrun import _mesh_ctx
 from repro.launch.mesh import make_production_mesh
 from repro.models import registry
 from repro.train.diloco import (LINT_BUDGET, DiLoCoConfig, diloco_init,
@@ -82,7 +81,7 @@ wire = wire_format_for(params_sds, pspecs, mesh, dcfg.n_pods,
                        method=compress)
 fn = jax.jit(lambda d: outer_step(d, dcfg, wire=wire),
              in_shardings=(state_sh,), out_shardings=state_sh)
-with _mesh_ctx(mesh):
+with jax.set_mesh(mesh):
     hlo = fn.lower(d_sds).compile().as_text()
 measured = collective_bytes(hlo)["wire_bytes"]
 predicted = outer_wire_bytes(params_sds, compress=compress, wire=wire)
@@ -234,5 +233,7 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for row in run()[0]:
         print(row)

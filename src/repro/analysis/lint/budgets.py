@@ -84,7 +84,6 @@ def _run_outer_sync(spec: BudgetSpec) -> list[Finding]:
 
     from repro.analysis.hlo import collective_bytes
     from repro.distributed.sharding import diloco_specs, param_specs, shardings_for
-    from repro.launch.dryrun import _mesh_ctx
     from repro.launch.mesh import make_production_mesh
     from repro.models import registry
     from repro.train.diloco import (
@@ -131,7 +130,7 @@ def _run_outer_sync(spec: BudgetSpec) -> list[Finding]:
         in_shardings=(state_sh,),
         out_shardings=state_sh,
     )
-    with _mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         hlo_text = fn.lower(d_sds).compile().as_text()
 
     findings = _check_callbacks(spec, hlo_text, "outer_step")

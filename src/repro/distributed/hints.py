@@ -22,11 +22,8 @@ BATCH_AXES = ("pod", "data")
 
 
 def shard_hint(x, spec):
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return x
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     names = set(mesh.axis_names)
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
@@ -54,11 +51,8 @@ def shard_hint(x, spec):
 
 def mesh_axis_size(name: str):
     """Size of a mesh axis in the ambient abstract mesh, or None."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return None
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return None
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     return sizes.get(name)

@@ -9,24 +9,21 @@ tracks *allocated* pages, not max_len: the pool is sized for live tokens
 across the whole batch, and prefix-shared pages appear in several rows'
 tables at once.
 
-Grid = (B, H, max_pages) with the page axis innermost/sequential. kv_lens
-and the page table ride in as scalar-prefetch operands
-(`PrefetchScalarGridSpec`), so the k/v index_map resolves the physical page
-id *before* the DMA is issued — the pool is streamed through the same
-online-softmax VMEM scratch as the dense kernel. `pl.when` skips pages past
+Grid = (B, max_pages) with the page axis innermost/sequential; each step
+takes one (page_size, Hkv, dh) page of K and V, all KV heads at once, as
+the dense kernel takes a cache block. kv_lens and the page table ride in as
+scalar-prefetch operands (`PrefetchScalarGridSpec`), so the k/v index_map
+resolves the physical page id *before* the DMA is issued — the pool is
+streamed through the dense kernel's own body (`kernel.decode_kernel`) and
+VMEM scratch. `pl.when` skips pages past
 a row's kv_len, and because every unmapped entry aliases the one trash
 page, the pipeline's consecutive-identical-block dedup collapses the
 unmapped tail into a single redundant fetch.
 
 Masking is bit-compatible with the dense kernel: scores past kv_len go to
 -1e30 before the exp, so trash-page garbage contributes exact 0.0 to the
-softmax and paged output == dense output bitwise for the same cache
-contents.
-
-Hardware caveat (same as kernel.py): this container only executes interpret
-mode; on real TPU the (1, page_size, 1, dh) block wants page_size >= the
-sublane tile and the scalar-prefetch table in SMEM, which needs validation
-before trusting pool-streaming throughput.
+softmax, and paged output == dense-kernel output (at block_k = page_size)
+bitwise for the same cache contents.
 """
 from __future__ import annotations
 
@@ -37,54 +34,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
+from .kernel import decode_kernel, scratch_shapes
 from .ref import decode_attention_reference
 
-NEG_INF = -1e30
 
-
-def _paged_decode_kernel(lens_ref, ptab_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, page_size: int,
-                         sm_scale: float):
-    bi = pl.program_id(0)
-    pi = pl.program_id(2)
-    npg = pl.num_programs(2)
-
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    kv_len = lens_ref[bi]                  # this row's valid logical prefix
-    k_start = pi * page_size
-
-    @pl.when(k_start < kv_len)             # skip pages past the row's length
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale      # (1, dh)
-        k = k_ref[0, :, 0].astype(jnp.float32)              # (ps, dh)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (1,ps)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, page_size),
-                                                  1)
-        s = jnp.where(kpos < kv_len, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    @pl.when(pi == npg - 1)
-    def _finalize():
-        # kv_len == 0 rows never ran _compute: emit exact zeros, not 0/eps
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, 0] = jnp.where(kv_len > 0, out, 0.0).astype(o_ref.dtype)
+def _paged_decode_kernel(lens_ref, ptab_ref, *refs, **kw):
+    # the page table only steers the k/v index_map (the DMA); the block
+    # arithmetic is the dense kernel's
+    decode_kernel(lens_ref, *refs, **kw)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -98,46 +55,32 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, kv_lens, *,
     ps, hkv = k_pages.shape[1], k_pages.shape[2]
     max_pages = page_table.shape[1]
     assert h % hkv == 0
-    group = h // hkv
-    q4 = q.reshape(b, h, 1, dh)
     kv_lens = jnp.broadcast_to(
         jnp.asarray(kv_lens, jnp.int32).reshape(-1), (b,))
     page_table = page_table.astype(jnp.int32)
 
-    kernel = functools.partial(_paged_decode_kernel, page_size=ps,
-                               sm_scale=dh ** -0.5)
+    kernel = functools.partial(_paged_decode_kernel, block_k=ps, hkv=hkv,
+                               group=h // hkv, sm_scale=dh ** -0.5)
+    page_spec = pl.BlockSpec((1, ps, hkv, dh),
+                             lambda bi, pi, lens, ptab:
+                             (ptab[bi, pi], 0, 0, 0))
+    row_spec = pl.BlockSpec((1, h, dh),
+                            lambda bi, pi, lens, ptab: (bi, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, dh),
-                         lambda bi, hi, pi, lens, ptab: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, ps, 1, dh),
-                         lambda bi, hi, pi, lens, ptab:
-                         (ptab[bi, pi], 0, hi // group, 0)),
-            pl.BlockSpec((1, ps, 1, dh),
-                         lambda bi, hi, pi, lens, ptab:
-                         (ptab[bi, pi], 0, hi // group, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, dh),
-                               lambda bi, hi, pi, lens, ptab:
-                               (bi, hi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, dh), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-        ],
+        grid=(b, max_pages),
+        in_specs=[row_spec, page_spec, page_spec],
+        out_specs=row_spec,
+        scratch_shapes=scratch_shapes(h, dh),
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, 1, dh), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(kv_lens, page_table, q4.reshape(b, h, 1, dh),
-      k_pages.reshape(-1, ps, hkv, dh), v_pages.reshape(-1, ps, hkv, dh))
-    return out.reshape(b, h, dh)
+    )(kv_lens, page_table, q, k_pages, v_pages)
 
 
 def gather_pages(pool, page_table):

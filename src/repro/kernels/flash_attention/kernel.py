@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -121,7 +119,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q,), jnp.float32),      # running max
             pltpu.VMEM((block_q,), jnp.float32),      # running denominator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

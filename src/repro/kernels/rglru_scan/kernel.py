@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+CHUNK = 16      # rows per load/store: one packed bf16 sublane tile
 
 
 def _rglru_kernel(a_ref, x_ref, o_ref, h_ref, *, block_s: int):
@@ -30,14 +30,22 @@ def _rglru_kernel(a_ref, x_ref, o_ref, h_ref, *, block_s: int):
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    def body(i, h):
-        h = (a_ref[0, i, :].astype(jnp.float32) * h
-             + x_ref[0, i, :].astype(jnp.float32))
-        o_ref[0, i, :] = h.astype(o_ref.dtype)
+    # Mosaic loads and stores whole (rows, bd) tiles at aligned row offsets
+    # and lays out 2-D values only: walk the block in CHUNK-row tiles and
+    # run the recurrence over the rows of each tile in registers
+    def chunk(c, h):
+        rows = pl.ds(pl.multiple_of(c * CHUNK, CHUNK), CHUNK)
+        a = a_ref[0, rows, :].astype(jnp.float32)             # (CHUNK, bd)
+        x = x_ref[0, rows, :].astype(jnp.float32)
+        row = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+        out = jnp.zeros_like(a)
+        for r in range(CHUNK):
+            h = a[r:r + 1] * h + x[r:r + 1]                    # (1, bd)
+            out = jnp.where(row == r, h, out)
+        o_ref[0, rows, :] = out.astype(o_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, block_s, body, h_ref[0])
-    h_ref[0] = h
+    h_ref[...] = jax.lax.fori_loop(0, block_s // CHUNK, chunk, h_ref[...])
 
 
 @functools.partial(jax.jit,
@@ -47,7 +55,7 @@ def rglru_scan_fwd(a, x, *, block_s: int = 256, block_d: int = 128,
     """a, x: (B, S, D) -> h: (B, S, D). S % block_s == 0, D % block_d == 0
     (ops.py pads)."""
     b, s, d = x.shape
-    assert s % block_s == 0 and d % block_d == 0
+    assert s % block_s == 0 and d % block_d == 0 and block_s % CHUNK == 0
     grid = (b, d // block_d, s // block_s)
     return pl.pallas_call(
         functools.partial(_rglru_kernel, block_s=block_s),
@@ -62,7 +70,7 @@ def rglru_scan_fwd(a, x, *, block_s: int = 256, block_d: int = 128,
                                lambda bi, di, si: (bi, si, di)),
         out_shape=jax.ShapeDtypeStruct((b, s, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, x)

@@ -43,6 +43,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 from repro.serving import (ConstellationRouter, EngineConfig, Request,
                            ServingEngine, check_forced_outage_contract,
@@ -155,6 +156,7 @@ def build_parser():
 
 def main():
     args = build_parser().parse_args()
+    enable_compile_cache()
     cfg = registry.get_reduced_config(args.arch)
     if registry.input_kind(args.arch) != "tokens":
         raise SystemExit("coserve supports token-LM archs (the serving "

@@ -81,12 +81,6 @@ TRAIN_MICROBATCHES = {
 }
 
 
-def _mesh_ctx(mesh):
-    """jax.set_mesh appeared after 0.4.x; Mesh itself is the context
-    manager on older releases — same axis-env effect for lowering."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-
-
 def _sds(tree, dtype_map=None):
     def conv(x):
         dt = x.dtype
@@ -196,7 +190,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     t0 = time.time()
     fn, args, mesh, meta = build_cell(arch, shape_name, multi_pod, attn_impl,
                                       mesh_shape)
-    with _mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()
@@ -309,7 +303,7 @@ def run_outer_sync_cell(arch: str = "suncatcher-lm-100m",
         in_shardings=(state_sh,), out_shardings=state_sh)
 
     t0 = time.time()
-    with _mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         compiled = fn.lower(d_sds).compile()
         hlo_txt = compiled.as_text()
     dt = time.time() - t0

@@ -11,6 +11,14 @@ XLA_FLAGS before any jax import).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # jax.make_mesh defaults to Explicit axes, under which sharding-in-types
+    # rejects the models' unannotated gathers; every axis here is Auto, so
+    # XLA's partitioner propagates shardings from the jit boundaries
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, shape=None):
@@ -20,13 +28,13 @@ def make_production_mesh(*, multi_pod: bool = False, shape=None):
         shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     assert len(shape) == len(axes)
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(n_devices: int | None = None):
     """Degenerate mesh over whatever devices exist (CPU tests: 1 device)."""
     n = n_devices or len(jax.devices())
-    return jax.make_mesh((1, n, 1), ("pod", "data", "model"))
+    return _auto_mesh((1, n, 1), ("pod", "data", "model"))
 
 
 def mesh_for(kind: str):

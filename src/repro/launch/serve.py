@@ -51,6 +51,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 from repro.serving import (ConstellationRouter, EngineConfig, GridConfig,
                            Request, ServingEngine,
@@ -122,17 +123,37 @@ def build_parser():
     return ap
 
 
-def build_plane(builds, args):
-    """Engine replicas behind a ConstellationRouter: `args.replicas` pods
-    per (cfg, fns, params) build — one arch group each."""
-    ecfg = EngineConfig(max_batch=args.slots, max_len=args.max_len,
+def build_models(archs, full: bool):
+    """(cfg, fns, params) per arch id: the published config with --full,
+    else the reduced one; params are random from PRNGKey(0)."""
+    builds = []
+    for a in archs:
+        cfg = (registry.get_config(a) if full
+               else registry.get_reduced_config(a))
+        fns = registry.model_fns(cfg)
+        builds.append((cfg, fns, fns.init(jax.random.PRNGKey(0), cfg)))
+    return builds
+
+
+def engine_config(args) -> EngineConfig:
+    return EngineConfig(max_batch=args.slots, max_len=args.max_len,
                         decode_block=args.decode_block,
                         page_size=args.page_size,
                         pool_pages=args.pool_pages,
                         prefix_cache=args.prefix_cache)
-    engines = [ServingEngine(cfg, fns, params, ecfg)
-               for cfg, fns, params in builds
-               for _ in range(args.replicas)]
+
+
+def build_plane(builds, args):
+    """Engine replicas behind a ConstellationRouter: `args.replicas` pods
+    per (cfg, fns, params) build — one arch group each. Replica i lives on
+    device i mod the device count, so a four-chip host gives four
+    one-chip replicas."""
+    ecfg = engine_config(args)
+    devices = jax.devices()
+    pods = [b for b in builds for _ in range(args.replicas)]
+    engines = [ServingEngine(cfg, fns, params, ecfg,
+                             device=devices[i % len(devices)])
+               for i, (cfg, fns, params) in enumerate(pods)]
     mask_fn = None
     if args.serving_constellation:
         from repro.core.isl import ConstellationLinkModel, LivenessConfig
@@ -149,6 +170,7 @@ def build_plane(builds, args):
 
 def main():
     args = build_parser().parse_args()
+    enable_compile_cache()
     if args.force_outage_at is not None and args.replicas < 2:
         raise SystemExit("--force-outage-at needs --replicas >= 2 (a "
                          "one-pod group has nowhere to migrate)")
@@ -165,24 +187,12 @@ def main():
         raise SystemExit("a mixed --arch plane needs --replicas >= 2: "
                          "standbys and failover stay inside an arch "
                          "group, so every group needs a second pod")
-    builds = []
-    for a in archs:
-        cfg = (registry.get_config(a) if args.full
-               else registry.get_reduced_config(a))
-        fns = registry.model_fns(cfg)
-        params = fns.init(jax.random.PRNGKey(0), cfg)
-        builds.append((cfg, fns, params))
+    builds = build_models(archs, args.full)
     cfg, fns, params = builds[0]
     if mixed or args.replicas > 1 or args.serving_constellation:
         eng = build_plane(builds, args)
     else:
-        eng = ServingEngine(cfg, fns, params,
-                            EngineConfig(max_batch=args.slots,
-                                         max_len=args.max_len,
-                                         decode_block=args.decode_block,
-                                         page_size=args.page_size,
-                                         pool_pages=args.pool_pages,
-                                         prefix_cache=args.prefix_cache))
+        eng = ServingEngine(cfg, fns, params, engine_config(args))
     rng = np.random.default_rng(0)
     reqs = []
     for uid in range(args.requests):

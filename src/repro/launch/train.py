@@ -25,6 +25,7 @@ import tempfile
 import jax
 
 from repro.core.radiation import RadiationEnvironment, SDCInjector
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import mesh_for
 from repro.models import registry
 from repro.train import (AdamWConfig, DataConfig, DiLoCoConfig,
@@ -90,6 +91,7 @@ def _run_diloco(args, cfg, fns, tcfg, data):
               f"{masked:.0%} pod-rounds masked "
               f"({sup.stats['straggler_pod_rounds']} straggler, "
               f"{sup.stats['outage_pod_rounds']} outage)")
+    return hist
 
 
 def _run_supervised(args, cfg, fns, tcfg, data):
@@ -131,6 +133,7 @@ def _run_supervised(args, cfg, fns, tcfg, data):
     print(f"{cfg.name}: {len(hist)} steps [{mode}], loss "
           f"{hist[0]['loss']:.3f} -> {hist[-1]['loss']:.3f}, "
           f"ft stats {trainer.stats}")
+    return hist
 
 
 def build_parser():
@@ -180,10 +183,8 @@ def build_parser():
     return ap
 
 
-def main():
-    ap = build_parser()
-    args = ap.parse_args()
-
+def setup(args):
+    """(model cfg, model fns, TrainConfig, SyntheticLM) for these flags."""
     cfg = (registry.get_config(args.arch) if args.full
            else registry.get_reduced_config(args.arch))
     fns = registry.model_fns(cfg)
@@ -197,15 +198,27 @@ def main():
         global_batch=args.batch,
         n_codebooks=getattr(cfg, "n_codebooks", 1),
         kind=registry.input_kind(args.arch)))
+    return cfg, fns, tcfg, data
 
+
+def run(args):
+    """Train as the command line says; returns the per-step (or, with
+    --diloco-pods, per-round) history."""
+    cfg, fns, tcfg, data = setup(args)
     if args.diloco_pods > 0:
-        if args.sdc_rate_multiplier:
-            ap.error("--sdc-rate-multiplier needs the host-driven injector "
-                     "and is not supported with --diloco-pods (the DiLoCo "
-                     "round is fully device-resident); drop one of the two")
-        _run_diloco(args, cfg, fns, tcfg, data)
-    else:
-        _run_supervised(args, cfg, fns, tcfg, data)
+        return _run_diloco(args, cfg, fns, tcfg, data)
+    return _run_supervised(args, cfg, fns, tcfg, data)
+
+
+def main():
+    ap = build_parser()
+    args = ap.parse_args()
+    if args.diloco_pods > 0 and args.sdc_rate_multiplier:
+        ap.error("--sdc-rate-multiplier needs the host-driven injector "
+                 "and is not supported with --diloco-pods (the DiLoCo "
+                 "round is fully device-resident); drop one of the two")
+    enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

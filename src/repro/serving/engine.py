@@ -182,10 +182,16 @@ def check_swap_compatible(old_params, new_params):
 
 
 class ServingEngine:
-    def __init__(self, cfg, fns, params, ecfg: EngineConfig):
+    """`device` commits params and all device state to one device (e.g.
+    one replica per chip behind a ConstellationRouter); the jitted steps
+    then run there, and rows imported from another engine are copied
+    onto it first. None leaves placement to JAX's default device."""
+
+    def __init__(self, cfg, fns, params, ecfg: EngineConfig, device=None):
         self.model_cfg = cfg
         self.fns = fns
-        self.params = params
+        self.device = device
+        self.params = self._place(params)
         self.ecfg = ecfg
         spec_fn = getattr(fns, "decode_spec", None) or ds.decode_spec
         self.spec = spec_fn(cfg)
@@ -195,18 +201,19 @@ class ServingEngine:
                 max_batch=ecfg.max_batch, max_len=ecfg.max_len,
                 pool_pages=ecfg.pool_pages,
                 prefix_entries=ecfg.prefix_cache)
-        self.cache = self.spec.init_state(ecfg.max_batch, ecfg.max_len)
+        self.cache = self._place(
+            self.spec.init_state(ecfg.max_batch, ecfg.max_len))
         self._axes = self.spec.batch_axes()
         self._laxes = self.spec.length_axes()
         b = ecfg.max_batch
-        self.state = {
+        self.state = self._place({
             "last": jnp.zeros((b,), jnp.int32),
             "active": jnp.zeros((b,), bool),
             "remaining": jnp.zeros((b,), jnp.int32),
             "temp": jnp.zeros((b,), jnp.float32),
             "eos": jnp.full((b,), -1, jnp.int32),
             "rkey": jnp.zeros((b, 2), jnp.uint32),
-        }
+        })
         self._base_key = jax.random.PRNGKey(ecfg.seed)
         self._next_seq = 0
         self.slots: list[Optional[Request]] = [None] * b
@@ -240,6 +247,11 @@ class ServingEngine:
                                      static_argnums=(4,))
         self._standby_apply = jax.jit(self._standby_apply_impl)
         self._deactivate = jax.jit(self._deactivate_impl)
+
+    def _place(self, tree):
+        """Commit `tree` to this engine's device (identity without one)."""
+        return tree if self.device is None else jax.device_put(tree,
+                                                               self.device)
 
     # --- bucketing ---------------------------------------------------------
     def buckets(self) -> list[int]:
@@ -462,8 +474,9 @@ class ServingEngine:
             src[d] = j
             mask[d] = True
         self._reserve_for_resume(dst_slots, reqs)
+        bcache, bstate = self._place((bundle["cache"], bundle["state"]))
         self.cache, self.state = self._import(
-            self.cache, self.state, bundle["cache"], bundle["state"],
+            self.cache, self.state, bcache, bstate,
             jnp.asarray(src), jnp.asarray(mask))
         for d, req in zip(dst_slots, reqs):
             self.slots[d] = req
@@ -513,10 +526,10 @@ class ServingEngine:
         generations. Lazy — engines outside a replicated grid never pay
         the memory."""
         if self.standby is None:
-            self.standby = {
+            self.standby = self._place({
                 "cache": self.spec.init_standby(self.cache),
                 "state": jax.tree.map(jnp.zeros_like, self.state),
-            }
+            })
 
     def export_delta(self, entries, width: int) -> dict:
         """Delta-export `entries` = [(slot, cursor), ...]: each slot's
@@ -568,10 +581,10 @@ class ServingEngine:
             src[r] = j
             starts[r] = bundle["starts"][j]
             mask[r] = True
+        bcache, bstate = self._place((bundle["cache"], bundle["state"]))
         sc, ss = self._standby_apply(
-            self.standby["cache"], self.standby["state"], bundle["cache"],
-            bundle["state"], jnp.asarray(src), jnp.asarray(starts),
-            jnp.asarray(mask))
+            self.standby["cache"], self.standby["state"], bcache, bstate,
+            jnp.asarray(src), jnp.asarray(starts), jnp.asarray(mask))
         self.standby = {"cache": sc, "state": ss}
         self.stats["standby_syncs"] += 1
 
@@ -641,7 +654,7 @@ class ServingEngine:
         Returns the version number the new params will serve under.
         """
         check_swap_compatible(self.params, new_params)
-        self._pending_params = new_params
+        self._pending_params = self._place(new_params)
         self._maybe_apply_swap()
         return self.params_version + (self._pending_params is not None)
 

@@ -214,7 +214,6 @@ def _wire_shard_hop(deltas, ef, pod_mask, denom, fmt):
     BG002 budget and tests/test_wire_format.py hold it to ~n_pods/S of
     the f32 baseline instead of the ~100x regression the simulated
     compressor lowers to."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.compression import (int8_wire_compress,
@@ -253,11 +252,11 @@ def _wire_shard_hop(deltas, ef, pod_mask, denom, fmt):
             return (grad.reshape(d_loc.shape[1:]),
                     resid.reshape(d_loc.shape))
 
-        return shard_map(
-            local, mesh,
+        return jax.shard_map(
+            local, mesh=mesh,
             in_specs=(P("pod", *spec), P("pod", *spec), P(), P()),
             out_specs=(P(*spec), P("pod", *spec)),
-            check_rep=False)(d, e, pod_mask, denom)
+            check_vma=False)(d, e, pod_mask, denom)
 
     pairs = jax.tree.map(leaf_hop, deltas, ef, fmt.layout,
                          is_leaf=lambda x: is_wire_leaf(x))

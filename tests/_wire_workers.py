@@ -30,7 +30,6 @@ from repro.analysis.hlo import collective_bytes
 from repro.distributed.compression import wire_format_for
 from repro.distributed.sharding import (diloco_specs, param_specs,
                                         shardings_for)
-from repro.launch.dryrun import _mesh_ctx
 from repro.launch.mesh import make_production_mesh
 from repro.models import registry
 from repro.train.diloco import (LINT_BUDGET, DiLoCoConfig, diloco_init,
@@ -83,7 +82,7 @@ def main():
                                     wire=fmt.simulated()),
             in_shardings=(state_sh, None), out_shardings=state_sh)
 
-        with _mesh_ctx(mesh):
+        with jax.set_mesh(mesh):
             d0_dev = jax.device_put(d0, state_sh)
             # round 1 (pod 1 dead) -> round 2 (all alive): EF residuals
             # carried across rounds on both paths

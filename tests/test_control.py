@@ -3,14 +3,14 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-jax.config.update("jax_enable_x64", True)
-
 from repro.core.orbital import ClusterDesign, ControlProblem, rollout, train_controller
 from repro.core.orbital.control import init_policy, policy_apply
 
+pytestmark = pytest.mark.usefixtures("x64")
+
 
 @pytest.fixture(scope="module")
-def trained():
+def trained(x64):
     d = ClusterDesign(n_side=3, spacing=100.0)
     prob = ControlProblem(design=d, u_max=2e-5, control_dt=60.0, substeps=4,
                           dv_weight=1e3)

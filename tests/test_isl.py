@@ -90,11 +90,9 @@ class TestLinkBudget:
 
 
 class TestTopology:
-    def test_formation_distances_support_full_stack(self):
+    def test_formation_distances_support_full_stack(self, x64):
         """At the 100-200 m §2.2 formation distances every neighbor link
         carries >= the full 24-channel DWDM stack (>= 9.6 Tbps)."""
-        import jax
-        jax.config.update("jax_enable_x64", True)
         from repro.core.orbital import ClusterDesign, hcw_state
         d = ClusterDesign()
         pos = np.asarray(hcw_state(d.alpha_beta(), d.n, 0.0)[..., :3])
@@ -132,12 +130,10 @@ class TestTopology:
                for j in np.argsort(d[i], kind="stable")[:k] if i < int(j)}
         assert old < eset                                # strictly more
 
-    def test_neighbor_graph_9x9_retains_physical_neighbors(self):
+    def test_neighbor_graph_9x9_retains_physical_neighbors(self, x64):
         """Acceptance: on the paper's 9x9 lattice every satellite keeps
         its direct formation links (the edges the pod fabric routes over)
         in the symmetrized k=8 graph."""
-        import jax
-        jax.config.update("jax_enable_x64", True)
         from repro.core.orbital import ClusterDesign, hcw_state
         d = ClusterDesign()
         pos = np.asarray(hcw_state(d.alpha_beta(), d.n, 0.0)[..., :3])
@@ -151,12 +147,10 @@ class TestTopology:
                         j = rr * 9 + cc
                         assert (min(i, j), max(i, j)) in eset, (i, j)
 
-    def test_pod_axis_conservative_is_worst_neighbor_link(self):
+    def test_pod_axis_conservative_is_worst_neighbor_link(self, x64):
         """Regression: the conservative pod-axis figure must be the worst
         routed (neighbor-graph) link, not the ~2.2 km corner-to-corner
         pair of the all-pairs matrix that nothing routes over."""
-        import jax
-        jax.config.update("jax_enable_x64", True)
         from repro.core.isl import pod_axis_bandwidth_bytes
         from repro.core.orbital import ClusterDesign, hcw_state
         d = ClusterDesign()

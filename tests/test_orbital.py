@@ -1,10 +1,7 @@
 """Orbital dynamics tests: integrator accuracy, HCW, cluster (paper §2.2)."""
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-jax.config.update("jax_enable_x64", True)
 
 from repro.core.orbital import (ClusterDesign, hcw_propagate, hcw_state,
                                 integrate, integrate_dense, make_rhs,
@@ -13,6 +10,8 @@ from repro.core.orbital import (ClusterDesign, hcw_propagate, hcw_state,
                                 sun_sync_inclination)
 from repro.core.orbital import constants as C
 from repro.core.orbital.frames import eci_to_hill, hill_to_eci
+
+pytestmark = pytest.mark.usefixtures("x64")
 
 
 def _circular_state(a):

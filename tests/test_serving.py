@@ -250,6 +250,33 @@ def test_paged_engine_through_pallas_kernel(setup, monkeypatch):
     assert serve() == ref
 
 
+def test_engine_device_commits_state_and_imported_rows(setup):
+    """device= commits params and slot state to that device; generations
+    migrated in from an engine without one are copied onto it and finish
+    with the tokens of an unmigrated run."""
+    cfg, fns, params = setup
+    dev = jax.devices()[-1]
+    prompts = _mixed_workload(cfg, n=3, seed=4)
+    want = _serve_all(ServingEngine(cfg, fns, params, _paged_ecfg(
+        page_size=0)), prompts, max_new=20)
+
+    src = ServingEngine(cfg, fns, params, _paged_ecfg(page_size=0))
+    dst = ServingEngine(cfg, fns, params, _paged_ecfg(page_size=0),
+                        device=dev)
+    held = jax.tree.leaves((dst.params, dst.cache, dst.state))
+    assert all(x.committed and x.devices() == {dev} for x in held)
+    for uid, p in enumerate(prompts):
+        src.submit(Request(uid=uid, prompt=p, max_new_tokens=20,
+                           temperature=0.8 if uid % 2 else 0.0))
+    src.step()
+    dst.import_slots(src.export_slots(
+        [i for i, r in enumerate(src.slots) if r is not None]))
+    assert all(x.devices() == {dev}
+               for x in jax.tree.leaves((dst.cache, dst.state)))
+    got = {r.uid: r.generated for r in dst.run()}
+    assert got == want
+
+
 def test_paged_continuous_admission_undersized_pool(setup):
     """A pool too small for all slots at once gates admission on free
     pages (head-of-line stall), recycles a finishing request's pages into

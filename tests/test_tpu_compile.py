@@ -1,0 +1,86 @@
+"""Compile the Pallas kernels of the main path for a described TPU v5e.
+
+Interpret mode (tests/test_kernels.py) checks the kernels' arithmetic but
+not what the TPU compiler accepts: block shapes against the tiling rules,
+VMEM use, 1-D values. These tests lower each kernel at the serving and
+training widths of suncatcher-lm-100m (and the RG-LRU width of
+recurrentgemma-2b) and compile it for one chip of a `v5e:2x2` topology,
+which the installed TPU compiler can do without a chip attached. Nothing
+runs. All of them live in this one file, behind module fixtures, so that
+only the worker that runs this file loads the TPU library.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.models import registry
+
+LM = registry.get_config("suncatcher-lm-100m")
+SLOTS, MAX_LEN, PAGE = 32, 1024, 16          # serving widths
+BATCH, SEQ = 8, 1024                          # training widths
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    """ShapeDtypeStruct factory on one chip of the described topology,
+    with the persistent compilation cache off: entries compiled for a
+    described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _assert_kernel(fn, *args):
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_decode_attention_compiles(sds):
+    from repro.kernels.decode_attention.ops import decode_attention
+    dt = LM.cdtype
+    cache = sds((SLOTS, MAX_LEN, LM.n_kv_heads, LM.hd), dt)
+    _assert_kernel(decode_attention, sds((SLOTS, 1, LM.n_heads, LM.hd), dt),
+                   cache, cache, sds((SLOTS,), jnp.int32))
+
+
+def test_paged_decode_attention_compiles(sds):
+    from repro.kernels.decode_attention.paged import paged_decode_attention
+    dt = LM.cdtype
+    pages = MAX_LEN // PAGE
+    pool = sds((SLOTS * pages + 1, PAGE, LM.n_kv_heads, LM.hd), dt)
+    _assert_kernel(paged_decode_attention,
+                   sds((SLOTS, 1, LM.n_heads, LM.hd), dt), pool, pool,
+                   sds((SLOTS, pages), jnp.int32), sds((SLOTS,), jnp.int32))
+
+
+def test_flash_attention_compiles(sds):
+    from repro.kernels.flash_attention.ops import flash_attention
+    dt = LM.cdtype
+    kv = sds((BATCH, SEQ, LM.n_kv_heads, LM.hd), dt)
+    _assert_kernel(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                   sds((BATCH, SEQ, LM.n_heads, LM.hd), dt), kv, kv)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_rglru_scan_compiles(sds, dtype):
+    from repro.kernels.rglru_scan.ops import rglru_scan
+    d = registry.get_config("recurrentgemma-2b").d_model
+    x = sds((4, SEQ, d), dtype)
+    _assert_kernel(rglru_scan, x, x)

@@ -711,6 +711,26 @@ class TestDeviceScreens:
 
 
 class TestSharding:
+    def test_test_mesh_axes_are_auto(self):
+        """jax.make_mesh builds Explicit axes by default, under which the
+        models' unannotated embedding gather raises ShardingTypeError."""
+        from jax.sharding import AxisType
+
+        from repro.launch.mesh import make_test_mesh
+        assert set(make_test_mesh().axis_types) == {AxisType.Auto}
+
+    def test_launcher_default_sharded_fused_path(self):
+        """`python -m repro.launch.train` defaults to --mesh test with
+        fused drains: the sharded fused path must train on that mesh."""
+        from repro.launch import train
+        args = train.build_parser().parse_args(
+            ["--steps", "2", "--drain-every", "2", "--seq-len", "16",
+             "--batch", "2"])
+        assert args.mesh == "test" and not args.full
+        hist = train.run(args)
+        assert len(hist) == 2
+        assert np.all(np.isfinite([h["loss"] for h in hist]))
+
     def test_sharded_train_step_bit_identical(self):
         from repro.launch.mesh import make_test_mesh
         cfg, fns, tcfg, dcfg, data, params = _micro_diloco_setup()
