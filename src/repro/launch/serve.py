@@ -168,6 +168,26 @@ def build_plane(builds, args):
                                forced_outage=forced, grid=grid)
 
 
+def admission_summary(stats: dict, done) -> str:
+    """The single engine's admission line: prefill calls and rows per call,
+    the share of the prefill program's token rows that hold prompt tokens
+    (the rest is padding to the bucket and to max_batch rows), and p90s of
+    the finished requests' queue wait (arrival to admission) and of their
+    admission to first token on the host, from the request stamps."""
+    def p90_ms(xs):
+        return 1e3 * float(np.percentile(xs, 90)) if xs else 0.0
+
+    calls = stats["prefill_calls"]
+    useful = stats["prefill_tokens"] / max(stats["prefill_slot_tokens"], 1)
+    return (f"  admission: {calls} prefill calls, "
+            f"{stats['prefill_rows'] / max(calls, 1):.1f} rows/call, "
+            f"{100 * useful:.1f}% of prefill token rows useful | p90 queue "
+            f"wait {p90_ms([r.admitted_at - r.arrival for r in done]):.1f}"
+            f" ms, admission to first token "
+            f"{p90_ms([r.first_token_at - r.admitted_at for r in done]):.1f}"
+            f" ms")
+
+
 def main():
     args = build_parser().parse_args()
     enable_compile_cache()
@@ -256,6 +276,7 @@ def main():
               f"{s['host_syncs'] / max(s['tokens'], 1):.3f} "
               f"host-syncs/token | {eng.trace_count()} traces "
               f"(buckets={eng.buckets()}, decode_block={args.decode_block})")
+        print(admission_summary(s, done))
         if args.page_size:
             ps = eng.page_stats()
             print(f"  paged KV: {ps['pool_pages']} pool pages x "

@@ -473,18 +473,21 @@ class PagedTransformerDecodeState(TransformerDecodeState):
         position starts a new page (pos % ps == 0)."""
         ps = self.page_size
         pos = state["pos"]
-        col = jnp.clip(pos // ps, 0, self.max_pages - 1)
-        need = active & (pos % ps == 0) & (pos // ps < self.max_pages)
-        b = pos.shape[0]
-        take = jnp.zeros((b, self.max_pages), bool)
-        take = take.at[jnp.arange(b), col].set(need)
-        ptab, ref, top = _alloc_rows(state["ptab"], state["free"],
-                                     state["top"], state["ref"], take)
+        with jax.named_scope("pages"):
+            col = jnp.clip(pos // ps, 0, self.max_pages - 1)
+            need = active & (pos % ps == 0) & (pos // ps < self.max_pages)
+            b = pos.shape[0]
+            take = jnp.zeros((b, self.max_pages), bool)
+            take = take.at[jnp.arange(b), col].set(need)
+            ptab, ref, top = _alloc_rows(state["ptab"], state["free"],
+                                         state["top"], state["ref"], take)
         return {**state, "ptab": ptab, "ref": ref, "top": top}
 
     def release(self, state, drop):
-        ptab, free, top, ref = _release_rows(
-            state["ptab"], state["free"], state["top"], state["ref"], drop)
+        with jax.named_scope("pages"):
+            ptab, free, top, ref = _release_rows(
+                state["ptab"], state["free"], state["top"], state["ref"],
+                drop)
         return {**state, "ptab": ptab, "free": free, "top": top,
                 "ref": ref}
 
@@ -509,56 +512,59 @@ class PagedTransformerDecodeState(TransformerDecodeState):
         logits, tmp = _transformer.decode_step(
             params, tmp, tokens, cfg, last_idx=jnp.maximum(lens - 1, 0))
 
-        ps, mp, trash = self.page_size, self.max_pages, self.pool_pages
-        cols = jnp.arange(mp)[None]                     # (1, MP)
-        ptab = jnp.where(admit[:, None], trash, state["ptab"])
-        ref, top = state["ref"], state["top"]
-        if page_ops is None:
-            zeros = jnp.zeros((b,), jnp.int32)
-            page_ops = {"pf_entry": zeros - 1, "pf_n": zeros,
-                        "pf_store": zeros - 1, "pf_store_n": zeros}
-        pf_entry, pf_n = page_ops["pf_entry"], page_ops["pf_n"]
-        pf_store, pf_store_n = page_ops["pf_store"], page_ops["pf_store_n"]
+        # the page half: prefix-page mapping, allocation, re-paging and
+        # publication
+        with jax.named_scope("pages"):
+            ps, mp, trash = self.page_size, self.max_pages, self.pool_pages
+            cols = jnp.arange(mp)[None]                     # (1, MP)
+            ptab = jnp.where(admit[:, None], trash, state["ptab"])
+            ref, top = state["ref"], state["top"]
+            if page_ops is None:
+                zeros = jnp.zeros((b,), jnp.int32)
+                page_ops = {"pf_entry": zeros - 1, "pf_n": zeros,
+                            "pf_store": zeros - 1, "pf_store_n": zeros}
+            pf_entry, pf_n = page_ops["pf_entry"], page_ops["pf_n"]
+            pf_store, pf_store_n = page_ops["pf_store"], page_ops["pf_store_n"]
 
-        new = dict(state)
-        shared = jnp.where(admit & (pf_entry >= 0), pf_n, 0)
-        if self.prefix_entries:
-            # map shared prefix pages from the pf table + take a reference
-            src = state["pf_tab"][jnp.clip(pf_entry, 0,
-                                           self.prefix_entries - 1)]
-            use = (admit & (pf_entry >= 0))[:, None] & \
-                (cols < shared[:, None])
-            ptab = jnp.where(use, src, ptab)
-            ref = ref.at[jnp.where(use, src, trash)].add(
-                use.astype(jnp.int32))
+            new = dict(state)
+            shared = jnp.where(admit & (pf_entry >= 0), pf_n, 0)
+            if self.prefix_entries:
+                # map shared prefix pages from the pf table + take a reference
+                src = state["pf_tab"][jnp.clip(pf_entry, 0,
+                                               self.prefix_entries - 1)]
+                use = (admit & (pf_entry >= 0))[:, None] & \
+                    (cols < shared[:, None])
+                ptab = jnp.where(use, src, ptab)
+                ref = ref.at[jnp.where(use, src, trash)].add(
+                    use.astype(jnp.int32))
 
-        # allocate the non-shared remainder of ceil(lens / ps) pages
-        pages_needed = -(-lens // ps)
-        take = admit[:, None] & (cols >= shared[:, None]) & \
-            (cols < pages_needed[:, None])
-        ptab, ref, top = _alloc_rows(ptab, state["free"], top, ref, take)
+            # allocate the non-shared remainder of ceil(lens / ps) pages
+            pages_needed = -(-lens // ps)
+            take = admit[:, None] & (cols >= shared[:, None]) & \
+                (cols < pages_needed[:, None])
+            ptab, ref, top = _alloc_rows(ptab, state["free"], top, ref, take)
 
-        # re-page the freshly prefilled KV (skip shared pages — their
-        # contents are already resident and bit-identical by the
-        # prefill length-independence proof)
-        t = jnp.arange(tmp["k"].shape[2])[None]   # dense pads lb up to 128
-        write = admit[:, None] & (t >= (shared * ps)[:, None]) & \
-            (t < lens[:, None])
-        new["kp"] = _scatter_logical(state["kp"], ptab, tmp["k"], write)
-        new["vp"] = _scatter_logical(state["vp"], ptab, tmp["v"], write)
+            # re-page the freshly prefilled KV (skip shared pages — their
+            # contents are already resident and bit-identical by the
+            # prefill length-independence proof)
+            t = jnp.arange(tmp["k"].shape[2])[None]   # dense pads lb up to 128
+            write = admit[:, None] & (t >= (shared * ps)[:, None]) & \
+                (t < lens[:, None])
+            new["kp"] = _scatter_logical(state["kp"], ptab, tmp["k"], write)
+            new["vp"] = _scatter_logical(state["vp"], ptab, tmp["v"], write)
 
-        if self.prefix_entries:
-            # publish flagged rows' head pages (+1 pin so they outlive
-            # the publishing request)
-            store = admit & (pf_store >= 0)
-            ents = jnp.where(store, pf_store, self.prefix_entries)
-            vals = jnp.where(cols < pf_store_n[:, None], ptab, trash)
-            new["pf_tab"] = state["pf_tab"].at[ents].set(vals, mode="drop")
-            new["pf_len"] = state["pf_len"].at[ents].set(pf_store_n,
-                                                         mode="drop")
-            pin = store[:, None] & (cols < pf_store_n[:, None])
-            ref = ref.at[jnp.where(pin, ptab, trash)].add(
-                pin.astype(jnp.int32))
+            if self.prefix_entries:
+                # publish flagged rows' head pages (+1 pin so they outlive
+                # the publishing request)
+                store = admit & (pf_store >= 0)
+                ents = jnp.where(store, pf_store, self.prefix_entries)
+                vals = jnp.where(cols < pf_store_n[:, None], ptab, trash)
+                new["pf_tab"] = state["pf_tab"].at[ents].set(vals, mode="drop")
+                new["pf_len"] = state["pf_len"].at[ents].set(pf_store_n,
+                                                             mode="drop")
+                pin = store[:, None] & (cols < pf_store_n[:, None])
+                ref = ref.at[jnp.where(pin, ptab, trash)].add(
+                    pin.astype(jnp.int32))
 
         new.update(ptab=ptab, ref=ref, top=top,
                    pos=jnp.where(admit, lens, state["pos"]))

@@ -218,87 +218,93 @@ def _block(cfg: TransformerConfig, x, lp, cos, sin, *, q_offset=0,
     # (Gather placed after the norm: the XLA CPU partitioner then gathers the
     # norm's f32 internals — 2x wire bytes vs bf16 — but keeps the saved
     # checkpoints sequence-sharded. See EXPERIMENTS.md §Perf iteration 3.)
-    hnb = _norm(cfg, x, lp["attn_norm"], lp.get("attn_norm_bias"))
-    hnb = shard_hint(hnb, ("batch", None, None))
-    q = hnb @ lp["wq"]
-    k = hnb @ lp["wk"]
-    v = hnb @ lp["wv"]
-    if cfg.qkv_bias:
-        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    # attention zone: shard heads over "model" when they divide, else fall
-    # back to sequence sharding of q (chunked attention handles both)
-    ms = mesh_axis_size("model")
-    head_par = ms is not None and h % ms == 0 and cache is None
-    seq_ax = None if (head_par or cache is not None) else "model"
-    q = shard_hint(q.reshape(b, s, h, hd),
-                   ("batch", seq_ax, "model" if head_par else None, None))
-    kv_head_ax = "model" if (ms and hkv % ms == 0 and head_par) else None
-    k = shard_hint(k.reshape(b, s, hkv, hd),
-                   ("batch", None, kv_head_ax, None))
-    v = shard_hint(v.reshape(b, s, hkv, hd),
-                   ("batch", None, kv_head_ax, None))
-    if cfg.pos_embed == "rope":
-        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    with jax.named_scope("attention"):
+        hnb = _norm(cfg, x, lp["attn_norm"], lp.get("attn_norm_bias"))
+        hnb = shard_hint(hnb, ("batch", None, None))
+        q = hnb @ lp["wq"]
+        k = hnb @ lp["wk"]
+        v = hnb @ lp["wv"]
+        if cfg.qkv_bias:
+            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        # attention zone: shard heads over "model" when they divide, else
+        # fall back to sequence sharding of q (chunked attention handles
+        # both)
+        ms = mesh_axis_size("model")
+        head_par = ms is not None and h % ms == 0 and cache is None
+        seq_ax = None if (head_par or cache is not None) else "model"
+        q = shard_hint(q.reshape(b, s, h, hd),
+                       ("batch", seq_ax, "model" if head_par else None, None))
+        kv_head_ax = "model" if (ms and hkv % ms == 0 and head_par) else None
+        k = shard_hint(k.reshape(b, s, hkv, hd),
+                       ("batch", None, kv_head_ax, None))
+        v = shard_hint(v.reshape(b, s, hkv, hd),
+                       ("batch", None, kv_head_ax, None))
+        if cfg.pos_embed == "rope":
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
 
-    new_cache = None
-    page_table = None
-    if cache is not None and len(cache) == 3:
-        # paged decode (s == 1): k/v pools (P+1, ps, Hkv, dh) + per-row
-        # page table. Each row writes its token at (table[pos // ps],
-        # pos % ps); rows with no mapped page there (inactive slots) land
-        # on the trash page. Active rows always write distinct pages —
-        # prefix-shared pages only cover positions < prompt_len, below any
-        # decode write.
-        kp, vp, page_table = cache
-        ps = kp.shape[1]
-        pids = page_table[jnp.arange(b), q_offset // ps]
-        kp = kp.at[pids, q_offset % ps].set(k[:, 0].astype(kp.dtype))
-        vp = vp.at[pids, q_offset % ps].set(v[:, 0].astype(vp.dtype))
-        k, v, new_cache = kp, vp, (kp, vp)
-    elif cache is not None:
-        ck, cv = cache
-        if jnp.ndim(q_offset) == 1:   # per-slot positions (continuous batching)
-            rows = jnp.arange(b)[:, None]
-            cols = q_offset[:, None] + jnp.arange(s)[None]
-            ck = ck.at[rows, cols].set(k.astype(ck.dtype))
-            cv = cv.at[rows, cols].set(v.astype(cv.dtype))
+        new_cache = None
+        page_table = None
+        if cache is not None and len(cache) == 3:
+            # paged decode (s == 1): k/v pools (P+1, ps, Hkv, dh) + per-row
+            # page table. Each row writes its token at (table[pos // ps],
+            # pos % ps); rows with no mapped page there (inactive slots)
+            # land on the trash page. Active rows always write distinct
+            # pages — prefix-shared pages only cover positions <
+            # prompt_len, below any decode write.
+            kp, vp, page_table = cache
+            ps = kp.shape[1]
+            pids = page_table[jnp.arange(b), q_offset // ps]
+            kp = kp.at[pids, q_offset % ps].set(k[:, 0].astype(kp.dtype))
+            vp = vp.at[pids, q_offset % ps].set(v[:, 0].astype(vp.dtype))
+            k, v, new_cache = kp, vp, (kp, vp)
+        elif cache is not None:
+            ck, cv = cache
+            if jnp.ndim(q_offset) == 1:   # per-slot positions
+                rows = jnp.arange(b)[:, None]
+                cols = q_offset[:, None] + jnp.arange(s)[None]
+                ck = ck.at[rows, cols].set(k.astype(ck.dtype))
+                cv = cv.at[rows, cols].set(v.astype(cv.dtype))
+            else:
+                ck = jax.lax.dynamic_update_slice_in_dim(
+                    ck, k.astype(ck.dtype), q_offset, axis=1)
+                cv = jax.lax.dynamic_update_slice_in_dim(
+                    cv, v.astype(cv.dtype), q_offset, axis=1)
+            k, v, new_cache = ck, cv, (ck, cv)
+
+        if jnp.ndim(q_offset) == 1:
+            # ragged per-slot positions (continuous batching). s == 1
+            # decode: kv_len mask IS the causal constraint, so drop the
+            # triangle (and let impl="pallas" stream the cache through the
+            # ragged decode kernel). s > 1 bucketed prefill: causal with
+            # per-row offsets — pad queries past a row's prompt attend only
+            # valid keys and their outputs/cache tail are masked downstream
+            # by kv_len. q_offset stays the per-row position vector even at
+            # s == 1: the causal triangle is vacuous there but the
+            # local-attention window mask still needs each query's absolute
+            # position
+            attn = attention(q, k, v, impl=cfg.attn_impl, causal=s > 1,
+                             window=cfg.window, kv_len=kv_len,
+                             q_offset=q_offset, page_table=page_table)
         else:
-            ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype),
-                                                     q_offset, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype),
-                                                     q_offset, axis=1)
-        k, v, new_cache = ck, cv, (ck, cv)
+            attn = attention(q, k, v, impl=cfg.attn_impl, causal=True,
+                             window=cfg.window, q_offset=q_offset,
+                             kv_len=kv_len)
+        attn_out = shard_hint(attn.reshape(b, s, h * hd) @ lp["wo"],
+                              ("batch", "model" if cache is None else None,
+                               None))   # reduce-scatter back to seq-sharded
+        if not cfg.parallel_block:
+            x = x + cfg.residual_scale * attn_out
 
-    if jnp.ndim(q_offset) == 1:
-        # ragged per-slot positions (continuous batching). s == 1 decode:
-        # kv_len mask IS the causal constraint, so drop the triangle (and
-        # let impl="pallas" stream the cache through the ragged decode
-        # kernel). s > 1 bucketed prefill: causal with per-row offsets —
-        # pad queries past a row's prompt attend only valid keys and their
-        # outputs/cache tail are masked downstream by kv_len.
-        # q_offset stays the per-row position vector even at s == 1: the
-        # causal triangle is vacuous there but the local-attention window
-        # mask still needs each query's absolute position
-        attn = attention(q, k, v, impl=cfg.attn_impl, causal=s > 1,
-                         window=cfg.window, kv_len=kv_len,
-                         q_offset=q_offset, page_table=page_table)
-    else:
-        attn = attention(q, k, v, impl=cfg.attn_impl, causal=True,
-                         window=cfg.window, q_offset=q_offset, kv_len=kv_len)
-    attn_out = shard_hint(attn.reshape(b, s, h * hd) @ lp["wo"],
-                          ("batch", "model" if cache is None else None,
-                           None))   # reduce-scatter back to seq-sharded
-
-    if cfg.parallel_block:
-        x = x + cfg.residual_scale * (attn_out + _mlp(cfg, lp, hnb))
-    else:
-        x = x + cfg.residual_scale * attn_out
-        h2 = _norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_bias"))
-        h2 = shard_hint(h2, ("batch", None, None))
-        mlp_out = shard_hint(_mlp(cfg, lp, h2),
-                             ("batch", "model" if cache is None else None,
-                              None))
-        x = x + cfg.residual_scale * mlp_out
+    with jax.named_scope("mlp"):
+        if cfg.parallel_block:
+            x = x + cfg.residual_scale * (attn_out + _mlp(cfg, lp, hnb))
+        else:
+            h2 = _norm(cfg, x, lp["mlp_norm"], lp.get("mlp_norm_bias"))
+            h2 = shard_hint(h2, ("batch", None, None))
+            mlp_out = shard_hint(_mlp(cfg, lp, h2),
+                                 ("batch", "model" if cache is None
+                                  else None, None))
+            x = x + cfg.residual_scale * mlp_out
     return x, new_cache
 
 
@@ -422,6 +428,25 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
             "pos": jnp.zeros((), jnp.int32)}
 
 
+def _decode_embed(cfg, params, tokens, pos0):
+    """Token embeddings (plus sinusoidal positions) of a decode or prefill
+    step whose first position is pos0: a scalar or a (B,) per-slot vector
+    (continuous batching). The angles are computed directly at pos0 +
+    arange(s) rather than sliced out of a (max) table."""
+    with jax.named_scope("embed"):
+        x = _embed(cfg, params, tokens)
+        if cfg.pos_embed == "sinusoidal":
+            d, s = cfg.d_model, x.shape[1]
+            p = _qpos(pos0, s).astype(jnp.float32)
+            if p.ndim == 1:
+                p = p[None]                                 # (B|1, s)
+            dim = jnp.arange(0, d, 2).astype(jnp.float32)
+            ang = p[..., None] / (10000.0 ** (dim / d))     # (B|1, s, d/2)
+            x = x + jnp.concatenate([jnp.sin(ang), jnp.cos(ang)],
+                                    -1).astype(x.dtype)
+        return x
+
+
 def decode_step(params, cache, tokens, cfg: TransformerConfig,
                 positions=None, last_idx=None):
     """One decode step: tokens (B, S_new) (S_new=1 for pure decode, >1 for
@@ -430,21 +455,9 @@ def decode_step(params, cache, tokens, cfg: TransformerConfig,
     `last_idx`: optional (B,) per-row index of the position whose logits to
     return (ragged bucketed prefill: rows padded to a shared bucket length
     read their logits at prompt_len - 1, not at the pad tail)."""
-    x = _embed(cfg, params, tokens)
-    b, s = x.shape[0], x.shape[1]
     pos0 = cache["pos"]
-    if cfg.pos_embed == "sinusoidal":
-        # decode offset via dynamic slice of a (max) table is avoided by
-        # computing the angles directly at pos0 + arange(s); pos0 may be a
-        # scalar or a (B,) per-slot vector (continuous batching)
-        d = cfg.d_model
-        p = _qpos(pos0, s).astype(jnp.float32)
-        if p.ndim == 1:
-            p = p[None]                                 # (B|1, s)
-        dim = jnp.arange(0, d, 2).astype(jnp.float32)
-        ang = p[..., None] / (10000.0 ** (dim / d))     # (B|1, s, d/2)
-        x = x + jnp.concatenate([jnp.sin(ang), jnp.cos(ang)],
-                                -1).astype(x.dtype)
+    x = _decode_embed(cfg, params, tokens, pos0)
+    b, s = x.shape[0], x.shape[1]
     if positions is None:
         pos_ids = _qpos(pos0, s)      # per-slot vector or scalar offset
         if cfg.mrope_sections is not None:
@@ -461,23 +474,22 @@ def decode_step(params, cache, tokens, cfg: TransformerConfig,
                               cache=(ck, cv), kv_len=kv_len)
         return x, new_cache
 
-    x, (nk, nv) = jax.lax.scan(body, x,
-                               (params["layers"], cache["k"], cache["v"]))
-    x = _norm(cfg, x, params["final_norm"].astype(cfg.cdtype),
-              params.get("final_norm_bias"))
-    if last_idx is not None:
-        assert cfg.n_codebooks == 1, "last_idx requires a single codebook"
-        # gather each row's last real position BEFORE the unembed so the
-        # (B, S, V) prefill logits are never materialized
-        x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
-        return _unembed(cfg, params, x)[:, -1], \
-            {"k": nk, "v": nv, "pos": pos0 + s}
-    logits = _unembed(cfg, params, x[:, -1:] if cfg.n_codebooks == 1
-                      else x)
-    if cfg.n_codebooks > 1:
-        logits = logits[:, :, -1]  # (B, n_q, V)
-    else:
-        logits = logits[:, -1]     # (B, V)
+    with jax.named_scope("layers"):
+        x, (nk, nv) = jax.lax.scan(
+            body, x, (params["layers"], cache["k"], cache["v"]))
+    with jax.named_scope("head"):
+        x = _norm(cfg, x, params["final_norm"].astype(cfg.cdtype),
+                  params.get("final_norm_bias"))
+        if last_idx is not None:
+            assert cfg.n_codebooks == 1, "last_idx requires a single codebook"
+            # gather each row's last real position BEFORE the unembed so
+            # the (B, S, V) prefill logits are never materialized
+            x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
+            logits = _unembed(cfg, params, x)[:, -1]
+        elif cfg.n_codebooks > 1:
+            logits = _unembed(cfg, params, x)[:, :, -1]      # (B, n_q, V)
+        else:
+            logits = _unembed(cfg, params, x[:, -1:])[:, -1]  # (B, V)
     return logits, {"k": nk, "v": nv, "pos": pos0 + s}
 
 
@@ -497,19 +509,10 @@ def paged_decode_step(params, cache, tokens, cfg: TransformerConfig):
     (L, P+1, ps, Hkv, dh), "ptab" (B, max_pages) int32 and "pos" (B,).
     Returns (logits (B, V), new cache). Positions/rope/sinusoidal handling
     mirrors decode_step exactly so paged == dense bitwise."""
-    x = _embed(cfg, params, tokens)
+    pos0 = cache["pos"]                      # (B,) per-slot positions
+    x = _decode_embed(cfg, params, tokens, pos0)
     b, s = x.shape[0], x.shape[1]
     assert s == 1 and cfg.n_codebooks == 1
-    pos0 = cache["pos"]                      # (B,) per-slot positions
-    if cfg.pos_embed == "sinusoidal":
-        d = cfg.d_model
-        p = _qpos(pos0, s).astype(jnp.float32)
-        if p.ndim == 1:
-            p = p[None]
-        dim = jnp.arange(0, d, 2).astype(jnp.float32)
-        ang = p[..., None] / (10000.0 ** (dim / d))
-        x = x + jnp.concatenate([jnp.sin(ang), jnp.cos(ang)],
-                                -1).astype(x.dtype)
     pos_ids = _qpos(pos0, s)
     if cfg.mrope_sections is not None:
         p = jnp.broadcast_to(pos_ids, (b, s))
@@ -526,10 +529,11 @@ def paged_decode_step(params, cache, tokens, cfg: TransformerConfig):
                               cache=(kp, vp, ptab), kv_len=kv_len)
         return x, new_cache
 
-    x, (nkp, nvp) = jax.lax.scan(body, x,
-                                 (params["layers"], cache["kp"],
-                                  cache["vp"]))
-    x = _norm(cfg, x, params["final_norm"].astype(cfg.cdtype),
-              params.get("final_norm_bias"))
-    logits = _unembed(cfg, params, x[:, -1:])[:, -1]
+    with jax.named_scope("layers"):
+        x, (nkp, nvp) = jax.lax.scan(
+            body, x, (params["layers"], cache["kp"], cache["vp"]))
+    with jax.named_scope("head"):
+        x = _norm(cfg, x, params["final_norm"].astype(cfg.cdtype),
+                  params.get("final_norm_bias"))
+        logits = _unembed(cfg, params, x[:, -1:])[:, -1]
     return logits, {**cache, "kp": nkp, "vp": nvp, "pos": pos0 + s}

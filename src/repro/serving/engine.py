@@ -48,9 +48,29 @@ xLSTM carry, MoE) supplies a spec with `init_state`/`decode`/`prefill`/
 `freeze` plus batch/length axis declarations, and every migration
 primitive here is a generic tree gather/scatter over those declarations —
 carry migration carries the same bit-exactness proof as KV migration.
+
+What operators can read, with the profiler on or off (no option turns any
+of it on): host spans, `jax.profiler.TraceAnnotation`s on the same clock as
+the device ops in a profiler trace — `engine.step` (all of `step`),
+`engine.fill` (admission; arg `rows`), `engine.prefill` (one prefill
+program call; args `bucket`, `rows`), `engine.decode_block`,
+`engine.drain` (the host waiting on `jax.device_get` of a fill or a block)
+and `engine.emit` (the host loop after a drain: tokens appended, requests
+finished, pages returned). Counters in `stats`, per prefill call:
+`prefill_calls`, `prefill_rows`, `prefill_tokens` (prompt tokens) and
+`prefill_slot_tokens` (max_batch x bucket, the token rows the program
+computes), so prefill_tokens / prefill_slot_tokens is the useful share of
+prefill work. Stamps on each `Request`, on `time.perf_counter()`:
+`arrival` (the caller's, else `submit`'s), `admitted_at` (taken into a
+slot) and `first_token_at` (first token on the host). Device ops carry
+`jax.named_scope` names in their op metadata: `embed`, `layers` (the scan
+over blocks: its own ops are the weight and cache slices and the cache
+write-back), `attention`, `mlp`, `head`, `sample` and `pages` (paged
+allocator and prefix-page mapping).
 """
 from __future__ import annotations
 
+import time
 from collections import defaultdict
 from dataclasses import dataclass, field
 from typing import Optional
@@ -79,6 +99,10 @@ class Request:
         Ignored by a bare ServingEngine.
       generated: output token ids (filled in by the engine).
       done: set once the request left its slot (eos/budget/out-of-room).
+      arrival: when the request arrived, on time.perf_counter(); the
+        caller's, else stamped by `submit`.
+      admitted_at: when a fill took it off the queue into a slot.
+      first_token_at: when its first token reached the host.
     """
     uid: int
     prompt: np.ndarray              # (S,) int32
@@ -89,6 +113,9 @@ class Request:
     # outputs
     generated: list = field(default_factory=list)
     done: bool = False
+    arrival: Optional[float] = None
+    admitted_at: Optional[float] = None
+    first_token_at: Optional[float] = None
     # engine-internal: submission order, keys the request's PRNG stream
     _seq: int = -1
     # engine-internal: params_version the request was admitted (and will
@@ -222,9 +249,10 @@ class ServingEngine:
         self.params_version = 0
         self._pending_params = None
         self.standby = None          # lazily allocated warm-standby store
-        self.stats = {"tokens": 0, "host_syncs": 0, "decode_blocks": 0,
-                      "swaps": 0, "exported_slots": 0, "imported_slots": 0,
-                      "standby_syncs": 0, "promoted_slots": 0}
+        self.stats = {"tokens": 0, "host_syncs": 0, "swaps": 0,
+                      "standby_syncs": 0, "promoted_slots": 0,
+                      "prefill_calls": 0, "prefill_rows": 0,
+                      "prefill_tokens": 0, "prefill_slot_tokens": 0}
         # host-side conservative page accounting (paged layout only):
         # admission reserves worst-case pages per request so the in-graph
         # allocator's free stack can never underflow.  Invariant:
@@ -276,13 +304,14 @@ class ServingEngine:
 
         `keys` is (B, 2): each row draws from its own request stream, so
         the result is independent of slot placement and co-batched rows."""
-        greedy = jnp.argmax(logits, axis=-1)
-        k = min(self.ecfg.top_k, logits.shape[-1])
-        vals, idx = jax.lax.top_k(logits, k)
-        scaled = vals / jnp.maximum(temps[:, None], 1e-6)
-        draw = jax.vmap(jax.random.categorical)(keys, scaled)
-        sampled = jnp.take_along_axis(idx, draw[:, None], -1)[:, 0]
-        return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            greedy = jnp.argmax(logits, axis=-1)
+            k = min(self.ecfg.top_k, logits.shape[-1])
+            vals, idx = jax.lax.top_k(logits, k)
+            scaled = vals / jnp.maximum(temps[:, None], 1e-6)
+            draw = jax.vmap(jax.random.categorical)(keys, scaled)
+            sampled = jnp.take_along_axis(idx, draw[:, None], -1)[:, 0]
+            return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
 
     # --- fused decode block (the hot path) --------------------------------
     def _engine_step_impl(self, params, cache, state):
@@ -435,7 +464,6 @@ class ServingEngine:
         for s in slot_ids:
             self.slots[s] = None
             self._return_pages(s)
-        self.stats["exported_slots"] += len(reqs)
         return {"cache": bcache, "state": bstate, "requests": reqs,
                 "params_version": self.params_version,
                 "max_len": self.ecfg.max_len}
@@ -480,7 +508,6 @@ class ServingEngine:
             jnp.asarray(src), jnp.asarray(mask))
         for d, req in zip(dst_slots, reqs):
             self.slots[d] = req
-        self.stats["imported_slots"] += len(reqs)
         return dst_slots
 
     # --- warm-standby replication (tuple-space serving grid) ---------------
@@ -790,6 +817,8 @@ class ServingEngine:
             # PRNG stream is independent of which replica it lands on
             req._seq = self._next_seq
             self._next_seq += 1
+        if req.arrival is None:
+            req.arrival = time.perf_counter()
         self.queue.append(req)
 
     def _fill_slots(self):
@@ -802,9 +831,33 @@ class ServingEngine:
         slots oversubscribing an undersized pool. The queue is FIFO:
         a head request that does not fit stalls admission (no reorder,
         no starvation) until a decode block recycles enough pages."""
+        with jax.profiler.TraceAnnotation("engine.fill") as span:
+            admitted = self._admit()
+            span.set_metadata(rows=len(admitted))
+            if not admitted:
+                return
+            results = self._prefill_buckets(admitted)
+            # one transfer for all admission rounds in this fill
+            with jax.profiler.TraceAnnotation("engine.drain"):
+                flat = jax.device_get([(f, d) for _, f, d in results])  # repro-lint: allow[HS001] the single batched admission drain; counted in stats["host_syncs"]
+            now = time.perf_counter()
+            self.stats["host_syncs"] += 1
+            with jax.profiler.TraceAnnotation("engine.emit"):
+                for (grp, _, _), (first, done0) in zip(results, flat):
+                    for slot, req, _ in grp:
+                        req.generated.append(int(first[slot]))
+                        req.first_token_at = now
+                        self.stats["tokens"] += 1
+                        if done0[slot]:
+                            req.done = True
+                            self.finished.append(req)
+                            self.slots[slot] = None
+                            self._return_pages(slot)
+
+    def _admit(self):
+        """Take queued requests into free slots (FIFO, page-gated when
+        paged): [(slot, request, page ops)]."""
         free = [i for i, s in enumerate(self.slots) if s is None]
-        if not free or not self.queue:
-            return
         admitted = []
         while free and self.queue:
             if self._paged:
@@ -818,8 +871,14 @@ class ServingEngine:
                 admitted.append((slot, self.queue.pop(0), plan[2]))
             else:
                 admitted.append((free.pop(0), self.queue.pop(0), None))
-        if not admitted:
-            return
+        now = time.perf_counter()
+        for _, req, _ in admitted:
+            req.admitted_at = now
+        return admitted
+
+    def _prefill_buckets(self, admitted):
+        """One full-batch prefill call per bucket the admitted rows use:
+        [(rows, first tokens, done-at-admission)], still on device."""
         groups = defaultdict(list)
         for slot, req, ops in admitted:
             groups[self._bucket_for(len(req.prompt))].append(
@@ -854,49 +913,46 @@ class ServingEngine:
                     (page_ops["pf_entry"][slot], page_ops["pf_n"][slot],
                      page_ops["pf_store"][slot],
                      page_ops["pf_store_n"][slot]) = ops
-            self.cache, self.state, first, done0 = self._prefill(
-                self.params, self.cache, self.state, jnp.asarray(tokens),
-                jnp.asarray(lens), jnp.asarray(admit), jnp.asarray(temps),
-                jnp.asarray(eos), jnp.asarray(budgets), jnp.asarray(seqs),
-                jax.tree.map(jnp.asarray, page_ops))
+            with jax.profiler.TraceAnnotation("engine.prefill", bucket=lb,
+                                              rows=len(grp)):
+                self.cache, self.state, first, done0 = self._prefill(
+                    self.params, self.cache, self.state,
+                    jnp.asarray(tokens), jnp.asarray(lens),
+                    jnp.asarray(admit), jnp.asarray(temps),
+                    jnp.asarray(eos), jnp.asarray(budgets),
+                    jnp.asarray(seqs), jax.tree.map(jnp.asarray, page_ops))
+            self.stats["prefill_calls"] += 1
+            self.stats["prefill_rows"] += len(grp)
+            self.stats["prefill_tokens"] += int(lens.sum())
+            self.stats["prefill_slot_tokens"] += b * lb
             results.append((grp, first, done0))
         # prefix entries published by the calls above are now resident
         # on device — matchable from the next fill on
         if self._prefix_staged:
             self._prefix_index.update(self._prefix_staged)
             self._prefix_staged.clear()
-
-        # one transfer for all admission rounds in this fill
-        flat = jax.device_get([(f, d) for _, f, d in results])  # repro-lint: allow[HS001] the single batched admission drain; counted in stats["host_syncs"]
-        self.stats["host_syncs"] += 1
-        for (grp, _, _), (first, done0) in zip(results, flat):
-            for slot, req, _ in grp:
-                req.generated.append(int(first[slot]))
-                self.stats["tokens"] += 1
-                if done0[slot]:
-                    req.done = True
-                    self.finished.append(req)
-                    self.slots[slot] = None
-                    self._return_pages(slot)
+        return results
 
     def _decode_block(self):
         """One fused device block; drain results in a single transfer."""
-        self.cache, self.state, toks, emit, done = self._engine_step(
-            self.params, self.cache, self.state)
-        toks, emit, done = jax.device_get((toks, emit, done))  # repro-lint: allow[HS001] THE per-block drain the 0.047 syncs/token budget is built on
-        self.stats["host_syncs"] += 1
-        self.stats["decode_blocks"] += 1
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            row = toks[i][emit[i]]
-            req.generated.extend(int(t) for t in row)
-            self.stats["tokens"] += int(emit[i].sum())
-            if done[i].any():
-                req.done = True
-                self.finished.append(req)
-                self.slots[i] = None
-                self._return_pages(i)
+        with jax.profiler.TraceAnnotation("engine.decode_block"):
+            self.cache, self.state, toks, emit, done = self._engine_step(
+                self.params, self.cache, self.state)
+            with jax.profiler.TraceAnnotation("engine.drain"):
+                toks, emit, done = jax.device_get((toks, emit, done))  # repro-lint: allow[HS001] THE per-block drain the 0.047 syncs/token budget is built on
+            self.stats["host_syncs"] += 1
+            with jax.profiler.TraceAnnotation("engine.emit"):
+                for i, req in enumerate(self.slots):
+                    if req is None:
+                        continue
+                    row = toks[i][emit[i]]
+                    req.generated.extend(int(t) for t in row)
+                    self.stats["tokens"] += int(emit[i].sum())
+                    if done[i].any():
+                        req.done = True
+                        self.finished.append(req)
+                        self.slots[i] = None
+                        self._return_pages(i)
 
     def step(self):
         """Admit new requests, then decode one block for all active slots.
@@ -906,14 +962,15 @@ class ServingEngine:
         wait) so the in-flight generation drains against its original
         snapshot; the swap applies at the first empty-slot boundary and
         admission resumes under the new version."""
-        self._maybe_apply_swap()
-        if self._pending_params is None:
-            self._fill_slots()
-        n_active = sum(s is not None for s in self.slots)
-        if n_active:
-            self._decode_block()
-            self._maybe_apply_swap()   # the block may have drained the pool
-        return n_active
+        with jax.profiler.TraceAnnotation("engine.step"):
+            self._maybe_apply_swap()
+            if self._pending_params is None:
+                self._fill_slots()
+            n_active = sum(s is not None for s in self.slots)
+            if n_active:
+                self._decode_block()
+                self._maybe_apply_swap()   # the block may have drained it
+            return n_active
 
     def run(self, max_steps: int = 10_000):
         steps = 0

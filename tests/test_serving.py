@@ -384,3 +384,154 @@ def test_eos_frees_slot(setup):
                         max_new_tokens=8, eos_id=first))
     done = eng2.run()
     assert len(done[0].generated) <= 8
+
+
+SCOPES = ("embed", "layers", "attention", "mlp", "head", "sample", "pages")
+
+
+def _op_scopes(hlo_text):
+    """(opcode, innermost named scope or "other", whether the op is the
+    layer scan's own, output shape) of every instruction of a compiled HLO
+    module that carries a metadata op_name."""
+    import re
+
+    out = []
+    for shape, op, path in re.findall(
+            r"= (\S+) ([\w-]+)\(.*?op_name=\"([^\"]*)\"", hlo_text):
+        inner = [p for p in path.split("/") if p in SCOPES]
+        # an op of the layer scan itself: ".../layers/while/body/<op>"
+        own = path.rsplit("/", 1)[0].endswith("/layers/while/body")
+        out.append((op, inner[-1] if inner else "other", own, shape))
+    return out
+
+
+@pytest.mark.parametrize("page_size", [0, 16])
+def test_programs_carry_named_scopes(setup, page_size):
+    cfg, fns, params = setup
+    eng = ServingEngine(cfg, fns, params,
+                        EngineConfig(max_batch=2, max_len=64,
+                                     decode_block=2, page_size=page_size))
+    b, lb = 2, 16
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)
+    page_ops = {k: i32(b) for k in ("pf_entry", "pf_n", "pf_store",
+                                    "pf_store_n")}
+    progs = {
+        "decode": eng._engine_step.lower(eng.params, eng.cache, eng.state),
+        "prefill": eng._prefill.lower(
+            eng.params, eng.cache, eng.state, i32(b, lb), i32(b),
+            jnp.zeros((b,), bool), jnp.zeros((b,), jnp.float32), i32(b),
+            i32(b), i32(b), page_ops),
+    }
+    want = set(SCOPES) - ({"pages"} if not page_size else set())
+    for name, lowered in progs.items():
+        ops = _op_scopes(lowered.compile().as_text())
+        assert want <= {sc for _, sc, _, _ in ops}, name
+        # the layer scan's own slices and write-backs sit under `layers`
+        # and in no child scope
+        scan = {(op, sc) for op, sc, own, _ in ops if own}
+        assert ("dynamic-slice", "layers") in scan, name
+        assert {sc for _, sc in scan} == {"layers"}, name
+        if name == "decode":
+            assert ("dynamic-update-slice", "layers") in scan
+        if name == "decode" and page_size:
+            # the paged decode gathers each row's whole page table into a
+            # dense row: every op of that shape sits under `attention`
+            (rows, mp), kp = eng.cache["ptab"].shape, eng.cache["kp"].shape
+            table = f"[{rows},{mp},{kp[2]},{kp[3]},{kp[4]}]"
+            gather = {(op, sc) for op, sc, _, shape in ops if table in shape}
+            assert ("select", "attention") in gather, gather
+            assert {sc for _, sc in gather} == {"attention"}, gather
+
+
+def test_prefill_counters_for_a_known_admission(setup):
+    cfg, fns, params = setup
+    eng = ServingEngine(cfg, fns, params,
+                        EngineConfig(max_batch=4, max_len=64))
+    for uid, n in enumerate((5, 10, 20)):           # buckets 16, 16, 32
+        eng.submit(Request(uid=uid, prompt=np.arange(n, dtype=np.int32),
+                           max_new_tokens=3))
+    eng.step()
+    s = eng.stats
+    assert (s["prefill_calls"], s["prefill_rows"]) == (2, 3)
+    assert s["prefill_tokens"] == 5 + 10 + 20
+    assert s["prefill_slot_tokens"] == 4 * 16 + 4 * 32
+    for gone in ("decode_blocks", "exported_slots", "imported_slots"):
+        assert gone not in s
+
+
+def test_request_stamps_are_ordered(setup):
+    cfg, fns, params = setup
+    eng = ServingEngine(cfg, fns, params,
+                        EngineConfig(max_batch=2, max_len=64))
+    rng = np.random.default_rng(3)
+    given = Request(uid=99, prompt=np.arange(4, dtype=np.int32),
+                    max_new_tokens=4, arrival=0.0)
+    eng.submit(given)
+    for uid in range(4):
+        eng.submit(Request(uid=uid, max_new_tokens=int(rng.integers(1, 9)),
+                           prompt=rng.integers(0, cfg.vocab_size, size=int(
+                               rng.integers(3, 40))).astype(np.int32)))
+    done = eng.run()
+    assert len(done) == 5 and given.arrival == 0.0
+    for r in done:
+        assert r.arrival <= r.admitted_at <= r.first_token_at, r.uid
+
+
+def _engine_spans(logdir):
+    """[(name, start ns, end ns)] of the `engine.*` host spans of the
+    profiler trace written under `logdir`."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    out = []
+    for path in glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True):
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                out += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                        for line in plane.lines for ev in line.events
+                        if ev.name.startswith("engine.")]
+    return out
+
+
+@pytest.mark.parametrize("page_size", [0, 16])
+def test_engine_spans_nest_in_a_profiler_trace(setup, tmp_path, page_size):
+    cfg, fns, params = setup
+    eng = ServingEngine(cfg, fns, params,
+                        EngineConfig(max_batch=2, max_len=64,
+                                     decode_block=2, page_size=page_size))
+    for uid, n in enumerate((5, 20)):
+        eng.submit(Request(uid=uid, prompt=np.arange(n, dtype=np.int32),
+                           max_new_tokens=4))
+    eng.step()                                    # compile outside the trace
+    eng.submit(Request(uid=2, prompt=np.arange(7, dtype=np.int32),
+                       max_new_tokens=4))
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run()
+    spans = _engine_spans(tmp_path)
+    parents = {"engine.fill": {"engine.step"},
+               "engine.prefill": {"engine.fill"},
+               "engine.decode_block": {"engine.step"},
+               "engine.drain": {"engine.fill", "engine.decode_block"},
+               "engine.emit": {"engine.fill", "engine.decode_block"}}
+    assert {name for name, _, _ in spans} == {"engine.step"} | set(parents)
+    for name, start, end in spans:
+        if name in parents:
+            assert any(p in parents[name] and s <= start and end <= e
+                       for p, s, e in spans), (name, start, end)
+
+
+def test_launcher_admission_summary_reads_counters_and_stamps():
+    from types import SimpleNamespace as NS
+
+    from repro.launch.serve import admission_summary
+
+    stats = {"prefill_calls": 2, "prefill_rows": 3, "prefill_tokens": 35,
+             "prefill_slot_tokens": 4 * 16 + 4 * 32}
+    done = [NS(arrival=0.0, admitted_at=0.01 * k,
+               first_token_at=0.01 * k + 0.002) for k in range(11)]
+    line = admission_summary(stats, done)
+    assert "2 prefill calls, 1.5 rows/call" in line
+    assert "18.2% of prefill token rows useful" in line
+    assert "p90 queue wait 90.0 ms" in line
+    assert "admission to first token 2.0 ms" in line
