@@ -42,6 +42,9 @@ SERVE = dict(slots=32, max_len=1024, decode_block=8, requests=64,
              min_prompt=16, max_prompt=512, new_tokens=32, page_size=16)
 TRAIN = dict(batch=8, seq_len=1024, steps=4, drain_every=2, pods=2,
              inner_steps=2, rounds=2)
+# The paged decode of the MiniCPM-2B batch cell: 36 MHA heads of 64.
+PAGED_MHA = dict(arch="minicpm-2b", slots=32, pages=128, page_size=16,
+                 pool_pages=1024)
 # Four-chip workload: prompts fit one prefill bucket, so each replica
 # compiles few programs.
 PLANE = dict(slots=8, max_len=128, decode_block=8, requests=16,
@@ -126,10 +129,13 @@ def run_serving(sz=SERVE, builds=None):
     builds = builds or serve.build_models([ARCH], args.full)
     cfg, fns, params = builds[0]
     plist = prompts(sz, cfg.vocab_size, sz["requests"])
+    # paged decode runs its kernel on the TPU; the dense engine runs the
+    # dense kernel, which folds the same chunks of the same cache contents
+    pcfg = replace(cfg, attn_impl="pallas")
     out = {}
     for layout, extra in (("dense", ()),
                           ("paged", ("--page-size", str(sz["page_size"])))):
-        eng = ServingEngine(cfg, fns, params,
+        eng = ServingEngine(pcfg, fns, params,
                             serve.engine_config(serve_args(sz, *extra)))
         got, marks, dt = serve_waves(eng, plist, sz["new_tokens"])
         assert marks[0] >= 0 and marks[-1] == marks[0], \
@@ -194,11 +200,13 @@ def _close(name, got, want, tol, why):
           f"(worst {err:.3g} of the bound; {why})")
 
 
-def run_kernels(builds, sz=SERVE, tsz=TRAIN, interpret=False):
+def run_kernels(builds, sz=SERVE, tsz=TRAIN, msz=PAGED_MHA,
+                interpret=False):
     from repro.kernels.decode_attention.kernel import decode_attention_fwd
     from repro.kernels.decode_attention.ops import decode_attention
     from repro.kernels.decode_attention.paged import (
-        paged_decode_attention_fwd, paged_decode_attention_reference)
+        pages_per_block, paged_decode_attention_fwd,
+        paged_decode_attention_reference)
     from repro.kernels.decode_attention.ref import decode_attention_reference
     from repro.kernels.flash_attention.ops import flash_attention
     from repro.kernels.flash_attention.ref import attention_reference
@@ -242,6 +250,29 @@ def run_kernels(builds, sz=SERVE, tsz=TRAIN, interpret=False):
     phase("kernel", "paged decode bitwise equal to dense decode at block_k "
           f"= page size {ps}")
 
+    # MiniCPM widths: many MHA heads, blocks of several pages, a row at
+    # kv_len 0, one ending mid-page, one on a block's end, the full table
+    mcfg = registry.get_config(msz["arch"])
+    mb, mmp, mps, mpool = (msz[k] for k in ("slots", "pages", "page_size",
+                                             "pool_pages"))
+    mh, mhkv, mdh = mcfg.n_heads, mcfg.n_kv_heads, mcfg.hd
+    mk = jax.random.split(jax.random.PRNGKey(2), 5)
+    mq = jax.random.normal(mk[0], (mb, mh, mdh), mcfg.cdtype)
+    mkp, mvp = (jax.random.normal(k, (mpool + 1, mps, mhkv, mdh),
+                                  mcfg.cdtype) for k in mk[1:3])
+    block = pages_per_block(mps, mmp) * mps
+    mlens = jax.random.randint(mk[3], (mb,), 0, mmp * mps + 1).at[:4].set(
+        jnp.asarray([0, 5 * mps + 7, block, mmp * mps]))
+    mtab = jax.random.randint(mk[4], (mb, mmp), 0, mpool)
+    mtab = jnp.where(jnp.arange(mmp)[None] < -(-mlens[:, None] // mps), mtab,
+                     mpool).astype(jnp.int32)
+    mpaged = paged_decode_attention_fwd(mq, mkp, mvp, mtab, mlens,
+                                        interpret=interpret)
+    _close(f"paged decode {mcfg.name} widths ({mh} MHA heads)", mpaged,
+           paged_decode_attention_reference(mq, mkp, mvp, mtab, mlens), 2e-2,
+           bf16)
+    assert not np.asarray(mpaged[0], np.float32).any(), "kv_len 0 row != 0"
+
     tb, ts = tsz["batch"], tsz["seq_len"]
     fq = jax.random.normal(ks[5], (tb, ts, h, dh), dt)
     fk = jax.random.normal(ks[6], (tb, ts, hkv, dh), dt)
@@ -260,21 +291,19 @@ def run_kernels(builds, sz=SERVE, tsz=TRAIN, interpret=False):
            "same f32 recurrence; a rounding difference per step decays "
            "by a <= 0.999, so it compounds to at most ~1e3 ulp")
 
-    # the engine with the decode kernels on its hot path
+    # the dense engine with the decode kernel on its hot path (the paged
+    # engine runs its kernel whatever attn_impl says: run_serving)
     pcfg = replace(cfg, attn_impl="pallas")
     plist = prompts(sz, cfg.vocab_size, sz["slots"], seed=1)
-    for layout, extra in (("dense", ()),
-                          ("paged", ("--page-size", str(ps)))):
-        eng = ServingEngine(pcfg, fns, params,
-                            serve.engine_config(serve_args(sz, *extra)))
-        got, _, _ = serve_waves(eng, plist, sz["new_tokens"], waves=1)
-        ref = ServingEngine(cfg, fns, params,
-                            serve.engine_config(serve_args(sz, *extra)))
-        want, _, _ = serve_waves(ref, plist, sz["new_tokens"], waves=1)
-        same = sum(got[u] == want[u] for u in got)
-        phase("kernel", f"engine attn_impl=pallas {layout}: {len(got)} "
-              f"requests completed; greedy tokens equal to the ref path "
-              f"for {same}/{len(got)}")
+    args = serve.engine_config(serve_args(sz))
+    got, _, _ = serve_waves(ServingEngine(pcfg, fns, params, args), plist,
+                            sz["new_tokens"], waves=1)
+    want, _, _ = serve_waves(ServingEngine(cfg, fns, params, args), plist,
+                             sz["new_tokens"], waves=1)
+    same = sum(got[u] == want[u] for u in got)
+    phase("kernel", f"engine attn_impl=pallas dense: {len(got)} requests "
+          f"completed; greedy tokens equal to the ref path for "
+          f"{same}/{len(got)}")
 
 
 # --------------------------------------------------------------------------

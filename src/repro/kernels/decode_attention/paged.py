@@ -9,21 +9,25 @@ tracks *allocated* pages, not max_len: the pool is sized for live tokens
 across the whole batch, and prefix-shared pages appear in several rows'
 tables at once.
 
-Grid = (B, max_pages) with the page axis innermost/sequential; each step
-takes one (page_size, Hkv, dh) page of K and V, all KV heads at once, as
-the dense kernel takes a cache block. kv_lens and the page table ride in as
-scalar-prefetch operands (`PrefetchScalarGridSpec`), so the k/v index_map
-resolves the physical page id *before* the DMA is issued — the pool is
-streamed through the dense kernel's own body (`kernel.decode_kernel`) and
-VMEM scratch. `pl.when` skips pages past
-a row's kv_len, and because every unmapped entry aliases the one trash
-page, the pipeline's consecutive-identical-block dedup collapses the
-unmapped tail into a single redundant fetch.
+Grid = (B, cdiv(max_pages, ppb)), blocks of ppb = `pages_per_block` pages
+(BLOCK_TOKENS positions) with the block axis innermost/sequential. The
+pool is passed ppb times, one input slot per page of a block, and each
+slot's index_map reads the physical page id from a fetch schedule that
+rides in as a scalar-prefetch operand with kv_lens
+(`PrefetchScalarGridSpec`): the pipeline fetches the next step's pages
+while this step folds, across row boundaries too. A dead slot (at or past
+its row's kv_len) repeats the page it already holds, and the pipeline skips
+a copy whose block index did not change, so only live pages are fetched.
+(The pipeline's copies are used rather than manual DMAs because Mosaic
+refuses a manual copy of a page whose (Hkv, dh) is not a multiple of the
+tiling, e.g. 36 heads of 64.) Each page is folded through the dense
+kernel's `kernel.fold_block`, all KV heads at once.
 
 Masking is bit-compatible with the dense kernel: scores past kv_len go to
--1e30 before the exp, so trash-page garbage contributes exact 0.0 to the
-softmax, and paged output == dense-kernel output (at block_k = page_size)
-bitwise for the same cache contents.
+-1e30 before the exp, so trash-page or stale contents contribute exact 0.0
+to the softmax, and since both kernels fold the same 16-position chunks in
+order, paged output == dense-kernel output bitwise for the same cache
+contents.
 """
 from __future__ import annotations
 
@@ -34,14 +38,67 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .kernel import decode_kernel, scratch_shapes
+from .kernel import (finalize, fold_block, group_heads, init_state,
+                     scratch_shapes, ungroup_heads)
 from .ref import decode_attention_reference
 
+BLOCK_TOKENS = 128     # positions fetched from the pool per grid step
 
-def _paged_decode_kernel(lens_ref, ptab_ref, *refs, **kw):
-    # the page table only steers the k/v index_map (the DMA); the block
-    # arithmetic is the dense kernel's
-    decode_kernel(lens_ref, *refs, **kw)
+
+def pages_per_block(page_size: int, max_pages: int) -> int:
+    """Pages fetched per grid step: BLOCK_TOKENS positions, at least one
+    page, at most the whole table. The dense kernel at block_k = this many
+    pages' positions folds the same blocks in the same order."""
+    return max(1, min(max_pages, BLOCK_TOKENS // page_size))
+
+
+def fetch_schedule(page_table, kv_lens, page_size: int, ppb: int):
+    """(B * nblk * ppb,) int32: the pool page that input slot i of grid step
+    (row, block) holds. A live page (below its row's kv_len) is its table
+    entry; a dead one repeats what that slot held at the step before (the
+    first live page of the slot, before any), so the pipeline, which skips
+    a block whose index did not change, fetches live pages only — a row
+    at kv_len 0 fetches nothing."""
+    b, max_pages = page_table.shape
+    nblk = pl.cdiv(max_pages, ppb)
+    tab = jnp.pad(page_table, ((0, 0), (0, nblk * ppb - max_pages)),
+                  mode="edge").reshape(b * nblk, ppb)
+    live_pages = pl.cdiv(kv_lens, page_size)[:, None]
+    live = (jnp.arange(nblk * ppb)[None] < live_pages).reshape(b * nblk, ppb)
+    step = jnp.arange(b * nblk, dtype=jnp.int32)[:, None]
+    last = jax.lax.cummax(jnp.where(live, step, -1), axis=0)
+    first = jax.lax.cummin(jnp.where(live, step, b * nblk), axis=0,
+                           reverse=True)
+    src = jnp.clip(jnp.where(last >= 0, last, first), 0, b * nblk - 1)
+    return jnp.take_along_axis(tab, src, axis=0).reshape(-1)
+
+
+def _paged_decode_kernel(lens_ref, fetch_ref, q_ref, *refs, ppb: int,
+                         sm_scale: float):
+    """Step (row, block): fold the block's live pages, one input slot per
+    page, through the dense kernel's `fold_block`."""
+    k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * ppb:]
+    bi = pl.program_id(0)
+    j = pl.program_id(1)
+    ps = k_refs[0].shape[1]
+
+    @pl.when(j == 0)
+    def _init():
+        init_state(acc_ref, m_ref, l_ref)
+
+    kv_len = lens_ref[bi]
+
+    @pl.when(j * ppb * ps < kv_len)        # ragged early-exit per row
+    def _compute():
+        for i in range(ppb):
+            fold_block(q_ref, k_refs[i], v_refs[i], acc_ref,
+                       m_ref, l_ref, k_start=(j * ppb + i) * ps,
+                       kv_len=kv_len, sm_scale=sm_scale)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        finalize(o_ref, acc_ref, l_ref, kv_len)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -55,32 +112,40 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, kv_lens, *,
     ps, hkv = k_pages.shape[1], k_pages.shape[2]
     max_pages = page_table.shape[1]
     assert h % hkv == 0
+    group = h // hkv
+    ppb = pages_per_block(ps, max_pages)
+    nblk = pl.cdiv(max_pages, ppb)
     kv_lens = jnp.broadcast_to(
         jnp.asarray(kv_lens, jnp.int32).reshape(-1), (b,))
-    page_table = page_table.astype(jnp.int32)
+    fetch = fetch_schedule(page_table.astype(jnp.int32), kv_lens, ps, ppb)
 
-    kernel = functools.partial(_paged_decode_kernel, block_k=ps, hkv=hkv,
-                               group=h // hkv, sm_scale=dh ** -0.5)
-    page_spec = pl.BlockSpec((1, ps, hkv, dh),
-                             lambda bi, pi, lens, ptab:
-                             (ptab[bi, pi], 0, 0, 0))
-    row_spec = pl.BlockSpec((1, h, dh),
-                            lambda bi, pi, lens, ptab: (bi, 0, 0))
+    def page_spec(i):
+        return pl.BlockSpec(
+            (1, ps, hkv, dh),
+            lambda bi, j, lens, fetch: (fetch[(bi * nblk + j) * ppb + i],
+                                        0, 0, 0))
+
+    row_spec = pl.BlockSpec((1, group, hkv, dh),
+                            lambda bi, j, lens, fetch: (bi, 0, 0, 0))
+    pages = [page_spec(i) for i in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, max_pages),
-        in_specs=[row_spec, page_spec, page_spec],
+        grid=(b, nblk),
+        in_specs=[row_spec, *pages, *pages],
         out_specs=row_spec,
-        scratch_shapes=scratch_shapes(h, dh),
+        scratch_shapes=scratch_shapes(group, hkv, dh),
     )
-    return pl.pallas_call(
-        kernel,
+    out = pl.pallas_call(
+        functools.partial(_paged_decode_kernel, ppb=ppb,
+                          sm_scale=dh ** -0.5),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, group, hkv, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(kv_lens, page_table, q, k_pages, v_pages)
+    )(kv_lens, fetch, group_heads(q, hkv), *[k_pages] * ppb,
+      *[v_pages] * ppb)
+    return ungroup_heads(out)
 
 
 def gather_pages(pool, page_table):

@@ -206,20 +206,23 @@ def attention_chunked(q, k, v, *, causal: bool = True,
 def attention(q, k, v, *, impl: str = "ref", page_table=None, **kw):
     if page_table is not None:
         # paged decode: k/v are (P+1, page_size, Hkv, dh) pools and
-        # page_table is the (B, max_pages) per-row physical map. The pallas
-        # kernel walks the table directly (cost tracks allocated pages);
-        # the ref fallback gathers the logical dense layout — positions
-        # >= kv_len mask to exact-zero probability either way, so paged ==
-        # dense bitwise for identical cache contents.
+        # page_table is the (B, max_pages) per-row physical map. On the TPU
+        # the kernel walks the table and fetches only each row's live pages;
+        # elsewhere the reference gathers the logical dense layout (and is
+        # the kernel's oracle). Positions >= kv_len mask to exact-zero
+        # probability either way, so paged == dense bitwise for identical
+        # cache contents on the same path. REPRO_DECODE_ATTN=interpret
+        # runs the kernel in interpret mode for a config with
+        # attn_impl="pallas", so CPU tests can reach it.
         assert q.shape[1] == 1 and kw.get("window") is None \
             and kw.get("kv_len") is not None
-        mode = os.environ.get("REPRO_DECODE_ATTN", "auto")
-        if impl == "pallas" and (mode == "interpret" or (
-                mode == "auto" and jax.default_backend() == "tpu")):
+        interpret = (impl == "pallas" and os.environ.get(
+            "REPRO_DECODE_ATTN") == "interpret")
+        if interpret or jax.default_backend() == "tpu":
             from repro.kernels.decode_attention.paged import \
                 paged_decode_attention
             return paged_decode_attention(q, k, v, page_table, kw["kv_len"],
-                                          interpret=mode == "interpret")
+                                          interpret=interpret)
         from repro.kernels.decode_attention.paged import gather_pages
         kw.pop("kv_block", None)
         return attention_ref(q, gather_pages(k, page_table),

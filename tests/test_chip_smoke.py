@@ -13,6 +13,8 @@ SERVE = ("dict(slots=4, max_len=128, decode_block=4, requests=8, "
          "min_prompt=4, max_prompt=40, new_tokens=6, page_size=16)")
 TRAIN = ("dict(batch=2, seq_len=32, steps=4, drain_every=2, pods=2, "
          "inner_steps=2, rounds=2)")
+PAGED_MHA = ("dict(arch='minicpm-2b', slots=4, pages=12, page_size=16, "
+             "pool_pages=48)")
 BUILDS = ("import chip_smoke as cs\n"
           "from repro.launch import serve\n"
           "builds = serve.build_models([cs.ARCH], full=False)\n")
@@ -41,7 +43,8 @@ def test_one_chip_phases_rehearsed():
     out = _python("-c", BUILDS + (
         f"cs.run_serving({SERVE}, builds)\n"
         f"cs.run_training({TRAIN}, full=False)\n"
-        f"cs.run_kernels(builds, {SERVE}, {TRAIN}, interpret=True)\n"),
+        f"cs.run_kernels(builds, {SERVE}, {TRAIN}, {PAGED_MHA}, "
+        "interpret=True)\n"),
         REPRO_DECODE_ATTN="interpret")
     lines = _phase_lines(out)
     assert "[serve] paged greedy tokens bitwise equal to dense" in lines

@@ -11,7 +11,8 @@ from repro.kernels.decode_attention import (decode_attention,
                                             gather_pages,
                                             paged_decode_attention,
                                             paged_decode_attention_reference)
-from repro.kernels.decode_attention.paged import paged_decode_attention_fwd
+from repro.kernels.decode_attention.paged import (paged_decode_attention_fwd,
+                                                 pages_per_block)
 from repro.kernels.flash_attention import attention_reference, flash_attention
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.rglru_scan import (rglru_scan, rglru_scan_associative,
@@ -188,18 +189,25 @@ class TestPagedDecodeAttention:
     pool; outputs must match the gather-to-dense oracle bitwise-closely and
     be exactly independent of trash-page / unmapped-pool garbage."""
 
-    @pytest.mark.parametrize("b,h,hkv,ps,mp,dh", [
-        (2, 8, 8, 16, 8, 64),   # MHA
-        (3, 8, 2, 32, 4, 128),  # GQA 4:1
-        (1, 4, 1, 64, 4, 64),   # MQA
+    @pytest.mark.parametrize("b,h,hkv,ps,mp,dh,lens", [
+        pytest.param(2, 8, 8, 16, 8, 64, None, id="2-8-8-16-8-64"),  # MHA
+        pytest.param(3, 8, 2, 32, 4, 128, None,
+                     id="3-8-2-32-4-128"),                          # GQA 4:1
+        pytest.param(1, 4, 1, 64, 4, 64, None, id="1-4-1-64-4-64"),  # MQA
+        # many-head MHA over blocks of 8 pages: an empty row, one ending
+        # mid-page, one on the first block's end, the full table
+        pytest.param(4, 12, 12, 16, 20, 64, (0, 16 * 5 + 7, 128, 320),
+                     id="mha12-ragged-multipage"),
     ])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    def test_sweep_vs_oracle(self, b, h, hkv, ps, mp, dh, dtype):
+    def test_sweep_vs_oracle(self, b, h, hkv, ps, mp, dh, lens, dtype):
         kq, kkv = jax.random.split(jax.random.PRNGKey(20))
         q = jax.random.normal(kq, (b, h, dh), dtype)
         kpool, vpool, ptab = _paged_setup(kkv, b, hkv, dh, ps, mp,
                                           pool_pages=b * mp + 3, dtype=dtype)
-        kv_len = (ps * mp) // 2 + 7             # scalar broadcasts
+        # a scalar broadcasts to every row
+        kv_len = (ps * mp) // 2 + 7 if lens is None else jnp.asarray(
+            lens, jnp.int32)
         out = paged_decode_attention_fwd(q, kpool, vpool, ptab, kv_len,
                                          interpret=True)
         ref = paged_decode_attention_reference(q, kpool, vpool, ptab,
@@ -207,6 +215,8 @@ class TestPagedDecodeAttention:
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(ref, np.float32),
             atol=_tol(dtype), rtol=_tol(dtype))
+        if lens is not None:
+            assert np.all(np.asarray(out[0]) == 0.0)  # empty row: zeros
 
     def test_ragged_lens_including_empty_row(self):
         b, h, hkv, ps, mp, dh = 4, 4, 2, 16, 8, 64
@@ -225,8 +235,9 @@ class TestPagedDecodeAttention:
     def test_trash_page_poison_is_bitwise_invariant(self):
         """Unmapped table entries alias the trash page; poisoning it (and
         every unreferenced pool page) to huge values must not change ANY
-        output bit — masking happens before the exp."""
-        b, h, hkv, ps, mp, dh = 2, 4, 2, 16, 6, 64
+        output bit — masking happens before the exp. The second row spans
+        two blocks of pages, the first leaves most of its block dead."""
+        b, h, hkv, ps, mp, dh = 2, 4, 2, 16, 12, 64
         pool_pages = 24
         kq, kkv = jax.random.split(jax.random.PRNGKey(22))
         q = jax.random.normal(kq, (b, h, dh))
@@ -250,19 +261,22 @@ class TestPagedDecodeAttention:
 
     def test_paged_matches_dense_kernel_on_same_logical_cache(self):
         """Gathering the paged pool to the dense layout and running the
-        dense kernel with one page per cache block gives bitwise the
-        result of the paged kernel directly: both fold the same blocks
-        through the same online-softmax code."""
-        b, h, hkv, ps, mp, dh = 2, 8, 2, 32, 4, 64
+        dense kernel at the paged kernel's block size (its pages per block
+        times the page size) gives bitwise the result of the paged kernel
+        directly: both fold the same blocks through the same online-softmax
+        code."""
+        b, h, hkv, ps, mp, dh = 3, 8, 2, 32, 8, 64
         kq, kkv = jax.random.split(jax.random.PRNGKey(23))
         q = jax.random.normal(kq, (b, h, dh))
         kpool, vpool, ptab = _paged_setup(kkv, b, hkv, dh, ps, mp,
                                           pool_pages=b * mp)
-        lens = jnp.asarray([ps * 3 + 9, ps * mp], jnp.int32)
+        block = pages_per_block(ps, mp) * ps
+        assert ps * mp == 2 * block                 # two blocks a row
+        lens = jnp.asarray([ps * 3 + 9, block, ps * mp], jnp.int32)
         from repro.kernels.decode_attention.kernel import decode_attention_fwd
         dense = decode_attention_fwd(q, gather_pages(kpool, ptab),
                                      gather_pages(vpool, ptab), lens,
-                                     block_k=ps, interpret=True)
+                                     block_k=block, interpret=True)
         paged = paged_decode_attention_fwd(q, kpool, vpool, ptab, lens,
                                            interpret=True)
         np.testing.assert_array_equal(np.asarray(paged), np.asarray(dense))

@@ -3,11 +3,13 @@
 Interpret mode (tests/test_kernels.py) checks the kernels' arithmetic but
 not what the TPU compiler accepts: block shapes against the tiling rules,
 VMEM use, 1-D values. These tests lower each kernel at the serving and
-training widths of suncatcher-lm-100m (and the RG-LRU width of
-recurrentgemma-2b) and compile it for one chip of a `v5e:2x2` topology,
-which the installed TPU compiler can do without a chip attached. Nothing
-runs. All of them live in this one file, behind module fixtures, so that
-only the worker that runs this file loads the TPU library.
+training widths of suncatcher-lm-100m (the paged decode kernel also at
+MiniCPM-2B's, and the RG-LRU width of recurrentgemma-2b) and compile it
+for one chip of a `v5e:2x2` topology, which the installed TPU compiler
+can do without a chip attached, and check that the serving engine's paged
+decode step, compiled for that chip, calls the kernel. Nothing runs. All
+of them live in this one file, behind module fixtures, so that only the
+worker that runs this file loads the TPU library.
 """
 import jax
 import jax.numpy as jnp
@@ -60,14 +62,50 @@ def test_decode_attention_compiles(sds):
                    cache, cache, sds((SLOTS,), jnp.int32))
 
 
-def test_paged_decode_attention_compiles(sds):
+def _compile_paged(sds, cfg, slots, pages, pool_pages):
     from repro.kernels.decode_attention.paged import paged_decode_attention
-    dt = LM.cdtype
-    pages = MAX_LEN // PAGE
-    pool = sds((SLOTS * pages + 1, PAGE, LM.n_kv_heads, LM.hd), dt)
+    dt = cfg.cdtype
+    pool = sds((pool_pages + 1, PAGE, cfg.n_kv_heads, cfg.hd), dt)
     _assert_kernel(paged_decode_attention,
-                   sds((SLOTS, 1, LM.n_heads, LM.hd), dt), pool, pool,
-                   sds((SLOTS, pages), jnp.int32), sds((SLOTS,), jnp.int32))
+                   sds((slots, 1, cfg.n_heads, cfg.hd), dt), pool, pool,
+                   sds((slots, pages), jnp.int32), sds((slots,), jnp.int32))
+
+
+def test_paged_decode_attention_compiles(sds):
+    pages = MAX_LEN // PAGE
+    _compile_paged(sds, LM, SLOTS, pages, SLOTS * pages)
+
+
+def test_paged_decode_attention_compiles_at_minicpm_widths(sds):
+    """The batch cell's decode: 32 slots of 128 pages on a 1024-page pool,
+    36 MHA heads of 64, bf16."""
+    _compile_paged(sds, registry.get_config("minicpm-2b"), 32, 128, 1024)
+
+
+def test_paged_decode_step_runs_the_kernel(sds, monkeypatch):
+    """The serving engine's paged decode step, compiled for the chip, calls
+    the paged kernel and never gathers the rows' page tables into the
+    dense (B, max_pages, page_size, Hkv, dh) layout. The kernel is chosen
+    by `jax.default_backend()`, which here names the CPU, so the test
+    names the TPU for the lowering."""
+    from repro.serving import EngineConfig, ServingEngine
+
+    cfg = registry.get_config("suncatcher-lm-100m", n_layers=2,
+                              vocab_size=1024)
+    fns = registry.model_fns(cfg)
+    params = jax.eval_shape(lambda k: fns.init(k, cfg), jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params)
+    eng = ServingEngine(cfg, fns, params,
+                        EngineConfig(max_batch=4, max_len=128, decode_block=2,
+                                     page_size=PAGE))
+    on_chip = lambda t: jax.tree.map(lambda a: sds(a.shape, a.dtype), t)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = jax.jit(eng._engine_step_impl).lower(
+        on_chip(eng.params), on_chip(eng.cache), on_chip(eng.state)
+    ).compile().as_text()
+    (b, mp), kp = eng.cache["ptab"].shape, eng.cache["kp"].shape
+    assert "tpu_custom_call" in hlo
+    assert f"[{b},{mp},{kp[2]},{kp[3]},{kp[4]}]" not in hlo
 
 
 def test_flash_attention_compiles(sds):
