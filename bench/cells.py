@@ -7,10 +7,12 @@ one of them, or to one per-layer metric, sits in a file of its own:
   bench/traffic/<traffic>.json      the mix, and the driver and engine it runs under
   bench/drivers/<driver>.py         how a window drives the program
   bench/references/<family>.py      the configuration's plain reference
+  bench/counts/<family>.py          its operations and bytes, from shapes
   bench/limits/<cell>.json          the limits that decide `correct`
   bench/metrics/<metric>.py         one reader per per-layer metric
 
-so a later change adds a cell, a mix or a metric by adding files and entries.
+so a later change adds a cell, a mix, a model family or a metric by adding
+files and entries.
 """
 from __future__ import annotations
 
@@ -67,6 +69,10 @@ def driver(kind: str):
 
 def reference(family: str):
     return importlib.import_module(f"bench.references.{family}")
+
+
+def counts(family: str):
+    return importlib.import_module(f"bench.counts.{family}")
 
 
 def metric_reader(metric: str, root: Path = ROOT):

@@ -1,10 +1,7 @@
-"""Operations and bytes that the work needs, computed from shapes.
-
-Counts are of the model, not of any implementation: no padding, no
-recomputation, and the bytes a step must move whatever implements it
-(weights once per step at bfloat16, the live keys and values of the rows
-that decode). A multiply-add is 2 operations.
-"""
+"""Counts of the `dense_lm` family: a decoder of `n_layers` blocks, each
+attention with `n_heads` query and `n_kv_heads` key/value heads of
+`head_dim` and a three-matrix MLP of `d_ff`, and a tied embedding and head
+(bench/counts/__init__.py says what a family file gives)."""
 from __future__ import annotations
 
 BF16 = 2    # bytes
@@ -68,9 +65,3 @@ def decode_substep(cfg: dict, contexts) -> tuple[int, int]:
     kv = kv_bytes_per_token(cfg)
     nbytes = weight_bytes(cfg) + sum((c + 1) * kv for c in contexts)
     return flops, nbytes
-
-
-def least_time_s(flops: float, nbytes: float, peak: dict) -> float:
-    """The roofline: the larger of operations over peak rate and bytes over
-    peak bandwidth."""
-    return max(flops / peak["bf16_flops"], nbytes / peak["hbm_bytes_per_s"])

@@ -8,6 +8,12 @@ slot ages are staggered; then measure for `--seconds`. After the window:
 read the device's peak memory, free the engine, and compare a sample of the
 finished requests with the plain reference (bench/references/).
 
+Besides the times of its own loop, a run keeps what the program records
+about itself: the engine's `stats` counters and `trace_count()` at the
+window's open and close, the requests' `admitted_at` stamps, and, traced,
+the device time of each program by named scope (bench/scopes.py). The run
+context that bench/metrics/ reads holds their reductions.
+
 Traffic keys read here (besides the generator's): `engine` (EngineConfig
 fields: slots, max_len, decode_block, page_size, pool_pages, prefix_cache,
 min_bucket), `warm_s`, `trace_s`, `check.sample`.
@@ -24,7 +30,8 @@ from dataclasses import dataclass
 import jax
 import numpy as np
 
-from bench import cells, e2e, flops, generator, trace_reduce
+from bench import cells, e2e, generator, scopes, trace_reduce
+from bench.counts import least_time_s
 from bench.e2e import Timeline
 
 clock = time.perf_counter
@@ -84,7 +91,8 @@ def engine_config(eng: dict):
 # --------------------------------------------------------------------------
 class Tracer:
     """Profiler on for [start_at, stop_at) of the window, with host spans
-    around the benchmark's calls into the engine. Off: no spans at all."""
+    around the steps of the benchmark's own loop (`bench.*`; the program
+    opens its own, `engine.*`, inside them). Off: no spans at all."""
 
     def __init__(self, on: bool, start_at: float, stop_at: float):
         self.on, self.start_at, self.stop_at = on, start_at, stop_at
@@ -95,17 +103,6 @@ class Tracer:
     def span(self, name):
         return jax.profiler.TraceAnnotation(name) if self.on \
             else contextlib.nullcontext()
-
-    def wrap(self, obj, attr, name):
-        """Put a host span around obj.attr (a call into the program)."""
-        fn = getattr(obj, attr, None)
-        if not self.on or fn is None:
-            return
-
-        def spanned(*a, **k):
-            with jax.profiler.TraceAnnotation(name):
-                return fn(*a, **k)
-        setattr(obj, attr, spanned)
 
     def next_event(self):
         """When the profiler next starts or stops (inf: never)."""
@@ -133,10 +130,15 @@ class Tracer:
         jax.profiler.stop_trace()
 
     def reduce(self):
+        """The trace reduction (bench/trace_reduce.py) with each program's
+        device time by named scope (bench/scopes.py) as `scopes`; None
+        untraced or where the trace holds no device operation."""
         if self.t_off is None:
             return None
         try:
-            return trace_reduce.reduce(trace_reduce.load(self.dir))
+            tr = scopes.load(self.dir)
+            red = trace_reduce.reduce(tr)
+            return red and dict(red, scopes=scopes.reduce(tr))
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
 
@@ -175,6 +177,11 @@ def _warm(eng, work, seed):
     eng.finished.clear()
 
 
+def snapshot(eng):
+    """The engine's counters, and how many programs it has compiled."""
+    return dict(eng.stats), eng.trace_count()
+
+
 def drive(eng, work, warm_s, seconds, tr):
     """Offer `work` from now; measure [now + warm_s, now + warm_s +
     seconds). The loop only records; `replay` does the arithmetic."""
@@ -185,10 +192,8 @@ def drive(eng, work, warm_s, seconds, tr):
     t_open, t_close = t0 + warm_s, t0 + warm_s + seconds
     tr.start_at += t_open
     tr.stop_at += t_open
-    tr.wrap(eng, "_fill_slots", "engine.fill")
-    tr.wrap(eng, "_decode_block", "engine.decode_block")
     i, n_req, refused = 0, len(work), 0
-    queue_open = None
+    queue_open = opened = None
 
     def submit(k, arrival):
         nonlocal refused
@@ -207,7 +212,7 @@ def drive(eng, work, warm_s, seconds, tr):
             break
         tr.tick(now)
         if queue_open is None and now >= t_open:
-            queue_open = len(eng.queue)
+            queue_open, opened = len(eng.queue), snapshot(eng)
         with tr.span("bench.submit"):
             if work.backlog:
                 while len(eng.queue) < work.backlog:
@@ -237,12 +242,40 @@ def drive(eng, work, warm_s, seconds, tr):
     tr.stop()
     return {"reqs": reqs, "arrivals": arrivals, "steps": steps,
             "t_open": t_open, "t_end": t_end, "refused": refused,
-            "queue": (queue_open, len(eng.queue))}
+            "queue": (queue_open, len(eng.queue)),
+            "opened": opened, "closed": snapshot(eng),
+            "traced": (tr.t_on, tr.t_off)}
+
+
+def queue_waits(reqs, arrivals, t_open, t_end):
+    """Seconds from scheduled arrival to admission of each request admitted
+    in [t_open, t_end)."""
+    return [r.admitted_at - a for r, a in zip(reqs, arrivals)
+            if getattr(r, "admitted_at", None) is not None
+            and t_open <= r.admitted_at < t_end]
+
+
+def counts_in_window(opened, closed):
+    """{counter: growth over the window} of every engine counter, from the
+    snapshots at the window's open and close; None where it never opened."""
+    if opened is None:
+        return None
+    return {k: closed[0][k] - v for k, v in opened[0].items()}
+
+
+def compiles_in_window(opened, closed):
+    """Programs compiled between the window's open and close, or None where
+    the window never opened or the engine cannot count them."""
+    if opened is None or min(opened[1], closed[1]) < 0:
+        return None
+    return closed[1] - opened[1]
 
 
 def replay(run_, cfgd, peak):
     """Timelines per request, and per step the roofline time and model
-    operations of the work it did, from the recorded token counts."""
+    operations of the work it did, from the recorded token counts, counted
+    by the configuration's family (bench/counts/<family>.py)."""
+    counts = cells.counts(cfgd["family"])
     reqs = run_["reqs"]
     tls = [Timeline(a) for a in run_["arrivals"]]
     have = [0] * len(reqs)
@@ -255,15 +288,15 @@ def replay(run_, cfgd, peak):
             tls[uid].deliveries.append((st.t1, n - k))
             p = len(reqs[uid].prompt)
             if k == 0:
-                st.model_flops += flops.prefill_flops(cfgd, p)
+                st.model_flops += counts.prefill_flops(cfgd, p)
             start = max(k, 1)
             for j in range(start, n):
                 per_sub.setdefault(j - start, []).append(p + j)
-                st.model_flops += flops.token_flops(cfgd, p + j)
+                st.model_flops += counts.token_flops(cfgd, p + j)
             have[uid] = n
         st.substeps = len(per_sub)
         st.least_s = sum(
-            flops.least_time_s(*flops.decode_substep(cfgd, c), peak)
+            least_time_s(*counts.decode_substep(cfgd, c), peak)
             for c in per_sub.values())
     return tls
 
@@ -320,6 +353,7 @@ def _serve(cfg, fns, cfgd, eng_cfg, work, seed, warm_s, seconds, tr, peak):
         attempted = sum(1 for tl in tls if any(
             t_open <= t <= t_end for t, _ in tl.deliveries))
     decode_steps = [s for s in in_win if s.n_active]
+    opened, closed = run_["opened"], run_["closed"]
     ctx = {
         "config": cfgd, "peak": peak, "trace": tr.reduce(),
         "decode_block": eng_cfg["decode_block"], "window_s": win,
@@ -329,6 +363,10 @@ def _serve(cfg, fns, cfgd, eng_cfg, work, seed, warm_s, seconds, tr, peak):
         "traced_blocks": len(traced),
         "traced_least_s": sum(s.least_s for s in traced),
         "queue_open_close": run_["queue"],
+        "queue_waits": queue_waits(run_["reqs"], run_["arrivals"], t_open,
+                                   t_end),
+        "counters": counts_in_window(opened, closed),
+        "compiles_in_window": compiles_in_window(opened, closed),
         "arrived": int(sum(t_open <= a < t_end for a in run_["arrivals"])),
         "completed": sum(1 for r, tl in zip(run_["reqs"], tls) if r.done
                          and t_open <= tl.deliveries[-1][0] <= t_end),
@@ -337,7 +375,7 @@ def _serve(cfg, fns, cfgd, eng_cfg, work, seed, warm_s, seconds, tr, peak):
                 for r in run_["reqs"] if r.done]
     return {"e2e": vals, "ctx": ctx, "attempted": attempted,
             "failed": run_["refused"], "memory_peak_bytes": int(mem),
-            "finished": finished, "t_open": t_open}
+            "finished": finished, "t_open": t_open, "record": run_}
 
 
 # --------------------------------------------------------------------------

@@ -2,9 +2,9 @@
 
 Every seed gets the same work. Lengths and inter-arrival gaps are drawn once
 from the mix's own `shape_seed`; the run's `--seed` only permutes them,
-within consecutive blocks of BLOCK requests, and draws the prompt token
-ids. So two seeds differ in order and content, never in how much work the
-window holds: any window of a few blocks holds the same lengths.
+within consecutive blocks of `permute_block` requests, and draws the prompt
+token ids. So two seeds differ in order and content, never in how much work
+the window holds: any window of a few blocks holds the same lengths.
 
 Mix keys read here:
   arrivals    {"kind": "poisson", "rate_per_s": r}  open loop at rate r, or
@@ -14,6 +14,9 @@ Mix keys read here:
               clipped to [lo, hi]
   table       number of (prompt, output) length pairs drawn (backlog mixes
               cycle through it)
+  permute_block
+              requests a seed permutes among (default BLOCK): the smaller,
+              the less a seed moves which requests share a fill
 """
 from __future__ import annotations
 
@@ -52,11 +55,16 @@ def _lognormal(rng, spec, n):
 BLOCK = 32
 
 
-def _block_permutation(rng, n):
+def block(traffic: dict) -> int:
+    """The mix's `permute_block`, else BLOCK."""
+    return int(traffic.get("permute_block", BLOCK))
+
+
+def _block_permutation(rng, n, k):
     """A permutation of range(n) that moves each index only within its
-    block of BLOCK."""
-    return np.concatenate([b + rng.permutation(min(BLOCK, n - b))
-                           for b in range(0, n, BLOCK)])
+    block of k."""
+    return np.concatenate([b + rng.permutation(min(k, n - b))
+                           for b in range(0, n, k)])
 
 
 def run_rng(seed: int, stream: int) -> np.random.Generator:
@@ -83,8 +91,8 @@ def make(traffic: dict, seed: int, horizon_s: float, vocab: int) -> Work:
         raise ValueError(f"unknown arrivals kind {arr['kind']!r}")
     plens = _lognormal(shape, traffic["prompt_len"], n)
     olens = _lognormal(shape, traffic["output_len"], n)
-    order = run_rng(seed, 0)
-    pick = _block_permutation(order, n)
+    order, k = run_rng(seed, 0), block(traffic)
+    pick = _block_permutation(order, n, k)
     return Work(prompt_lens=plens[pick], output_lens=olens[pick],
-                arrival_s=np.cumsum(gaps[_block_permutation(order, n)]),
+                arrival_s=np.cumsum(gaps[_block_permutation(order, n, k)]),
                 backlog=backlog, seed=seed, vocab=vocab)

@@ -4,4 +4,4 @@ from bench.readers import DECODE, ms_per_call
 
 
 def read(ctx):
-    return ms_per_call(ctx, DECODE, ctx["decode_block"])
+    return ms_per_call(ctx, DECODE, ctx.get("decode_block"))

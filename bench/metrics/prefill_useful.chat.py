@@ -4,7 +4,7 @@ growth of `prefill_slot_tokens` (max_batch x bucket per call)."""
 
 
 def read(ctx):
-    got = ctx.get("prefill_window")
-    if not got or not got["prefill_slot_tokens"]:
+    got = ctx.get("counters") or {}
+    if not got.get("prefill_slot_tokens"):
         return None
     return 100.0 * got["prefill_tokens"] / got["prefill_slot_tokens"]

@@ -1,6 +1,6 @@
 """Model operations of the prompt and output tokens the window processed
-(no padding, no recomputation; bench/flops.py), over the window times the
-chip's bf16 peak."""
+(no padding, no recomputation; bench/counts/ of the configuration's
+family), over the window times the chip's bf16 peak."""
 
 
 def read(ctx):

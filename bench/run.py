@@ -13,8 +13,9 @@ on stdout:
 
 With --trace 0 the metrics are the cell's end-to-end metrics; with
 --trace 1 a profiler trace of a few seconds inside the window gives its
-per-layer metrics, and `breakdown` lists the top device operations and
-the idle gaps by host span. `checks` holds each number compared with its
+per-layer metrics, and `breakdown` lists the top device operations, the
+idle gaps by host span and the decode program's time per sub-step by
+named scope (`scopes`). `checks` holds each number compared with its
 limit; the same lines close stderr.
 
 Without an accelerator, or with fewer chips than the cell asks for, it
@@ -100,7 +101,7 @@ def peaks_for(kind: str) -> dict:
 def run_cell(cell, seed: int, seconds: float, trace: bool, devs,
              peak: dict, t_start: float, control: bool = False) -> dict:
     """One run of `cell` on `devs`; returns the result object."""
-    from bench import cells
+    from bench import cells, scopes
 
     drv = cells.driver(cell.traffic["driver"])
     out = drv.run(cell, seed, seconds, trace, t_start, peak, control)
@@ -130,6 +131,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devs,
         device["window_s"] = red["window_s"]
         res["breakdown"] = {"device_ops": red["device_ops"],
                             "idle_gaps": red["idle_gaps"]}
+        by_scope = scopes.decode_ms(out["ctx"])
+        if by_scope:
+            res["breakdown"]["scopes"] = by_scope
     res["check_info"] = out["check_info"]
     res["checks"] = checks
     return res

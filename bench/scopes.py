@@ -1,6 +1,8 @@
 """Device time by the program's named scopes, from a profiler trace.
 
-The program names its parts with `jax.named_scope` (`SCOPES`); XLA keeps the
+The program names its parts with `jax.named_scope`; `program_scopes` reads
+every name it gives there, as a string literal, from the program's source,
+so a scope the program adds is charged without a change here. XLA keeps the
 names in each instruction's `op_name` metadata, a path such as
 `jit(_engine_step_impl)/while/body/layers/while/body/attention/dot_general`.
 `load` reads a trace directory into bench/trace_reduce.py's intervals, each
@@ -18,22 +20,26 @@ first. On the TPU the profiler's own `tf_op` stat is empty for such
 fusions, so a reduction by `tf_op` charges the paged decode's gather of
 each row's page table and its f32 convert to `other`.
 
-The benchmark does not read this yet: bench/program_trace.py runs a cell
-with it, and bench/metrics/decode_{scan_self,attention}_ms.*.py read its
-totals through `ms_per_substep`.
+Every traced run reads it: the serving driver's `Tracer.reduce` adds
+`reduce`'s totals to the trace reduction as `scopes`, bench/run.py puts
+`decode_ms` in the result's `breakdown`, and readers such as
+bench/metrics/decode_{scan_self,attention}_ms.*.py take one scope's time
+through `ms_per_substep`.
 """
 from __future__ import annotations
 
+import ast
 import bisect
+import functools
 import glob
+import importlib.util
 import os
 from collections import defaultdict, deque
 from dataclasses import dataclass
+from pathlib import Path
 
 from bench import trace_reduce
 from bench.readers import DECODE
-
-SCOPES = ("embed", "layers", "attention", "mlp", "head", "sample", "pages")
 
 
 @dataclass
@@ -41,13 +47,53 @@ class Op(trace_reduce.Interval):
     path: str = ""           # the op's HLO op_name
 
 
-def scope(path: str) -> str:
-    """The innermost of SCOPES among the parts of a name path (a part may
-    be wrapped by a transformation, as in `jvp(mlp)`), or "other"."""
+def _scope_calls(src: str | None):
+    """(file, call) of every `named_scope(...)` call in the source under
+    `src` (default: the program's package, `repro`)."""
+    roots = [src] if src else \
+        importlib.util.find_spec("repro").submodule_search_locations
+    for root in roots:
+        for path in sorted(Path(root).rglob("*.py")):
+            for node in ast.walk(ast.parse(path.read_bytes())):
+                if isinstance(node, ast.Call) and node.args:
+                    fn = node.func
+                    name = fn.attr if isinstance(fn, ast.Attribute) \
+                        else getattr(fn, "id", "")
+                    if name == "named_scope":
+                        yield path, node
+
+
+def _literal(call):
+    arg = call.args[0]
+    return arg.value if isinstance(arg, ast.Constant) and \
+        isinstance(arg.value, str) else None
+
+
+@functools.cache
+def program_scopes(src: str | None = None) -> frozenset:
+    """Every name the program's source under `src` (default: the program's
+    package, `repro`) gives `jax.named_scope` as a string literal."""
+    return frozenset(name for _, call in _scope_calls(src)
+                     if (name := _literal(call)) is not None)
+
+
+def unread_scopes(src: str | None = None) -> list[str]:
+    """`file:line` of each `named_scope` call under `src` whose name is not
+    a string literal: `program_scopes` cannot read it, so its ops would be
+    charged to the scope around it."""
+    return [f"{path}:{call.lineno}" for path, call in _scope_calls(src)
+            if _literal(call) is None]
+
+
+def scope(path: str, names=None) -> str:
+    """The innermost of the program's scope names (`names`, default
+    `program_scopes()`) among the parts of a name path (a part may be
+    wrapped by a transformation, as in `jvp(mlp)`), or "other"."""
+    names = program_scopes() if names is None else names
     for part in reversed(path.split("/")):
         while part.endswith(")") and "(" in part:
             part = part[part.index("(") + 1:-1]
-        if part in SCOPES:
+        if part in names:
             return part
     return "other"
 
@@ -246,3 +292,13 @@ def ms_per_substep(ctx, name):
     if not hit or not hit[0] or not got:
         return None
     return sum(got) / hit[0] / ctx["decode_block"] * 1e3
+
+
+def decode_ms(ctx):
+    """[[scope, ms per decode sub-step], ...] of the decode-block program,
+    longest first; None where the trace reduction holds no scope of it."""
+    red = (ctx.get("trace") or {}).get("scopes", {})
+    seen = {name for k, v in red.items() if DECODE in k for name in v}
+    got = [[name, ms] for name in seen
+           if (ms := ms_per_substep(ctx, name)) is not None]
+    return sorted(got, key=lambda kv: -kv[1]) or None

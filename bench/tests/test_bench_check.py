@@ -1,20 +1,49 @@
 """`correct` on a tiny cell: sound runs pass; the fp8 control and each fault
-the serving cells can have come out as not correct."""
+the serving cells can have come out as not correct.
+
+The cells are every workload of BENCHMARK.json that the serving driver
+runs, each cut to its configuration's bench/tests/tiny_sizes/<config>.json:
+a new serving cell gets these tests by its entry and that file alone. A
+cell under another driver brings its control and faults in a test file of
+its own, bench/tests/test_bench_check_<driver>.py."""
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from bench import cells
 from bench.drivers import serve_engine
 from bench.tests import tiny
 from repro.serving import ServingEngine
 
-CELLS = ["sun100m.chat", "minicpm2b.batch"]
+WORKLOADS = cells.load_benchmark()["workloads"]
+DRIVERS = {w["name"]: cells.resolve(w["name"]).traffic["driver"]
+           for w in WORKLOADS}
+CELLS = [name for name, d in DRIVERS.items() if d == "serve_engine"]
+
+
+def test_every_cell_has_tiny_sizes():
+    assert tiny.missing_sizes({w["config"] for w in WORKLOADS}) == []
+
+
+def test_a_config_without_tiny_sizes_fails_the_check(tmp_path):
+    (tmp_path / "a.json").write_text("{}")
+    assert tiny.missing_sizes(["a", "b"], tmp_path) == ["b"]
+
+
+def test_every_cell_has_a_check_test():
+    here = Path(__file__).parent
+    assert [name for name, d in DRIVERS.items() if d != "serve_engine" and
+            not (here / f"test_bench_check_{d}.py").is_file()] == []
 
 
 @pytest.mark.parametrize("name", CELLS)
 def test_sound_run_is_correct_and_control_is_not(name):
-    res = tiny.run(tiny.cell(name), control=True)
+    # a 4 s window: on a loaded CPU 2 s can finish too few requests to
+    # compare the tokens asserted below
+    res = tiny.run(tiny.cell(name), seconds=4.0, control=True)
     info, checks = res["check_info"], res["checks"]
     # the program's own tokens hold every limit ...
     assert info["program_widest_gap"] <= checks["widest_gap"]["limit"], info

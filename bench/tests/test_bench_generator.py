@@ -53,7 +53,7 @@ def test_each_block_of_requests_holds_the_same_lengths(mix):
     t = _traffic(mix)
     a = generator.make(t, 11, 40.0, 1000)
     b = generator.make(t, 12, 40.0, 1000)
-    k = generator.BLOCK
+    k = generator.block(t)
     for s in range(0, len(a) - k, k):
         assert sorted(a.prompt_lens[s:s + k]) == sorted(b.prompt_lens[s:s + k])
         assert sorted(a.output_lens[s:s + k]) == sorted(b.output_lens[s:s + k])

@@ -8,6 +8,7 @@ import sys
 import pytest
 
 from bench import cells
+from bench.tests import tiny
 
 ROOT = cells.ROOT
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -22,6 +23,8 @@ def test_cell_resolves_to_its_files(name):
     assert "setup_s" in [m["name"] for m in c.end_to_end]
     assert cells.driver(c.traffic["driver"]).run
     assert cells.reference(c.config["family"]).served_gaps
+    assert cells.counts(c.config["family"]).decode_substep
+    assert tiny.missing_sizes([c.config["name"]]) == []
     for m in c.per_layer:
         assert callable(cells.metric_reader(m["name"]))
         assert m["moves"] in [e["name"] for e in c.end_to_end]

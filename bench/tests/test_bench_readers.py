@@ -1,40 +1,65 @@
-"""Per-layer readers on a hand-made run context: known values, and nothing
-read where the trace holds nothing."""
+"""Per-layer readers on hand-made run contexts with a known answer, and
+nothing read where the context holds nothing.
+
+Every per-layer metric of BENCHMARK.json brings its known answer in a file
+of its own, bench/tests/answers/<metric>.json: a run context (`context`)
+and the value its reader has to give on it (`value`). A listed metric
+without one fails here; a new metric adds its file and edits no test."""
+import json
+from pathlib import Path
+
 import pytest
 
 from bench import cells
 
-RED = {"window_s": 4.0, "busy_s": 3.0,
-       "modules": {"jit__prefill_impl": [2, 0.5],
-                   "jit__engine_step_impl": [10, 2.0]}}
-CTX = {"trace": RED, "decode_block": 8, "window_s": 40.0,
-       "peak": {"bf16_flops": 2e14}, "model_flops": 8e14,
-       "occupancy": 0.5, "traced_blocks": 10, "traced_least_s": 0.5}
-WANT = {"prefill_ms.chat": 250.0, "decode_substep_ms.chat": 25.0,
-        "device_idle.chat": 25.0, "device_idle.batch": 25.0,
-        "decode_roofline": 25.0, "serve_mfu.batch": 10.0,
-        "occupancy.batch": 50.0}
-ALL = [m["name"] for m in cells.load_benchmark()["per_layer"]]
+ANSWERS = Path(__file__).parent / "answers"
+LISTED = cells.load_benchmark()["per_layer"]
+ALL = [m["name"] for m in LISTED]
+TRACED = [m["name"] for m in LISTED if m["source"] == "device_trace"]
+
+
+def missing_answers(names, where=ANSWERS):
+    """The metrics among `names` that have no answer file in `where`."""
+    return [n for n in names if not (where / f"{n}.json").is_file()]
+
+
+def answer(name):
+    return json.loads((ANSWERS / f"{name}.json").read_text())
 
 
 def test_every_metric_has_a_known_answer_here():
-    assert sorted(WANT) == sorted(ALL)
+    assert missing_answers(ALL) == []
+
+
+def test_a_listed_metric_without_an_answer_fails_the_check(tmp_path):
+    (tmp_path / "a_ms.chat.json").write_text("{}")
+    assert missing_answers(["a_ms.chat", "b_ms.chat"], tmp_path) == \
+        ["b_ms.chat"]
+    assert missing_answers(ALL + ["made_up_ms.chat"]) == ["made_up_ms.chat"]
 
 
 @pytest.mark.parametrize("name", ALL)
 def test_reader_value(name):
-    assert cells.metric_reader(name)(CTX) == pytest.approx(WANT[name])
+    want = answer(name)
+    assert cells.metric_reader(name)(want["context"]) == \
+        pytest.approx(want["value"])
 
 
-@pytest.mark.parametrize("name", [n for n in ALL if n not in
-                                  ("serve_mfu.batch", "occupancy.batch")])
+@pytest.mark.parametrize("name", TRACED)
 def test_reader_without_trace_reads_nothing(name):
-    assert cells.metric_reader(name)(dict(CTX, trace=None)) is None
+    ctx = dict(answer(name)["context"], trace=None)
+    assert cells.metric_reader(name)(ctx) is None
+
+
+@pytest.mark.parametrize("name", ALL)
+def test_reader_on_an_empty_context_reads_nothing(name):
+    assert cells.metric_reader(name)({}) is None
 
 
 def test_roofline_compares_means_per_block():
     # 9 host blocks against 10 device programs: mean against mean
-    ctx = dict(CTX, traced_blocks=9, traced_least_s=0.45)
-    assert cells.metric_reader("decode_roofline")(ctx) == pytest.approx(25.0)
-    assert cells.metric_reader("decode_roofline")(
-        dict(CTX, traced_blocks=0)) is None
+    ctx = answer("decode_roofline")["context"]
+    read = cells.metric_reader("decode_roofline")
+    assert read(dict(ctx, traced_blocks=9, traced_least_s=0.45)) == \
+        pytest.approx(25.0)
+    assert read(dict(ctx, traced_blocks=0)) is None

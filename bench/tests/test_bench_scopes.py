@@ -1,16 +1,15 @@
-"""Scope reduction (bench/scopes.py), the readers of the program's own
-records and bench/program_trace.py's arithmetic, on hand-made inputs with a
-known answer; the fusion rule on a decode program compiled for a described
-TPU v5e; one tiny traced run through the hooks on the CPU."""
+"""Scope reduction (bench/scopes.py) and the readers of the program's own
+records, on hand-made inputs with a known answer; the scope names read from
+the program's source; the fusion rule on a decode program compiled for a
+described TPU v5e."""
 import glob
 import re
-from types import SimpleNamespace as NS
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from bench import cells, program_trace, scopes
+from bench import cells, scopes
 from bench import trace_reduce as tr
 from bench.scopes import Op
 from bench.trace_reduce import Interval as I
@@ -76,6 +75,32 @@ def test_reduce_of_a_trace_without_ops_is_empty():
     ("", "other")])
 def test_scope_of_a_path(path, want):
     assert scopes.scope(path) == want
+
+
+def test_the_programs_scope_names_are_read_from_its_source():
+    assert {"embed", "layers", "attention", "mlp", "head", "sample",
+            "pages"} <= scopes.program_scopes()
+
+
+def test_every_scope_the_program_names_is_a_literal():
+    assert scopes.unread_scopes() == []
+
+
+def test_a_scope_the_program_adds_is_charged_without_a_change_here(
+        tmp_path):
+    (tmp_path / "moe.py").write_text(
+        "import jax\nfrom jax import named_scope\n\n\n"
+        "def ffn(x, name):\n"
+        "    with jax.named_scope('mlp'):\n"
+        "        with named_scope(\"experts\"):\n"
+        "            x = x + 1\n"
+        "        with jax.named_scope(name):\n"   # not a literal: not read
+        "            return x\n")
+    names = scopes.program_scopes(str(tmp_path))
+    assert names == {"mlp", "experts"}
+    assert scopes.unread_scopes(str(tmp_path)) == [f"{tmp_path}/moe.py:9"]
+    assert scopes.scope("jit(f)/mlp/experts/dot_general", names) == "experts"
+    assert scopes.scope("jit(f)/mlp/dot_general", names) == "mlp"
 
 
 # a few protobuf fields, written by hand
@@ -241,33 +266,23 @@ RED = {"window_s": 4.0, "busy_s": 3.0,
                                             "mlp": 0.4, "other": 0.2}}}
 CTX = {"trace": RED, "decode_block": 8,
        "queue_waits": [0.1 * k for k in range(11)],
-       "prefill_window": {"prefill_tokens": 48,
-                          "prefill_slot_tokens": 64 * 48}}
-WANT = {  # 0.8 s under `layers` in 10 calls of 8 sub-steps: 10 ms
-    "decode_scan_self_ms.chat": 10.0, "decode_scan_self_ms.batch": 10.0,
-    "decode_attention_ms.chat": 7.5, "decode_attention_ms.batch": 7.5,
-    "queue_wait_p90_ms.chat": 900.0, "prefill_useful.chat": 100 / 64}
-SCOPED = [m for m in WANT if m.startswith("decode_")]
+       "counters": {"prefill_tokens": 48, "prefill_slot_tokens": 64 * 48}}
+SCOPED = ["decode_scan_self_ms.chat", "decode_scan_self_ms.batch",
+          "decode_attention_ms.chat", "decode_attention_ms.batch"]
 
 
-def test_the_tool_reads_every_such_metric():
-    assert sorted(WANT) == sorted(program_trace.METRICS)
-
-
-@pytest.mark.parametrize("name", sorted(WANT))
-def test_reader_value(name):
-    assert cells.metric_reader(name)(CTX) == pytest.approx(WANT[name])
-
-
-@pytest.mark.parametrize("name", sorted(WANT))
-def test_reader_on_an_empty_context_reads_nothing(name):
-    assert cells.metric_reader(name)({}) is None
+def test_decode_ms_lists_the_decode_programs_scopes_longest_first():
+    assert scopes.decode_ms(CTX) == [
+        ["layers", pytest.approx(10.0)], ["attention", pytest.approx(7.5)],
+        ["mlp", pytest.approx(5.0)], ["other", pytest.approx(2.5)]]
+    assert scopes.decode_ms(dict(CTX, trace=dict(RED, scopes={}))) is None
+    assert scopes.decode_ms({}) is None
 
 
 @pytest.mark.parametrize("name", SCOPED)
 def test_scope_reader_without_scope_names_reads_nothing(name):
     # a program without named scopes: every op lands in `other`; or a
-    # reduction that keeps no scopes at all (the benchmark's own)
+    # reduction that keeps no scopes at all
     red = dict(RED, scopes={"jit__engine_step_impl": {"other": 2.0}})
     assert cells.metric_reader(name)(dict(CTX, trace=red)) is None
     red = {k: v for k, v in RED.items() if k != "scopes"}
@@ -277,72 +292,5 @@ def test_scope_reader_without_scope_names_reads_nothing(name):
 @pytest.mark.parametrize("name", ["queue_wait_p90_ms.chat",
                                   "prefill_useful.chat"])
 def test_counter_reader_without_stamps_or_counters_reads_nothing(name):
-    ctx = dict(CTX, queue_waits=[], prefill_window=None)
+    ctx = dict(CTX, queue_waits=[], counters=None)
     assert cells.metric_reader(name)(ctx) is None
-
-
-# --------------------------------------------------------------------------
-# bench/program_trace.py
-# --------------------------------------------------------------------------
-def _req(admitted, first):
-    return NS(admitted_at=admitted, first_token_at=first)
-
-
-def test_queue_waits_count_admissions_in_the_window_from_the_schedule():
-    reqs = [_req(1.5, 1.6), _req(2.5, 2.7), _req(None, None),
-            _req(9.0, 9.1)]
-    got = program_trace.queue_waits(reqs, [1.0, 2.0, 3.0, 4.0], 2.0, 8.0)
-    assert got == [pytest.approx(0.5)]
-
-
-def test_counts_in_window():
-    a = {"prefill_tokens": 10, "prefill_slot_tokens": 100}
-    b = {"prefill_tokens": 25, "prefill_slot_tokens": 400}
-    assert program_trace.counts_in_window(a, b) == {
-        "prefill_tokens": 15, "prefill_slot_tokens": 300}
-    assert program_trace.counts_in_window({"tokens": 3}, b) is None
-    assert program_trace.counts_in_window(None, b) is None
-
-
-def test_ttft_split_parts_add_up_to_the_wait():
-    steps = [NS(t1=1.0, seen=[(0, 0)]), NS(t1=2.0, seen=[(0, 1)]),
-             NS(t1=3.0, seen=[(0, 3), (1, 1)]), NS(t1=4.0, seen=[(2, 1)])]
-    first = program_trace.first_deliveries(steps)
-    assert first == {0: 2.0, 1: 3.0, 2: 4.0}
-    reqs = [_req(1.25, 1.75), _req(2.0, 2.5), _req(3.5, 3.75)]
-    got = program_trace.ttft_split(reqs, [1.0, 1.5, 3.0], first, 0.5, 2.9)
-    # request 2 arrives after the window's close; 0: 1000 = 250 + 500 +
-    # 250 ms, 1: 1500 = 500 + 500 + 500 ms
-    assert got["tail_mean"] == pytest.approx(
-        {"ttft": 1500, "queue": 500, "prefill": 500, "hold": 500})
-    assert got["p90"]["ttft"] == pytest.approx(1450)
-    assert program_trace.ttft_split(reqs, [9.0] * 3, first, 0, 5) is None
-
-
-def test_step_ms_inside_and_outside_the_trace():
-    steps = [NS(t0=0.0, t1=0.1, n_active=2), NS(t0=1.0, t1=1.3, n_active=2),
-             NS(t0=1.4, t1=1.5, n_active=0), NS(t0=3.0, t1=3.2, n_active=1)]
-    got = program_trace.step_ms(steps, 0.9, 2.0)
-    assert got == {"in_trace": pytest.approx(300.0),
-                   "outside": pytest.approx(150.0)}
-    assert program_trace.step_ms(steps, None, None) is None
-
-
-def test_a_tiny_traced_run_through_the_hooks():
-    """The chat cell cut to CPU size: the hooks read stamps and counters,
-    nothing compiles in the window, and the driver is left as it was."""
-    from bench.drivers import serve_engine
-    from bench.tests import tiny
-
-    before = serve_engine.Tracer, serve_engine.drive
-    got = program_trace.one_run(tiny.cell("sun100m.chat"), 2200001041, 2.0,
-                                tiny.peaks_for("TPU v5 lite"))
-    assert (serve_engine.Tracer, serve_engine.drive) == before
-    assert got["correct"] and got["compiles_in_window"] == 0
-    assert set(got["metrics"]) == {m for m in program_trace.METRICS
-                                   if m.endswith(".chat")}
-    assert got["metrics"]["queue_wait_p90_ms.chat"] >= 0
-    assert 0 < got["metrics"]["prefill_useful.chat"] <= 100
-    p90 = got["ttft_split"]["tail_mean"]
-    assert p90["queue"] + p90["prefill"] + p90["hold"] == \
-        pytest.approx(p90["ttft"])
