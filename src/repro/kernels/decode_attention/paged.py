@@ -28,6 +28,14 @@ Masking is bit-compatible with the dense kernel: scores past kv_len go to
 to the softmax, and since both kernels fold the same 16-position chunks in
 order, paged output == dense-kernel output bitwise for the same cache
 contents.
+
+`paged_latent_attention` reads a latent pool (P+1, page_size, dk): one KV
+head shared by every query head, whose first `v_dim` columns are also the
+values (multi-head latent attention in its absorbed form), with the
+softmax scale from the caller. Same fetch schedule; each grid step lays
+its pages side by side and runs the scores and the weighted sum as matrix
+products for all query heads at once, where the MHA fold would spend one
+elementwise product per query head on a single KV head.
 """
 from __future__ import annotations
 
@@ -38,7 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .kernel import (finalize, fold_block, group_heads, init_state,
+from .kernel import (NEG_INF, finalize, fold_block, group_heads, init_state,
                      scratch_shapes, ungroup_heads)
 from .ref import decode_attention_reference
 
@@ -183,3 +191,113 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, kv_len, *,
     out = paged_decode_attention_fwd(q, k_pages, v_pages, page_table,
                                      kv_len, interpret=interpret)
     return out[:, None] if squeeze else out
+
+
+# --------------------------------------------------------------------------
+# latent pages: one KV head whose leading columns are also its values
+# --------------------------------------------------------------------------
+def _paged_latent_kernel(lens_ref, fetch_ref, q_ref, *refs, ppb: int,
+                         sm_scale: float, v_dim: int):
+    """Step (row, block): the block's ppb pages side by side as one
+    (ppb * ps, dk) slab; scores of all H query heads against it in one
+    matrix product, values its first `v_dim` columns."""
+    k_refs = refs[:ppb]
+    o_ref, acc_ref, m_ref, l_ref = refs[ppb:]
+    bi = pl.program_id(0)
+    j = pl.program_id(1)
+    ps = k_refs[0].shape[1]
+
+    @pl.when(j == 0)
+    def _init():
+        init_state(acc_ref, m_ref, l_ref)
+
+    kv_len = lens_ref[bi]
+
+    @pl.when(j * ppb * ps < kv_len)        # ragged early-exit per row
+    def _compute():
+        k = jnp.concatenate([r[0] for r in k_refs], axis=0)  # (ppb*ps, dk)
+        s = jax.lax.dot_general(q_ref[0], k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s * sm_scale                                      # (H, ppb*ps)
+        kpos = j * ppb * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos < kv_len, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(k.dtype), k[:, :v_dim],
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        finalize(o_ref, acc_ref, l_ref, kv_len)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "v_dim", "interpret"))
+def paged_latent_attention(q, pages, page_table, kv_lens, *, sm_scale: float,
+                           v_dim: int, interpret: bool = False):
+    """Decode attention over a latent pool, where every query head shares
+    one KV head and the values are the keys' first `v_dim` columns (MLA in
+    its absorbed form: K = [c_kv | k_pe], V = c_kv).
+
+    q: (B, H, dk); pages: (P+1, page_size, dk) pool (last page is trash);
+    page_table, kv_lens as in `paged_decode_attention_fwd`. Returns (B, H,
+    v_dim). Pages are fetched once each, by the same schedule as the MHA
+    kernel; the scores and the weighted sum run on the matrix unit, bf16
+    operands with float32 sums."""
+    b, h, dk = q.shape
+    ps = pages.shape[1]
+    max_pages = page_table.shape[1]
+    ppb = pages_per_block(ps, max_pages)
+    nblk = pl.cdiv(max_pages, ppb)
+    kv_lens = jnp.broadcast_to(
+        jnp.asarray(kv_lens, jnp.int32).reshape(-1), (b,))
+    fetch = fetch_schedule(page_table.astype(jnp.int32), kv_lens, ps, ppb)
+
+    def page_spec(i):
+        return pl.BlockSpec(
+            (1, ps, dk),
+            lambda bi, j, lens, fetch: (fetch[(bi * nblk + j) * ppb + i],
+                                        0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, nblk),
+        in_specs=[pl.BlockSpec((1, h, dk), lambda bi, j, lens, fetch:
+                               (bi, 0, 0)),
+                  *[page_spec(i) for i in range(ppb)]],
+        out_specs=pl.BlockSpec((1, h, v_dim), lambda bi, j, lens, fetch:
+                               (bi, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((h, v_dim), jnp.float32),
+                        pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, 1), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_paged_latent_kernel, ppb=ppb, sm_scale=sm_scale,
+                          v_dim=v_dim),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, v_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(kv_lens, fetch, q, *[pages] * ppb)
+
+
+def latent_attention_reference(q, rows, kv_len, *, sm_scale: float,
+                               v_dim: int):
+    """Pure-jnp latent decode attention over dense rows: q (B, H, dk), rows
+    (B, M, dk), kv_len () or (B,). Positions >= kv_len get exact-zero
+    weight; the oracle of `paged_latent_attention` (through `gather_pages`)
+    and the path off the TPU. Returns (B, H, v_dim)."""
+    s = jnp.einsum("bhd,bmd->bhm", q, rows,
+                   preferred_element_type=jnp.float32) * sm_scale
+    kv_len = jnp.broadcast_to(jnp.asarray(kv_len), (q.shape[0],))
+    valid = jnp.arange(rows.shape[1])[None, None] < kv_len[:, None, None]
+    p = jax.nn.softmax(jnp.where(valid, s, NEG_INF), axis=-1)
+    out = jnp.einsum("bhm,bmd->bhd", p.astype(rows.dtype), rows[..., :v_dim],
+                     preferred_element_type=jnp.float32)
+    return jnp.where(kv_len[:, None, None] > 0, out, 0.0).astype(q.dtype)

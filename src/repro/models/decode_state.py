@@ -31,6 +31,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from . import mla as _mla
 from . import rglru as _rglru
 from . import transformer as _transformer
 from . import xlstm as _xlstm
@@ -263,6 +264,11 @@ class DecodeStateSpec:
         whose rows own fixed storage."""
         return state
 
+    def counters(self, state):
+        """{name: device scalar} of the counters the family's decode keeps
+        in its state (cumulative); fetched with each block's tokens."""
+        return {}
+
     def row_wire_bytes(self, max_len):
         """Actual wire cost of one slot row, from the axis declarations:
         (full_bytes, per_pos_bytes, carry_bytes).  full = one row's whole
@@ -287,12 +293,17 @@ class DecodeStateSpec:
 class TransformerDecodeState(DecodeStateSpec):
     """KV family: (L, B, M, Hkv, dh) cache rows + per-row pos.  Covers the
     dense, MoE ("kv+experts": expert-sharded FFN via models/moe.py — the
-    decode state itself is still per-slot KV rows), VLM and audio configs.
+    decode state itself is still per-slot KV rows), VLM and audio configs,
+    and latent attention ("latent", models/mla.py), whose rows are
+    (L, B, M, r + rope) per layer stack under its own keys.
     """
 
     def __init__(self, cfg: TransformerConfig):
         super().__init__(cfg)
-        self.state_kind = "kv+experts" if cfg.is_moe else "kv"
+        self.state_kind = "latent" if cfg.is_mla else "kv"
+        if cfg.is_moe:
+            self.state_kind += "+experts"
+        self.names = _transformer.cache_names(cfg)
 
     def init_state(self, batch, max_len, dtype=None):
         st = _transformer.init_cache(self.cfg, batch, max_len, dtype)
@@ -300,10 +311,10 @@ class TransformerDecodeState(DecodeStateSpec):
         return st
 
     def batch_axes(self):
-        return {"k": 1, "v": 1, "pos": 0}
+        return {**dict.fromkeys(self.names, 1), "pos": 0}
 
     def length_axes(self):
-        return {"k": 2, "v": 2, "pos": -1}
+        return {**dict.fromkeys(self.names, 2), "pos": -1}
 
     def decode(self, params, state, last):
         return _transformer.decode_step(params, state, last, self.cfg)
@@ -315,12 +326,12 @@ class TransformerDecodeState(DecodeStateSpec):
         logits, tmp = _transformer.decode_step(
             params, tmp, tokens, cfg, last_idx=jnp.maximum(lens - 1, 0))
         # merge admitted rows' fresh cache prefix into the shared cache
-        w = tmp["k"].shape[2]                  # bucket len, block-aligned
-        adm5 = admit[None, :, None, None, None]
         new = dict(state)
-        for nm in ("k", "v"):
+        for nm in self.names:
+            w = tmp[nm].shape[2]               # bucket len, block-aligned
+            adm = admit.reshape((1, b) + (1,) * (tmp[nm].ndim - 2))
             new[nm] = state[nm].at[:, :, :w].set(
-                jnp.where(adm5, tmp[nm][:, :, :w], state[nm][:, :, :w]))
+                jnp.where(adm, tmp[nm][:, :, :w], state[nm][:, :, :w]))
         new["pos"] = jnp.where(admit, lens, state["pos"])
         return logits, new
 
@@ -444,11 +455,10 @@ class PagedTransformerDecodeState(TransformerDecodeState):
 
     def init_state(self, batch, max_len, dtype=None):
         dtype = dtype or self.cfg.cdtype
-        kp = _transformer.init_paged_pool(self.cfg, self.pool_pages,
-                                          self.page_size, dtype)
         trash = self.pool_pages
         st = {
-            "kp": kp, "vp": jnp.zeros_like(kp),
+            **_transformer.init_paged_pools(self.cfg, self.pool_pages,
+                                            self.page_size, dtype),
             "ptab": jnp.full((batch, self.max_pages), trash, jnp.int32),
             "pos": jnp.zeros((batch,), jnp.int32),
             "free": jnp.arange(self.pool_pages, dtype=jnp.int32),
@@ -459,7 +469,12 @@ class PagedTransformerDecodeState(TransformerDecodeState):
             st["pf_tab"] = jnp.full((self.prefix_entries, self.max_pages),
                                     trash, jnp.int32)
             st["pf_len"] = jnp.zeros((self.prefix_entries,), jnp.int32)
+        if self.cfg.moe_d_ff:
+            st.update(dict.fromkeys(_mla.COUNTERS, jnp.zeros((), jnp.int32)))
         return st
+
+    def counters(self, state):
+        return {k: state[k] for k in _mla.COUNTERS if k in state}
 
     # axis declarations describe the WIRE format (the dense logical
     # layout every bundle travels in), not the pool — all physical-layout
@@ -547,11 +562,12 @@ class PagedTransformerDecodeState(TransformerDecodeState):
             # re-page the freshly prefilled KV (skip shared pages — their
             # contents are already resident and bit-identical by the
             # prefill length-independence proof)
-            t = jnp.arange(tmp["k"].shape[2])[None]   # dense pads lb up to 128
+            t = jnp.arange(tmp[self.names[0]].shape[2])[None]  # lb up to 128
             write = admit[:, None] & (t >= (shared * ps)[:, None]) & \
                 (t < lens[:, None])
-            new["kp"] = _scatter_logical(state["kp"], ptab, tmp["k"], write)
-            new["vp"] = _scatter_logical(state["vp"], ptab, tmp["v"], write)
+            for nm in self.names:
+                new[nm + "p"] = _scatter_logical(state[nm + "p"], ptab,
+                                                 tmp[nm], write)
 
             if self.prefix_entries:
                 # publish flagged rows' head pages (+1 pin so they outlive
@@ -573,25 +589,25 @@ class PagedTransformerDecodeState(TransformerDecodeState):
     # --- migration/replication: dense-logical wire format -----------------
     def export_rows(self, state, idx):
         ptab = jnp.take(state["ptab"], idx, axis=0)
-        return {"k": _gather_logical(state["kp"], ptab),
-                "v": _gather_logical(state["vp"], ptab),
+        return {**{nm: _gather_logical(state[nm + "p"], ptab)
+                   for nm in self.names},
                 "pos": jnp.take(state["pos"], idx)}
 
     def import_rows(self, state, bundle, src_for_dst, mask):
         state = self.release(state, mask)      # targets drop their pages
-        bk = jnp.take(bundle["k"], src_for_dst, axis=1)
-        bv = jnp.take(bundle["v"], src_for_dst, axis=1)
+        rows = {nm: jnp.take(bundle[nm], src_for_dst, axis=1)
+                for nm in self.names}
         pos = jnp.where(mask, jnp.take(bundle["pos"], src_for_dst), 0)
         ps = self.page_size
         cols = jnp.arange(self.max_pages)[None]
         take = mask[:, None] & (cols < (-(-pos // ps))[:, None])
         ptab, ref, top = _alloc_rows(state["ptab"], state["free"],
                                      state["top"], state["ref"], take)
-        t = jnp.arange(bk.shape[2])[None]
+        t = jnp.arange(rows[self.names[0]].shape[2])[None]
         write = mask[:, None] & (t < pos[:, None])
         return {**state, "ptab": ptab, "ref": ref, "top": top,
-                "kp": _scatter_logical(state["kp"], ptab, bk, write),
-                "vp": _scatter_logical(state["vp"], ptab, bv, write),
+                **{nm + "p": _scatter_logical(state[nm + "p"], ptab, r, write)
+                   for nm, r in rows.items()},
                 "pos": jnp.where(mask, pos, state["pos"])}
 
     def export_delta_rows(self, state, idx, starts, width):
@@ -600,8 +616,7 @@ class PagedTransformerDecodeState(TransformerDecodeState):
                         self.padded_len - 1)            # (B, W)
         pid = jnp.take_along_axis(ptab, cols // self.page_size, axis=1)
         off = cols % self.page_size
-        return {"k": state["kp"][:, pid, off],
-                "v": state["vp"][:, pid, off],
+        return {**{nm: state[nm + "p"][:, pid, off] for nm in self.names},
                 "pos": jnp.take(state["pos"], idx)}
 
     def init_standby(self, state):

@@ -111,3 +111,82 @@ def init_moe_params(key, d_model: int, d_ff: int, num_experts: int,
         "wo": (jax.random.normal(k4, (num_experts, d_ff, d_model), dtype)
                * s_out),
     }
+
+
+# --------------------------------------------------------------------------
+# a held share of sigmoid-routed experts, dropless (DeepSeek-V3 / Moonlight)
+# --------------------------------------------------------------------------
+def sigmoid_route(x, w_router, bias, *, top_k: int, scaling: float):
+    """DeepSeek-V3 routing over every expert: scores sigmoid(x W_r); the
+    top-k of scores + `bias` (the selection bias, which chooses but never
+    weighs) are taken, and their unbiased scores, renormalised over the k
+    and times `scaling`, are the gates. x (T, D) -> (gates, experts),
+    each (T, k); gates in float32."""
+    logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    _, ix = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, ix, axis=-1)
+    return w / jnp.sum(w, axis=-1, keepdims=True) * scaling, ix
+
+
+DENSE_ROWS = 512        # at most this many tokens: every held expert on all
+PREFILL_CHUNK = 4096    # more tokens are routed this many at a time
+
+
+def held_experts_ffn(x, params, *, top_k: int, scaling: float, offset: int):
+    """The routed part that this chip's experts give, with no assignment
+    dropped. x: (T, D); params: router (D, E) and bias (E,) over all E
+    experts, wi_gate / wi_up (H, D, F) and wo (H, F, D) for the H experts
+    held here, experts offset .. offset + H - 1. Assignments to experts not
+    held here contribute nothing (expert parallelism without its exchange).
+    Returns (out (T, D), counts (H,) of assignments per held expert).
+
+    Two forms of the same sum, chosen by T:
+      T <= DENSE_ROWS (decode)  every held expert on all T rows, weighted
+          by its gate (0 where not chosen): H x T rows of work, and reading
+          the held weights once is the cost either way;
+      larger T (prefill)  the held assignments sorted by expert through
+          three grouped products (`lax.ragged_dot`, whose TPU kernel
+          visits only the 512-row tiles the groups cover), so the work
+          follows the ~T k H / E tokens routed here and not T x H; in
+          chunks of PREFILL_CHUNK tokens, one after another, so the sorted
+          copies stay small. (At decode each group would still fill a
+          whole tile.)
+    """
+    t, d = x.shape
+    if t > PREFILL_CHUNK and t % PREFILL_CHUNK == 0:
+        out, counts = jax.lax.map(
+            lambda xc: held_experts_ffn(xc, params, top_k=top_k,
+                                        scaling=scaling, offset=offset),
+            x.reshape(t // PREFILL_CHUNK, PREFILL_CHUNK, d))
+        return out.reshape(t, d), counts.sum(0)
+    held = params["wi_gate"].shape[0]
+    gates, experts = sigmoid_route(x, params["router"], params["bias"],
+                                   top_k=top_k, scaling=scaling)
+    local = experts - offset
+    mine = (local >= 0) & (local < held)
+    group = jnp.where(mine, local, held)           # not held: sorted last
+    counts = jnp.bincount(group.reshape(-1), length=held + 1)[:held]
+    counts = counts.astype(jnp.int32)
+    if t <= DENSE_ROWS:
+        gate = jnp.sum(jnp.where(group[:, None, :] == jnp.arange(held)[
+            None, :, None], gates[:, None, :], 0.0), -1)      # (T, H)
+        g = jnp.einsum("td,edf->etf", x, params["wi_gate"])
+        u = jnp.einsum("td,edf->etf", x, params["wi_up"])
+        y = jnp.einsum("etf,efd->etd", (jax.nn.silu(g) * u).astype(x.dtype),
+                       params["wo"])
+        out = jnp.sum(y.astype(jnp.float32) * gate.T[..., None], axis=0)
+        return out.astype(x.dtype), counts
+    order = jnp.argsort(group.reshape(-1))
+    xs = x[order // top_k]                                 # (T*k, D)
+    g = jax.lax.ragged_dot(xs, params["wi_gate"], counts)
+    u = jax.lax.ragged_dot(xs, params["wi_up"], counts)
+    y = jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(x.dtype),
+                           params["wo"], counts)
+    # back to (token, choice) order; rows past the held assignments are
+    # not computed, so they are selected away, not scaled
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    y = jnp.where(mine.reshape(-1, 1), y[back].astype(jnp.float32), 0.0)
+    out = jnp.sum(y.reshape(t, top_k, d) * gates[..., None], axis=1)
+    return out.astype(x.dtype), counts

@@ -9,6 +9,8 @@ Supports:
   - GQA/MQA/MHA (+ optional QKV bias), RoPE / M-RoPE / sinusoidal positions
   - SwiGLU / GeGLU / GELU MLPs; parallel attention+FFN blocks (Command-R)
   - capacity-based top-k MoE FFN (granite / qwen3-moe)
+  - multi-head latent attention, a leading dense block and a held share of
+    sigmoid-routed dropless experts (DeepSeek-V3 / Moonlight; models/mla.py)
   - multi-codebook token streams (MusicGen EnCodec frontend stub)
   - local (windowed) attention
   - KV-cache prefill/decode for serving
@@ -26,6 +28,7 @@ from repro.distributed.hints import mesh_axis_size, shard_hint
 from .layers import (_qpos, apply_rope, attention, gelu_mlp, geglu,
                      layer_norm, mrope_cos_sin, rms_norm, rope_cos_sin,
                      swiglu)
+from . import mla
 from .losses import chunked_lm_loss, softmax_xent
 from .moe import init_moe_params, moe_ffn
 
@@ -49,6 +52,20 @@ class TransformerConfig:
     num_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
+    # latent attention (models/mla.py; kv_lora_rank 0 = none)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    first_dense_layers: int = 0              # leading blocks with a d_ff MLP
+    # held-share dropless experts, routed by sigmoid scores (moe_d_ff 0 =
+    # the capacity `moe_ffn`, routed by softmax)
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    routed_scaling: float = 1.0
+    experts_held: int = 0                    # 0 = all num_experts
+    expert_offset: int = 0
+    rms_norm_eps: float = 1e-6
     # modality / position
     mrope_sections: Optional[tuple] = None   # qwen2-vl
     n_codebooks: int = 1                     # musicgen
@@ -77,6 +94,25 @@ class TransformerConfig:
         return self.num_experts > 0
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def n_held(self) -> int:
+        """Experts computed here (a share of num_experts under expert
+        parallelism)."""
+        return self.experts_held or self.num_experts
+
+    def __post_init__(self):
+        if self.moe_d_ff and not self.is_mla:
+            raise ValueError("held-share experts (moe_d_ff) come with the "
+                             "latent-attention block (kv_lora_rank > 0)")
+        if self.expert_offset + self.n_held > self.num_experts:
+            raise ValueError(f"experts {self.expert_offset}.."
+                             f"{self.expert_offset + self.n_held - 1} are "
+                             f"not among {self.num_experts}")
+
+    @property
     def cdtype(self):
         return jnp.dtype(self.compute_dtype)
 
@@ -93,6 +129,10 @@ class TransformerConfig:
         total = self.param_count()
         if not self.is_moe:
             return total
+        if self.moe_d_ff:
+            expert = 3 * self.d_model * self.moe_d_ff * self.n_held * \
+                (self.n_layers - self.first_dense_layers)
+            return total - expert + expert * self.top_k // self.num_experts
         expert = 3 * self.d_model * self.d_ff * self.num_experts * \
             self.n_layers
         return total - expert + expert * self.top_k // self.num_experts
@@ -102,6 +142,8 @@ class TransformerConfig:
 # init
 # --------------------------------------------------------------------------
 def init_params(key, cfg: TransformerConfig):
+    if cfg.is_mla:
+        return mla.init_params(key, cfg)
     dt = cfg.pdtype
     hd, h, hkv = cfg.hd, cfg.n_heads, cfg.n_kv_heads
     d, L = cfg.d_model, cfg.n_layers
@@ -170,7 +212,7 @@ def init_params(key, cfg: TransformerConfig):
 def _norm(cfg, x, w, b=None):
     if cfg.norm == "layernorm":
         return layer_norm(x, w, b)
-    return rms_norm(x, w)
+    return rms_norm(x, w, cfg.rms_norm_eps)
 
 
 def _mlp(cfg, lp, h):
@@ -389,6 +431,8 @@ def _hidden(params, tokens, cfg: TransformerConfig, positions=None):
 def forward(params, tokens, cfg: TransformerConfig, positions=None):
     """tokens: (B, S) int32 — or (B, n_q, S) for multi-codebook.
     Returns logits (B, S, V) (or (B, n_q, S, V))."""
+    if cfg.is_mla:
+        return mla.forward(params, tokens, cfg, positions)
     x = _hidden(params, tokens, cfg, positions)
     return _unembed(cfg, params, x)
 
@@ -399,7 +443,7 @@ def loss_fn(params, batch, cfg: TransformerConfig):
     With cfg.loss_chunk > 0 (and a single codebook) the (B, S, V) logits are
     never materialized: the xent scans the sequence in chunks."""
     labels = batch["labels"]
-    if cfg.loss_chunk and cfg.n_codebooks == 1 \
+    if cfg.loss_chunk and cfg.n_codebooks == 1 and not cfg.is_mla \
             and labels.shape[-1] % cfg.loss_chunk == 0:
         x = _hidden(params, batch["tokens"], cfg,
                     positions=batch.get("positions"))
@@ -420,7 +464,10 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
     """KV cache in model layout (L, B, M, Hkv, dh). M is rounded up to a
     multiple of `pad_to` HERE, once, so the decode-attention kernel (block-
     strided over M) never pads or transposes the cache on the hot path;
-    positions >= kv_len are masked everywhere downstream."""
+    positions >= kv_len are masked everywhere downstream. Latent attention
+    keeps latent rows instead (models/mla.py `init_cache`)."""
+    if cfg.is_mla:
+        return mla.init_cache(cfg, batch, max_len, dtype, pad_to)
     dtype = dtype or cfg.cdtype
     m = -(-max_len // pad_to) * pad_to
     shape = (cfg.n_layers, batch, m, cfg.n_kv_heads, cfg.hd)
@@ -455,6 +502,9 @@ def decode_step(params, cache, tokens, cfg: TransformerConfig,
     `last_idx`: optional (B,) per-row index of the position whose logits to
     return (ragged bucketed prefill: rows padded to a shared bucket length
     read their logits at prompt_len - 1, not at the pad tail)."""
+    if cfg.is_mla:
+        return mla.decode_step(params, cache, tokens, cfg, positions,
+                               last_idx)
     pos0 = cache["pos"]
     x = _decode_embed(cfg, params, tokens, pos0)
     b, s = x.shape[0], x.shape[1]
@@ -504,11 +554,28 @@ def init_paged_pool(cfg: TransformerConfig, pool_pages: int, page_size: int,
     return jnp.zeros(shape, dtype)
 
 
+def cache_names(cfg: TransformerConfig):
+    """Keys of the cache leaves that grow with the sequence, in the dense
+    layout (L, B, M, ...); their paged pools are keyed name + "p"."""
+    return mla.cache_names(cfg) if cfg.is_mla else ("k", "v")
+
+
+def init_paged_pools(cfg: TransformerConfig, pool_pages: int, page_size: int,
+                     dtype=None):
+    """{pool key: pool} of every paged cache leaf (see `cache_names`)."""
+    if cfg.is_mla:
+        return mla.init_paged_pools(cfg, pool_pages, page_size, dtype)
+    kp = init_paged_pool(cfg, pool_pages, page_size, dtype)
+    return {"kp": kp, "vp": jnp.zeros_like(kp)}
+
+
 def paged_decode_step(params, cache, tokens, cfg: TransformerConfig):
     """One paged decode step: tokens (B, 1). cache carries "kp"/"vp" pools
     (L, P+1, ps, Hkv, dh), "ptab" (B, max_pages) int32 and "pos" (B,).
     Returns (logits (B, V), new cache). Positions/rope/sinusoidal handling
     mirrors decode_step exactly so paged == dense bitwise."""
+    if cfg.is_mla:
+        return mla.paged_decode_step(params, cache, tokens, cfg)
     pos0 = cache["pos"]                      # (B,) per-slot positions
     x = _decode_embed(cfg, params, tokens, pos0)
     b, s = x.shape[0], x.shape[1]

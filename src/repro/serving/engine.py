@@ -60,13 +60,19 @@ finished, pages returned). Counters in `stats`, per prefill call:
 `prefill_calls`, `prefill_rows`, `prefill_tokens` (prompt tokens) and
 `prefill_slot_tokens` (max_batch x bucket, the token rows the program
 computes), so prefill_tokens / prefill_slot_tokens is the useful share of
-prefill work. Stamps on each `Request`, on `time.perf_counter()`:
+prefill work. A model whose decode state keeps counters of its own
+(`DecodeStateSpec.counters`; models/mla.py's `decode_steps`,
+`expert_tokens`, `expert_tokens_peak` and `latent_positions`) has them
+copied into `stats` at each block's drain, fetched with the block's
+tokens. Stamps on each `Request`, on `time.perf_counter()`:
 `arrival` (the caller's, else `submit`'s), `admitted_at` (taken into a
 slot) and `first_token_at` (first token on the host). Device ops carry
 `jax.named_scope` names in their op metadata: `embed`, `layers` (the scan
 over blocks: its own ops are the weight and cache slices and the cache
 write-back), `attention`, `mlp`, `head`, `sample` and `pages` (paged
-allocator and prefix-page mapping).
+allocator and prefix-page mapping); the latent-attention model adds
+`latent` (its decode attention) and `experts` (routing and the held
+experts' products), models/mla.py.
 """
 from __future__ import annotations
 
@@ -266,6 +272,8 @@ class ServingEngine:
             self.stats.update(pages_reserved=0, pages_shared=0,
                               prefix_hits=0, prefix_stores=0,
                               admission_stalls=0)
+        # the model's own counters (cumulative, kept in its decode state)
+        self.stats.update(dict.fromkeys(self.spec.counters(self.cache), 0))
 
         self._prefill = jax.jit(self._prefill_impl)
         self._engine_step = jax.jit(self._engine_step_impl)
@@ -939,8 +947,9 @@ class ServingEngine:
             self.cache, self.state, toks, emit, done = self._engine_step(
                 self.params, self.cache, self.state)
             with jax.profiler.TraceAnnotation("engine.drain"):
-                toks, emit, done = jax.device_get((toks, emit, done))  # repro-lint: allow[HS001] THE per-block drain the 0.047 syncs/token budget is built on
+                toks, emit, done, ctr = jax.device_get((toks, emit, done, self.spec.counters(self.cache)))  # repro-lint: allow[HS001] THE per-block drain the 0.047 syncs/token budget is built on
             self.stats["host_syncs"] += 1
+            self.stats.update({k: int(v) for k, v in ctr.items()})
             with jax.profiler.TraceAnnotation("engine.emit"):
                 for i, req in enumerate(self.slots):
                     if req is None:

@@ -79,6 +79,7 @@ PARAM_BOUNDS = {
     "xlstm-350m": (0.28e9, 0.45e9, None, None),
     "recurrentgemma-2b": (2.4e9, 3.2e9, None, None),
     "musicgen-medium": (1.1e9, 1.8e9, None, None),
+    "moonlight-16b-a3b": (15.5e9, 16.5e9, 2.7e9, 3.2e9),
 }
 
 

@@ -317,6 +317,63 @@ class TestPagedDecodeAttention:
                                           np.asarray(out[i]))
 
 
+class TestPagedLatentAttention:
+    """The latent pool: 16 query heads over one KV head of 576 (the
+    Moonlight MLA row [c_kv | k_pe]), values its first 512 columns, the
+    scale from the caller (192 ** -0.5, not 576 ** -0.5)."""
+
+    H, DK, DV, SCALE = 16, 576, 512, 192 ** -0.5
+
+    def _setup(self, key, b, ps, mp, pool_pages, dtype):
+        kq, kp, kt = jax.random.split(key, 3)
+        q = jax.random.normal(kq, (b, self.H, self.DK), dtype)
+        pool = jax.random.normal(kp, (pool_pages + 1, ps, self.DK), dtype)
+        ptab = jax.random.permutation(kt, pool_pages)[:b * mp]
+        return q, pool, ptab.reshape(b, mp).astype(jnp.int32)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_latent_shape_vs_oracle(self, dtype):
+        """Rows ending mid-page, on a block's end, across two blocks, and
+        an empty row (exact zeros)."""
+        from repro.kernels.decode_attention.paged import (
+            latent_attention_reference, paged_latent_attention)
+        b, ps, mp = 4, 16, 12
+        q, pool, ptab = self._setup(jax.random.PRNGKey(26), b, ps, mp,
+                                    b * mp + 2, dtype)
+        lens = jnp.asarray([ps * 3 + 5, 128, ps * mp - 1, 0], jnp.int32)
+        out = paged_latent_attention(q, pool, ptab, lens,
+                                     sm_scale=self.SCALE, v_dim=self.DV,
+                                     interpret=True)
+        ref = latent_attention_reference(q, gather_pages(pool, ptab), lens,
+                                         sm_scale=self.SCALE, v_dim=self.DV)
+        assert out.shape == (b, self.H, self.DV)
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            atol=_tol(dtype), rtol=_tol(dtype))
+        assert np.all(np.asarray(out[3]) == 0.0)
+
+    def test_values_are_the_keys_leading_columns(self):
+        """The same pool as separate K and V (V = K[..., :512]) through the
+        naive per-head oracle, with q's scale folded in."""
+        from repro.kernels.decode_attention.paged import (
+            paged_latent_attention)
+        b, ps, mp = 2, 16, 4
+        q, pool, ptab = self._setup(jax.random.PRNGKey(27), b, ps, mp,
+                                    b * mp, jnp.float32)
+        lens = jnp.asarray([ps * 2 + 1, ps * mp], jnp.int32)
+        out = paged_latent_attention(q, pool, ptab, lens,
+                                     sm_scale=self.SCALE, v_dim=self.DV,
+                                     interpret=True)
+        keys = gather_pages(pool, ptab)                     # (B, M, 576)
+        s = jnp.einsum("bhd,bmd->bhm", q, keys) * self.SCALE
+        s = jnp.where(jnp.arange(keys.shape[1]) < lens[:, None, None], s,
+                      -jnp.inf)
+        want = jnp.einsum("bhm,bmd->bhd", jax.nn.softmax(s, -1),
+                          keys[..., :self.DV])
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   atol=1e-4, rtol=1e-4)
+
+
 class TestRGLRUScan:
     @pytest.mark.parametrize("b,s,d", [(2, 256, 128), (1, 512, 256),
                                        (3, 128, 384)])

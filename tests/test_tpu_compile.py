@@ -108,6 +108,43 @@ def test_paged_decode_step_runs_the_kernel(sds, monkeypatch):
     assert f"[{b},{mp},{kp[2]},{kp[3]},{kp[4]}]" not in hlo
 
 
+def test_paged_latent_attention_compiles_at_moonlight_widths(sds):
+    """The moonlight16b.batch64 cell's latent decode: 32 slots of 128 pages
+    on a 4096-page pool of [c_kv | k_pe] rows (576), 16 query heads."""
+    from repro.kernels.decode_attention.paged import paged_latent_attention
+    dt = jnp.bfloat16
+    _assert_kernel(
+        lambda q, p, t, n: paged_latent_attention(q, p, t, n,
+                                                  sm_scale=192 ** -0.5,
+                                                  v_dim=512),
+        sds((32, 16, 576), dt), sds((4097, PAGE, 576), dt),
+        sds((32, 128), jnp.int32), sds((32,), jnp.int32))
+
+
+def test_paged_latent_decode_step_runs_the_kernel(sds, monkeypatch):
+    """The engine's paged decode step of the latent-attention model,
+    compiled for the chip, calls the latent kernel and never gathers the
+    rows' pages into the dense (B, max_pages * page_size, 576) layout."""
+    from repro.serving import EngineConfig, ServingEngine
+
+    cfg = registry.get_config("moonlight-16b-a3b", n_layers=2,
+                              vocab_size=1024, experts_held=8)
+    fns = registry.model_fns(cfg)
+    params = jax.eval_shape(lambda k: fns.init(k, cfg), jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params)
+    eng = ServingEngine(cfg, fns, params,
+                        EngineConfig(max_batch=4, max_len=128, decode_block=2,
+                                     page_size=PAGE))
+    on_chip = lambda t: jax.tree.map(lambda a: sds(a.shape, a.dtype), t)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = jax.jit(eng._engine_step_impl).lower(
+        on_chip(eng.params), on_chip(eng.cache), on_chip(eng.state)
+    ).compile().as_text()
+    assert "paged_latent_attention" in hlo and "tpu_custom_call" in hlo
+    assert f"[4,{128 // PAGE},{PAGE},576]" not in hlo
+    assert "[4,128,576]" not in hlo
+
+
 def test_flash_attention_compiles(sds):
     from repro.kernels.flash_attention.ops import flash_attention
     dt = LM.cdtype
