@@ -23,6 +23,13 @@ refuses a manual copy of a page whose (Hkv, dh) is not a multiple of the
 tiling, e.g. 36 heads of 64.) Each page is folded through the dense
 kernel's `kernel.fold_block`, all KV heads at once.
 
+Both kernels also take the pools stacked over layers, (L, P+1, ...), with
+a layer index as a third scalar-prefetch operand: each page slot's
+index_map leads with the layer on a squeezed dimension, so the body sees
+the same page ref and a decode layer scan can keep the stacked pools in
+one buffer, written in place, instead of slicing a layer's pool out for
+the kernel. An unstacked pool is the L = 1 case.
+
 Masking is bit-compatible with the dense kernel: scores past kv_len go to
 -1e30 before the exp, so trash-page or stale contents contribute exact 0.0
 to the softmax, and since both kernels fold the same 16-position chunks in
@@ -81,8 +88,14 @@ def fetch_schedule(page_table, kv_lens, page_size: int, ppb: int):
     return jnp.take_along_axis(tab, src, axis=0).reshape(-1)
 
 
-def _paged_decode_kernel(lens_ref, fetch_ref, q_ref, *refs, ppb: int,
-                         sm_scale: float):
+def _stacked(pool, ndim: int):
+    """The pool with its layer axis: a pool of `ndim` dims is one layer's
+    (a reshape, so no copy), one of ndim + 1 is already stacked."""
+    return pool[None] if pool.ndim == ndim else pool
+
+
+def _paged_decode_kernel(lens_ref, fetch_ref, layer_ref, q_ref, *refs,
+                         ppb: int, sm_scale: float):
     """Step (row, block): fold the block's live pages, one input slot per
     page, through the dense kernel's `fold_block`."""
     k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
@@ -110,14 +123,16 @@ def _paged_decode_kernel(lens_ref, fetch_ref, q_ref, *refs, ppb: int,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, kv_lens, *,
-                               interpret: bool = False):
+def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, kv_lens,
+                               layer=0, *, interpret: bool = False):
     """q: (B, H, dh); k/v_pages: (P+1, page_size, Hkv, dh) pool (last page
-    is trash); page_table: (B, max_pages) int32 physical page ids (unmapped
-    entries point at the trash page); kv_lens: (B,) int32 logical lengths
-    (a scalar broadcasts to all rows)."""
+    is trash), or the pools of L layers stacked, (L, P+1, page_size, Hkv,
+    dh), read at `layer`; page_table: (B, max_pages) int32 physical page
+    ids (unmapped entries point at the trash page); kv_lens: (B,) int32
+    logical lengths (a scalar broadcasts to all rows)."""
+    k_pages, v_pages = _stacked(k_pages, 4), _stacked(v_pages, 4)
     b, h, dh = q.shape
-    ps, hkv = k_pages.shape[1], k_pages.shape[2]
+    ps, hkv = k_pages.shape[2], k_pages.shape[3]
     max_pages = page_table.shape[1]
     assert h % hkv == 0
     group = h // hkv
@@ -126,18 +141,19 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, kv_lens, *,
     kv_lens = jnp.broadcast_to(
         jnp.asarray(kv_lens, jnp.int32).reshape(-1), (b,))
     fetch = fetch_schedule(page_table.astype(jnp.int32), kv_lens, ps, ppb)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
     def page_spec(i):
         return pl.BlockSpec(
-            (1, ps, hkv, dh),
-            lambda bi, j, lens, fetch: (fetch[(bi * nblk + j) * ppb + i],
-                                        0, 0, 0))
+            (None, 1, ps, hkv, dh),
+            lambda bi, j, lens, fetch, layer: (
+                layer[0], fetch[(bi * nblk + j) * ppb + i], 0, 0, 0))
 
     row_spec = pl.BlockSpec((1, group, hkv, dh),
-                            lambda bi, j, lens, fetch: (bi, 0, 0, 0))
+                            lambda bi, j, lens, fetch, layer: (bi, 0, 0, 0))
     pages = [page_spec(i) for i in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, nblk),
         in_specs=[row_spec, *pages, *pages],
         out_specs=row_spec,
@@ -151,18 +167,22 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, kv_lens, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(kv_lens, fetch, group_heads(q, hkv), *[k_pages] * ppb,
+    )(kv_lens, fetch, layer, group_heads(q, hkv), *[k_pages] * ppb,
       *[v_pages] * ppb)
     return ungroup_heads(out)
 
 
-def gather_pages(pool, page_table):
+def gather_pages(pool, page_table, layer=None):
     """Materialize the logical dense layout from a pool + page table.
 
-    pool: (P+1, page_size, Hkv, dh); page_table: (B, max_pages) int32.
+    pool: (P+1, page_size, Hkv, dh), or with `layer` the stacked (L, P+1,
+    page_size, ...) read at that layer; page_table: (B, max_pages) int32.
     Returns (B, max_pages * page_size, Hkv, dh) — the reference/CPU path;
     the pallas kernel never builds this.
     """
+    if layer is not None:       # one gather from the flattened layers
+        page_table = page_table + layer * pool.shape[1]
+        pool = pool.reshape(-1, *pool.shape[2:])
     b, mp = page_table.shape
     ps = pool.shape[1]
     dense = jnp.take(pool, page_table, axis=0)      # (B, MP, ps, Hkv, dh)
@@ -181,23 +201,24 @@ def paged_decode_attention_reference(q, k_pages, v_pages, page_table,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attention(q, k_pages, v_pages, page_table, kv_len, *,
-                           interpret: bool = False):
-    """q: (B, 1, H, dh) or (B, H, dh); pools: (P+1, page_size, Hkv, dh);
-    page_table: (B, max_pages); kv_len: scalar or (B,)."""
+def paged_decode_attention(q, k_pages, v_pages, page_table, kv_len,
+                           layer=0, *, interpret: bool = False):
+    """q: (B, 1, H, dh) or (B, H, dh); pools: (P+1, page_size, Hkv, dh), or
+    (L, P+1, page_size, Hkv, dh) read at `layer`; page_table: (B,
+    max_pages); kv_len: scalar or (B,)."""
     squeeze = q.ndim == 4
     if squeeze:  # repro-lint: allow[RT001] rank normalization is trace-time static; two shapes total
         q = q[:, 0]
     out = paged_decode_attention_fwd(q, k_pages, v_pages, page_table,
-                                     kv_len, interpret=interpret)
+                                     kv_len, layer, interpret=interpret)
     return out[:, None] if squeeze else out
 
 
 # --------------------------------------------------------------------------
 # latent pages: one KV head whose leading columns are also its values
 # --------------------------------------------------------------------------
-def _paged_latent_kernel(lens_ref, fetch_ref, q_ref, *refs, ppb: int,
-                         sm_scale: float, v_dim: int):
+def _paged_latent_kernel(lens_ref, fetch_ref, layer_ref, q_ref, *refs,
+                         ppb: int, sm_scale: float, v_dim: int):
     """Step (row, block): the block's ppb pages side by side as one
     (ppb * ps, dk) slab; scores of all H query heads against it in one
     matrix product, values its first `v_dim` columns."""
@@ -238,40 +259,43 @@ def _paged_latent_kernel(lens_ref, fetch_ref, q_ref, *refs, ppb: int,
 
 @functools.partial(jax.jit,
                    static_argnames=("sm_scale", "v_dim", "interpret"))
-def paged_latent_attention(q, pages, page_table, kv_lens, *, sm_scale: float,
-                           v_dim: int, interpret: bool = False):
+def paged_latent_attention(q, pages, page_table, kv_lens, layer=0, *,
+                           sm_scale: float, v_dim: int,
+                           interpret: bool = False):
     """Decode attention over a latent pool, where every query head shares
     one KV head and the values are the keys' first `v_dim` columns (MLA in
     its absorbed form: K = [c_kv | k_pe], V = c_kv).
 
-    q: (B, H, dk); pages: (P+1, page_size, dk) pool (last page is trash);
-    page_table, kv_lens as in `paged_decode_attention_fwd`. Returns (B, H,
-    v_dim). Pages are fetched once each, by the same schedule as the MHA
-    kernel; the scores and the weighted sum run on the matrix unit, bf16
-    operands with float32 sums."""
+    q: (B, H, dk); pages: (P+1, page_size, dk) pool (last page is trash),
+    or (L, P+1, page_size, dk) read at `layer`; page_table, kv_lens as in
+    `paged_decode_attention_fwd`. Returns (B, H, v_dim). Pages are fetched
+    once each, by the same schedule as the MHA kernel; the scores and the
+    weighted sum run on the matrix unit, bf16 operands with float32
+    sums."""
+    pages = _stacked(pages, 3)
     b, h, dk = q.shape
-    ps = pages.shape[1]
+    ps = pages.shape[2]
     max_pages = page_table.shape[1]
     ppb = pages_per_block(ps, max_pages)
     nblk = pl.cdiv(max_pages, ppb)
     kv_lens = jnp.broadcast_to(
         jnp.asarray(kv_lens, jnp.int32).reshape(-1), (b,))
     fetch = fetch_schedule(page_table.astype(jnp.int32), kv_lens, ps, ppb)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
     def page_spec(i):
         return pl.BlockSpec(
-            (1, ps, dk),
-            lambda bi, j, lens, fetch: (fetch[(bi * nblk + j) * ppb + i],
-                                        0, 0))
+            (None, 1, ps, dk),
+            lambda bi, j, lens, fetch, layer: (
+                layer[0], fetch[(bi * nblk + j) * ppb + i], 0, 0))
 
+    row = lambda bi, j, lens, fetch, layer: (bi, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, nblk),
-        in_specs=[pl.BlockSpec((1, h, dk), lambda bi, j, lens, fetch:
-                               (bi, 0, 0)),
+        in_specs=[pl.BlockSpec((1, h, dk), row),
                   *[page_spec(i) for i in range(ppb)]],
-        out_specs=pl.BlockSpec((1, h, v_dim), lambda bi, j, lens, fetch:
-                               (bi, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, v_dim), row),
         scratch_shapes=[pltpu.VMEM((h, v_dim), jnp.float32),
                         pltpu.VMEM((h, 1), jnp.float32),
                         pltpu.VMEM((h, 1), jnp.float32)],
@@ -284,7 +308,7 @@ def paged_latent_attention(q, pages, page_table, kv_lens, *, sm_scale: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(kv_lens, fetch, q, *[pages] * ppb)
+    )(kv_lens, fetch, layer, q, *[pages] * ppb)
 
 
 def latent_attention_reference(q, rows, kv_len, *, sm_scale: float,

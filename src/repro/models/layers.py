@@ -203,17 +203,19 @@ def attention_chunked(q, k, v, *, causal: bool = True,
     return out.transpose(0, 2, 1, 3).astype(v.dtype)
 
 
-def attention(q, k, v, *, impl: str = "ref", page_table=None, **kw):
+def attention(q, k, v, *, impl: str = "ref", page_table=None, layer=None,
+              **kw):
     if page_table is not None:
-        # paged decode: k/v are (P+1, page_size, Hkv, dh) pools and
-        # page_table is the (B, max_pages) per-row physical map. On the TPU
-        # the kernel walks the table and fetches only each row's live pages;
-        # elsewhere the reference gathers the logical dense layout (and is
-        # the kernel's oracle). Positions >= kv_len mask to exact-zero
-        # probability either way, so paged == dense bitwise for identical
-        # cache contents on the same path. REPRO_DECODE_ATTN=interpret
-        # runs the kernel in interpret mode for a config with
-        # attn_impl="pallas", so CPU tests can reach it.
+        # paged decode: k/v are the stacked (L, P+1, page_size, Hkv, dh)
+        # pools, read at `layer`, and page_table is the (B, max_pages)
+        # per-row physical map. On the TPU the kernel walks the table and
+        # fetches only each row's live pages; elsewhere the reference
+        # gathers the logical dense layout (and is the kernel's oracle).
+        # Positions >= kv_len mask to exact-zero probability either way, so
+        # paged == dense bitwise for identical cache contents on the same
+        # path. REPRO_DECODE_ATTN=interpret runs the kernel in interpret
+        # mode for a config with attn_impl="pallas", so CPU tests can reach
+        # it.
         assert q.shape[1] == 1 and kw.get("window") is None \
             and kw.get("kv_len") is not None
         interpret = (impl == "pallas" and os.environ.get(
@@ -222,11 +224,11 @@ def attention(q, k, v, *, impl: str = "ref", page_table=None, **kw):
             from repro.kernels.decode_attention.paged import \
                 paged_decode_attention
             return paged_decode_attention(q, k, v, page_table, kw["kv_len"],
-                                          interpret=interpret)
+                                          layer, interpret=interpret)
         from repro.kernels.decode_attention.paged import gather_pages
         kw.pop("kv_block", None)
-        return attention_ref(q, gather_pages(k, page_table),
-                             gather_pages(v, page_table), **kw)
+        return attention_ref(q, gather_pages(k, page_table, layer),
+                             gather_pages(v, page_table, layer), **kw)
     if q.shape[1] == 1:
         # decode: one query row. impl == "pallas" on TPU streams the cache
         # through the ragged decode kernel (per-row kv_len, model layout —

@@ -206,13 +206,15 @@ def latent_out(cfg, lp, o_lat):
     return o.reshape(o.shape[0], o.shape[1], -1)
 
 
-def latent_attention(cfg, q, rows_or_pool, kv_len, page_table=None):
+def latent_attention(cfg, q, rows_or_pool, kv_len, page_table=None,
+                     layer=None):
     """Absorbed decode attention of q (B, H, r + dr) over dense rows (B, M,
-    r + dr) or, with `page_table`, a pool (P+1, ps, r + dr). On the TPU a
-    pool goes through the paged latent kernel (as paged MHA decode does,
-    chosen by the platform; REPRO_DECODE_ATTN=interpret with attn_impl
-    "pallas" runs it in interpret mode elsewhere); otherwise the pages are
-    gathered and the reference runs. Returns (B, H, r)."""
+    r + dr) or, with `page_table`, the stacked pools (L, P+1, ps, r + dr)
+    read at `layer`. On the TPU a pool goes through the paged latent kernel
+    (as paged MHA decode does, chosen by the platform;
+    REPRO_DECODE_ATTN=interpret with attn_impl "pallas" runs it in
+    interpret mode elsewhere); otherwise the pages are gathered and the
+    reference runs. Returns (B, H, r)."""
     from repro.kernels.decode_attention.paged import (
         gather_pages, latent_attention_reference, paged_latent_attention)
     kw = dict(sm_scale=_scale(cfg), v_dim=cfg.kv_lora_rank)
@@ -222,9 +224,9 @@ def latent_attention(cfg, q, rows_or_pool, kv_len, page_table=None):
         "REPRO_DECODE_ATTN") == "interpret")
     if interpret or jax.default_backend() == "tpu":
         return paged_latent_attention(q, rows_or_pool, page_table, kv_len,
-                                      interpret=interpret, **kw)
+                                      layer, interpret=interpret, **kw)
     return latent_attention_reference(
-        q, gather_pages(rows_or_pool, page_table), kv_len, **kw)
+        q, gather_pages(rows_or_pool, page_table, layer), kv_len, **kw)
 
 
 # --------------------------------------------------------------------------
@@ -250,13 +252,14 @@ def _mlp(cfg, lp, x, moe):
 
 
 def block(cfg, x, lp, cos, sin, moe, *, cache=None, pos=None,
-          page_table=None):
+          page_table=None, layer=None):
     """One block. cache None: causal attention over x itself (training
     forward). Else `cache` is this layer's dense rows (B, M, r + dr) or,
-    with `page_table`, its pool (P+1, ps, r + dr); the new rows are written
-    at per-row positions `pos` (B,) onward, and the queries attend to every
-    cached position below their own + 1: naive for a prompt (S > 1),
-    absorbed for a decode token. Returns (x, new cache, expert counts)."""
+    with `page_table`, its stack's pools (L, P+1, ps, r + dr), written and
+    read at `layer` in place; the new rows are written at per-row
+    positions `pos` (B,) onward, and the queries attend to every cached
+    position below their own + 1: naive for a prompt (S > 1), absorbed for
+    a decode token. Returns (x, new cache, expert counts)."""
     b, s, _ = x.shape
     lp = jax.tree.map(lambda a: a.astype(cfg.cdtype), lp)
     with jax.named_scope("attention"):
@@ -266,12 +269,14 @@ def block(cfg, x, lp, cos, sin, moe, *, cache=None, pos=None,
             attn = naive_attention(cfg, lp, q_nope, q_pe, row,
                                    (qp[None, :] <= qp[:, None])[None])
         elif page_table is not None:
-            ps = cache.shape[1]
+            ps = cache.shape[2]
             pids = page_table[jnp.arange(b), pos // ps]
-            cache = cache.at[pids, pos % ps].set(row[:, 0].astype(cache.dtype))
+            cache = cache.at[layer, pids, pos % ps].set(
+                row[:, 0].astype(cache.dtype))
             q = latent_query(cfg, lp, q_nope, q_pe)[:, 0]
             with jax.named_scope("latent"):
-                o = latent_attention(cfg, q, cache, pos + 1, page_table)
+                o = latent_attention(cfg, q, cache, pos + 1, page_table,
+                                     layer)
             attn = latent_out(cfg, lp, o[:, None])
         else:
             cols = pos[:, None] + jnp.arange(s)[None]
@@ -342,19 +347,35 @@ def init_paged_pools(cfg, pool_pages: int, page_size: int, dtype=None):
 
 
 def _run_stacks(cfg, params, x, cache, pos, suffix, page_table=None):
-    """Every stack's scan over (weights, cache). Returns (x, new caches,
+    """Every stack's scan over its weights. Dense rows ride as the scan's
+    xs and ys, a layer's rows each; with `page_table` the carry holds the
+    stack's pools, written in place at each layer. Returns (x, new caches,
     summed held-expert counts, summed per-layer peaks)."""
     cos, sin = _cos_sin(cfg, _qpos(pos, x.shape[1]))
     new, total, peak = {}, jnp.int32(0), jnp.int32(0)
     with jax.named_scope("layers"):
-        for pname, c, _, moe in stacks(cfg):
-            def body(x, xs, moe=moe):
-                lp, lc = xs
-                x, lc, counts = block(cfg, x, lp, cos, sin, moe, cache=lc,
-                                      pos=pos, page_table=page_table)
-                return x, (lc, counts if moe else jnp.zeros((1,), jnp.int32))
-            x, (new[c + suffix], counts) = jax.lax.scan(
-                body, x, (params[pname], cache[c + suffix]))
+        for pname, c, n, moe in stacks(cfg):
+            if page_table is None:
+                def body(x, xs, moe=moe):
+                    lp, lc = xs
+                    x, lc, counts = block(cfg, x, lp, cos, sin, moe,
+                                          cache=lc, pos=pos)
+                    return x, (lc, counts if moe
+                               else jnp.zeros((1,), jnp.int32))
+                x, (new[c + suffix], counts) = jax.lax.scan(
+                    body, x, (params[pname], cache[c + suffix]))
+            else:
+                def body(carry, xs, moe=moe):
+                    (x, pool), (lp, layer) = carry, xs
+                    x, pool, counts = block(cfg, x, lp, cos, sin, moe,
+                                            cache=pool, pos=pos,
+                                            page_table=page_table,
+                                            layer=layer)
+                    return (x, pool), (counts if moe
+                                       else jnp.zeros((1,), jnp.int32))
+                (x, new[c + suffix]), counts = jax.lax.scan(
+                    body, (x, cache[c + suffix]),
+                    (params[pname], jnp.arange(n)))
             if moe:
                 total += counts.sum(dtype=jnp.int32)
                 peak += counts.max(axis=1).sum(dtype=jnp.int32)

@@ -246,7 +246,9 @@ _BLOCK_WSPECS = {
 
 def _block(cfg: TransformerConfig, x, lp, cos, sin, *, q_offset=0,
            cache=None, kv_len=None):
-    """One transformer block. cache: (k, v) of (B, M, Hkv, hd) to update."""
+    """One transformer block. cache: (k, v) of (B, M, Hkv, hd) to update,
+    or for paged decode (kp, vp, page_table, layer): the stacked pools
+    (L, P+1, ps, Hkv, hd), updated at `layer` in place."""
     b, s, d = x.shape
     hd, h, hkv = cfg.hd, cfg.n_heads, cfg.n_kv_heads
     if cfg.fsdp_hints:
@@ -285,19 +287,21 @@ def _block(cfg: TransformerConfig, x, lp, cos, sin, *, q_offset=0,
             q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
 
         new_cache = None
-        page_table = None
-        if cache is not None and len(cache) == 3:
-            # paged decode (s == 1): k/v pools (P+1, ps, Hkv, dh) + per-row
-            # page table. Each row writes its token at (table[pos // ps],
-            # pos % ps); rows with no mapped page there (inactive slots)
-            # land on the trash page. Active rows always write distinct
-            # pages — prefix-shared pages only cover positions <
-            # prompt_len, below any decode write.
-            kp, vp, page_table = cache
-            ps = kp.shape[1]
+        page_table = layer = None
+        if cache is not None and len(cache) == 4:
+            # paged decode (s == 1): the stacked k/v pools (L, P+1, ps, Hkv,
+            # dh), the per-row page table and this block's layer. Each row
+            # writes its token at (layer, table[pos // ps], pos % ps), in
+            # place; rows with no mapped page there (inactive slots) land
+            # on the trash page. Active rows always write distinct pages —
+            # prefix-shared pages only cover positions < prompt_len, below
+            # any decode write.
+            kp, vp, page_table, layer = cache
+            ps = kp.shape[2]
             pids = page_table[jnp.arange(b), q_offset // ps]
-            kp = kp.at[pids, q_offset % ps].set(k[:, 0].astype(kp.dtype))
-            vp = vp.at[pids, q_offset % ps].set(v[:, 0].astype(vp.dtype))
+            at = (layer, pids, q_offset % ps)
+            kp = kp.at[at].set(k[:, 0].astype(kp.dtype))
+            vp = vp.at[at].set(v[:, 0].astype(vp.dtype))
             k, v, new_cache = kp, vp, (kp, vp)
         elif cache is not None:
             ck, cv = cache
@@ -326,7 +330,8 @@ def _block(cfg: TransformerConfig, x, lp, cos, sin, *, q_offset=0,
             # position
             attn = attention(q, k, v, impl=cfg.attn_impl, causal=s > 1,
                              window=cfg.window, kv_len=kv_len,
-                             q_offset=q_offset, page_table=page_table)
+                             q_offset=q_offset, page_table=page_table,
+                             layer=layer)
         else:
             attn = attention(q, k, v, impl=cfg.attn_impl, causal=True,
                              window=cfg.window, q_offset=q_offset,
@@ -573,7 +578,9 @@ def paged_decode_step(params, cache, tokens, cfg: TransformerConfig):
     """One paged decode step: tokens (B, 1). cache carries "kp"/"vp" pools
     (L, P+1, ps, Hkv, dh), "ptab" (B, max_pages) int32 and "pos" (B,).
     Returns (logits (B, V), new cache). Positions/rope/sinusoidal handling
-    mirrors decode_step exactly so paged == dense bitwise."""
+    mirrors decode_step exactly so paged == dense bitwise. The layer scan
+    carries the stacked pools, so each layer writes its rows' new position
+    in place and the kernel reads its layer from the same buffer."""
     if cfg.is_mla:
         return mla.paged_decode_step(params, cache, tokens, cfg)
     pos0 = cache["pos"]                      # (B,) per-slot positions
@@ -590,15 +597,17 @@ def paged_decode_step(params, cache, tokens, cfg: TransformerConfig):
     kv_len = pos0 + s
     ptab = cache["ptab"]
 
-    def body(x, xs):
-        lp, kp, vp = xs
-        x, new_cache = _block(cfg, x, lp, cos, sin, q_offset=pos0,
-                              cache=(kp, vp, ptab), kv_len=kv_len)
-        return x, new_cache
+    def body(carry, xs):
+        x, kp, vp = carry
+        lp, layer = xs
+        x, (kp, vp) = _block(cfg, x, lp, cos, sin, q_offset=pos0,
+                             cache=(kp, vp, ptab, layer), kv_len=kv_len)
+        return (x, kp, vp), None
 
     with jax.named_scope("layers"):
-        x, (nkp, nvp) = jax.lax.scan(
-            body, x, (params["layers"], cache["kp"], cache["vp"]))
+        (x, nkp, nvp), _ = jax.lax.scan(
+            body, (x, cache["kp"], cache["vp"]),
+            (params["layers"], jnp.arange(cfg.n_layers)))
     with jax.named_scope("head"):
         x = _norm(cfg, x, params["final_norm"].astype(cfg.cdtype),
                   params.get("final_norm_bias"))

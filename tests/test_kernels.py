@@ -374,6 +374,50 @@ class TestPagedLatentAttention:
                                    atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("kind", ["mha", "latent"])
+def test_stacked_pools_read_their_layer(kind, layer):
+    """Either paged kernel, given three layers' pools stacked (L, P+1, ps,
+    ...) and a layer index, returns bitwise what it returns on that
+    layer's pool alone, and poisoning every other layer's pages (trash
+    pages included) changes no bit."""
+    n_layers, b, ps, mp, pool_pages = 3, 2, 16, 12, 24
+    kq, kp, kt = jax.random.split(jax.random.PRNGKey(31), 3)
+    ptab = jax.random.permutation(kt, pool_pages)[:b * mp]
+    ptab = ptab.reshape(b, mp).astype(jnp.int32)
+    lens = jnp.asarray([ps * 2 + 5, ps * mp - 2], jnp.int32)
+    if kind == "mha":
+        h, hkv, dh = 4, 2, 64
+        q = jax.random.normal(kq, (b, h, dh))
+        pools = tuple(jax.random.normal(
+            kp, (2, n_layers, pool_pages + 1, ps, hkv, dh)))
+
+        def run(pools, *at):
+            return paged_decode_attention_fwd(q, *pools, ptab, lens, *at,
+                                              interpret=True)
+    else:
+        from repro.kernels.decode_attention.paged import (
+            paged_latent_attention)
+        c = TestPagedLatentAttention
+        q = jax.random.normal(kq, (b, c.H, c.DK), jnp.bfloat16)
+        pools = (jax.random.normal(kp, (n_layers, pool_pages + 1, ps, c.DK),
+                                   jnp.bfloat16),)
+
+        def run(pools, *at):
+            return paged_latent_attention(q, *pools, ptab, lens, *at,
+                                          sm_scale=c.SCALE, v_dim=c.DV,
+                                          interpret=True)
+    alone = run(tuple(p[layer] for p in pools))
+    stacked = run(pools, layer)
+    np.testing.assert_array_equal(np.asarray(stacked), np.asarray(alone))
+    other = jnp.arange(n_layers) != layer
+    poisoned = tuple(
+        jnp.where(other.reshape(-1, *[1] * (p.ndim - 1)), sign * 1e4, p)
+        .astype(p.dtype) for p, sign in zip(pools, (1, -1)))
+    np.testing.assert_array_equal(np.asarray(run(poisoned, layer)),
+                                  np.asarray(alone))
+
+
 class TestRGLRUScan:
     @pytest.mark.parametrize("b,s,d", [(2, 256, 128), (1, 512, 256),
                                        (3, 128, 384)])

@@ -1,4 +1,6 @@
 """Serving engine tests: continuous batching, determinism, decode parity."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -393,8 +395,6 @@ def _op_scopes(hlo_text):
     """(opcode, innermost named scope or "other", whether the op is the
     layer scan's own, output shape) of every instruction of a compiled HLO
     module that carries a metadata op_name."""
-    import re
-
     out = []
     for shape, op, path in re.findall(
             r"= (\S+) ([\w-]+)\(.*?op_name=\"([^\"]*)\"", hlo_text):
@@ -431,9 +431,16 @@ def test_programs_carry_named_scopes(setup, page_size):
         scan = {(op, sc) for op, sc, own, _ in ops if own}
         assert ("dynamic-slice", "layers") in scan, name
         assert {sc for _, sc in scan} == {"layers"}, name
-        if name == "decode":
+        if name == "decode" and not page_size:
             assert ("dynamic-update-slice", "layers") in scan
         if name == "decode" and page_size:
+            # the layer scan carries the stacked pools and writes each
+            # layer's new rows in place: no op, under `layers` or
+            # elsewhere, has the shape of one layer's pool
+            one = ",".join(map(str, eng.cache["kp"].shape[1:]))
+            pool = {(op, sc) for op, sc, _, shape in ops
+                    if re.search(rf"\[(1,)?{one}\]", shape)}
+            assert not pool, pool
             # the paged decode gathers each row's whole page table into a
             # dense row: every op of that shape sits under `attention`
             (rows, mp), kp = eng.cache["ptab"].shape, eng.cache["kp"].shape
