@@ -31,7 +31,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from . import mla as _mla
 from . import rglru as _rglru
 from . import transformer as _transformer
 from . import xlstm as _xlstm
@@ -300,9 +299,7 @@ class TransformerDecodeState(DecodeStateSpec):
 
     def __init__(self, cfg: TransformerConfig):
         super().__init__(cfg)
-        self.state_kind = "latent" if cfg.is_mla else "kv"
-        if cfg.is_moe:
-            self.state_kind += "+experts"
+        self.state_kind = _transformer.state_kind(cfg)
         self.names = _transformer.cache_names(cfg)
 
     def init_state(self, batch, max_len, dtype=None):
@@ -470,19 +467,17 @@ class PagedTransformerDecodeState(TransformerDecodeState):
                                     trash, jnp.int32)
             st["pf_len"] = jnp.zeros((self.prefix_entries,), jnp.int32)
         if self.cfg.moe_d_ff:
-            st.update(dict.fromkeys(_mla.COUNTERS, jnp.zeros((), jnp.int32)))
+            st.update(dict.fromkeys(_transformer.COUNTERS,
+                                    jnp.zeros((), jnp.int32)))
         return st
 
     def counters(self, state):
-        return {k: state[k] for k in _mla.COUNTERS if k in state}
+        return {k: state[k] for k in _transformer.COUNTERS if k in state}
 
     # axis declarations describe the WIRE format (the dense logical
     # layout every bundle travels in), not the pool — all physical-layout
-    # ops are overridden below.
-    def decode(self, params, state, last):
-        return _transformer.paged_decode_step(params, state, last,
-                                              self.cfg)
-
+    # ops are overridden below. `decode` is the dense spec's: the trunk
+    # reads a state holding "ptab" through its page table.
     def advance(self, state, active):
         """Map one fresh page for each active row whose next write
         position starts a new page (pos % ps == 0)."""

@@ -37,14 +37,10 @@ each flip swaps a whole expert's output (at the reduced size, the widest
 served-token gap over 100 requests of one seed fell from 0.49 to 0.07
 logits with the float32 stream).
 
-Named scopes: `attention`, `latent` (the latent attention call), `mlp`,
-`experts` (routing and the held experts' products), and the trunk's
-`embed`, `layers`, `head`. Paged decode adds to the state's counters:
-`decode_steps` (one a sub-step), `expert_tokens` (assignments to held
-experts) and `expert_tokens_peak` (per layer and sub-step, the busiest
-held expert's count), summed over layers and sub-steps, and
-`latent_positions` (per sub-step, the cached positions that the rows
-holding pages attend to, one layer's worth).
+Named scopes: `attention`, `latent` (the latent attention call), `mlp`
+and `experts` (routing and the held experts' products). The block returns
+each held expert's count of assigned rows, which the trunk
+(models/transformer.py) sums into the paged state's counters.
 """
 from __future__ import annotations
 
@@ -53,13 +49,12 @@ import os
 import jax
 import jax.numpy as jnp
 
-from .layers import _qpos, apply_rope, rms_norm, rope_cos_sin, swiglu
+from .layers import apply_rope, rms_norm, rope_cos_sin, swiglu
 from .moe import held_experts_ffn
 
 KV_NORM_EPS = 1e-6      # the latent's RMSNorm keeps its class default
 NEG_INF = -1e30
-COUNTERS = ("decode_steps", "expert_tokens", "expert_tokens_peak",
-            "latent_positions")
+RESIDUAL = "float32"    # dtype of the residual stream (Precision, above)
 
 
 def row_width(cfg) -> int:
@@ -67,16 +62,10 @@ def row_width(cfg) -> int:
     return cfg.kv_lora_rank + cfg.qk_rope_head_dim
 
 
-def stacks(cfg):
-    """(params key, dense cache key, layer count, is MoE) of each layer
-    stack, in the order the trunk runs them."""
-    f = cfg.first_dense_layers
-    out = [("dense_layers", "ckv0", f, False)] if f else []
-    return out + [("layers", "ckv", cfg.n_layers - f, cfg.moe_d_ff > 0)]
-
-
-def cache_names(cfg):
-    return tuple(c for _, c, _, _ in stacks(cfg))
+def rope_angles(cfg, positions):
+    """cos, sin of the rope key's angles at positions (S,) or (B, S)."""
+    return rope_cos_sin(positions, cfg.qk_rope_head_dim, cfg.rope_base,
+                        cfg.cdtype)
 
 
 # --------------------------------------------------------------------------
@@ -110,31 +99,19 @@ def _fan_in(name, shape):
     return shape[-2]
 
 
-def init_params(key, cfg):
-    """normal(0, fan_in^-1/2) projections, ones for norms, zeros for the
-    selection bias; embedding normal(0, 1); an untied head."""
+def init_stack(keys, cfg, n: int, moe: bool) -> dict:
+    """One stack of n blocks, drawing from the key iterator `keys`:
+    normal(0, fan_in^-1/2) projections, ones for norms, zeros for the
+    selection bias."""
     dt = cfg.pdtype
-    d, v = cfg.d_model, cfg.vocab_size
-    keys = iter(jax.random.split(key, 64))
-
-    def stack(n, moe):
-        out = {}
-        for name, shape in layer_shapes(cfg, moe).items():
-            fan = _fan_in(name, shape)
-            full = (n,) + shape
-            out[name] = (jnp.ones(full, dt) if fan is None else
-                         jnp.zeros(full, dt) if fan == 0 else
-                         jax.random.normal(next(keys), full, dt)
-                         * fan ** -0.5)
-        return out
-
-    params = {"embed": jax.random.normal(next(keys), (v, d), dt),
-              "final_norm": jnp.ones((d,), dt),
-              "lm_head": jax.random.normal(next(keys), (d, v), dt)
-              * d ** -0.5}
-    for pname, _, n, moe in stacks(cfg):
-        params[pname] = stack(n, moe)
-    return params
+    out = {}
+    for name, shape in layer_shapes(cfg, moe).items():
+        fan = _fan_in(name, shape)
+        full = (n,) + shape
+        out[name] = (jnp.ones(full, dt) if fan is None else
+                     jnp.zeros(full, dt) if fan == 0 else
+                     jax.random.normal(next(keys), full, dt) * fan ** -0.5)
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -232,10 +209,10 @@ def latent_attention(cfg, q, rows_or_pool, kv_len, page_table=None,
 # --------------------------------------------------------------------------
 # the block
 # --------------------------------------------------------------------------
-def _mlp(cfg, lp, x, moe):
-    """SwiGLU (dense stack) or shared + held routed experts. Returns (out,
-    per-held-expert counts or None)."""
-    if not moe:
+def _mlp(cfg, lp, x):
+    """SwiGLU (a dense stack's block) or shared + held routed experts (a
+    block with a router). Returns (out, per-held-expert counts or None)."""
+    if "router" not in lp:
         return swiglu(x, lp["wi_gate"], lp["wi_up"], lp["wo_mlp"]), None
     b, s, d = x.shape
     with jax.named_scope("experts"):
@@ -251,15 +228,16 @@ def _mlp(cfg, lp, x, moe):
     return routed.reshape(b, s, d) + shared, counts
 
 
-def block(cfg, x, lp, cos, sin, moe, *, cache=None, pos=None,
-          page_table=None, layer=None):
+def block(cfg, x, lp, cos, sin, *, cache=None, pos=None, page_table=None,
+          layer=None):
     """One block. cache None: causal attention over x itself (training
-    forward). Else `cache` is this layer's dense rows (B, M, r + dr) or,
-    with `page_table`, its stack's pools (L, P+1, ps, r + dr), written and
-    read at `layer` in place; the new rows are written at per-row
-    positions `pos` (B,) onward, and the queries attend to every cached
-    position below their own + 1: naive for a prompt (S > 1), absorbed for
-    a decode token. Returns (x, new cache, expert counts)."""
+    forward). Else `cache` is (rows,): this layer's dense rows (B, M, r +
+    dr) or, with `page_table`, its stack's pool (L, P+1, ps, r + dr),
+    written and read at `layer` in place; the new rows are written at
+    positions `pos` (a scalar, or per row (B,)) onward, and the queries
+    attend to every cached position below their own + 1: naive for a
+    prompt (S > 1), absorbed for a decode token. Returns (x, new cache,
+    per-held-expert counts or None)."""
     b, s, _ = x.shape
     lp = jax.tree.map(lambda a: a.astype(cfg.cdtype), lp)
     with jax.named_scope("attention"):
@@ -269,154 +247,36 @@ def block(cfg, x, lp, cos, sin, moe, *, cache=None, pos=None,
             attn = naive_attention(cfg, lp, q_nope, q_pe, row,
                                    (qp[None, :] <= qp[:, None])[None])
         elif page_table is not None:
-            ps = cache.shape[2]
+            (pool,) = cache
+            ps = pool.shape[2]
             pids = page_table[jnp.arange(b), pos // ps]
-            cache = cache.at[layer, pids, pos % ps].set(
-                row[:, 0].astype(cache.dtype))
+            pool = pool.at[layer, pids, pos % ps].set(
+                row[:, 0].astype(pool.dtype))
+            cache = (pool,)
             q = latent_query(cfg, lp, q_nope, q_pe)[:, 0]
             with jax.named_scope("latent"):
-                o = latent_attention(cfg, q, cache, pos + 1, page_table,
+                o = latent_attention(cfg, q, pool, pos + 1, page_table,
                                      layer)
             attn = latent_out(cfg, lp, o[:, None])
         else:
+            (rows,) = cache
+            pos = jnp.broadcast_to(pos, (b,))
             cols = pos[:, None] + jnp.arange(s)[None]
-            cache = cache.at[jnp.arange(b)[:, None], cols].set(
-                row.astype(cache.dtype))
+            rows = rows.at[jnp.arange(b)[:, None], cols].set(
+                row.astype(rows.dtype))
+            cache = (rows,)
             if s > 1:
-                kpos = jnp.arange(cache.shape[1])
-                attn = naive_attention(cfg, lp, q_nope, q_pe, cache,
+                kpos = jnp.arange(rows.shape[1])
+                attn = naive_attention(cfg, lp, q_nope, q_pe, rows,
                                        kpos[None, None] <= cols[..., None])
             else:
                 q = latent_query(cfg, lp, q_nope, q_pe)[:, 0]
                 with jax.named_scope("latent"):
-                    o = latent_attention(cfg, q, cache, pos + 1)
+                    o = latent_attention(cfg, q, rows, pos + 1)
                 attn = latent_out(cfg, lp, o[:, None])
         x = x + (attn @ lp["wo"]).astype(x.dtype)
     with jax.named_scope("mlp"):
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps).astype(cfg.cdtype)
-        out, counts = _mlp(cfg, lp, h, moe)
+        out, counts = _mlp(cfg, lp, h)
         x = x + out.astype(x.dtype)
     return x, cache, counts
-
-
-# --------------------------------------------------------------------------
-# trunk
-# --------------------------------------------------------------------------
-def _cos_sin(cfg, positions):
-    return rope_cos_sin(positions, cfg.qk_rope_head_dim, cfg.rope_base,
-                        cfg.cdtype)
-
-
-def _head(cfg, params, x):
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"].astype(jnp.float32),
-                     cfg.rms_norm_eps).astype(cfg.cdtype)
-        return x @ params["lm_head"].astype(cfg.cdtype)
-
-
-def forward(params, tokens, cfg, positions=None):
-    """tokens (B, S) -> logits (B, S, V); naive attention, causal."""
-    x = params["embed"][tokens].astype(jnp.float32)
-    cos, sin = _cos_sin(cfg, jnp.arange(tokens.shape[1])
-                        if positions is None else positions)
-    for pname, _, _, moe in stacks(cfg):
-        def body(x, lp, moe=moe):
-            return block(cfg, x, lp, cos, sin, moe)[0], None
-        x, _ = jax.lax.scan(body, x, params[pname])
-    return _head(cfg, params, x)
-
-
-def init_cache(cfg, batch: int, max_len: int, dtype=None, pad_to: int = 128):
-    """Dense latent rows per layer stack, (n, B, M, r + dr), M padded to
-    `pad_to` as the MHA cache is."""
-    dtype = dtype or cfg.cdtype
-    m = -(-max_len // pad_to) * pad_to
-    out = {c: jnp.zeros((n, batch, m, row_width(cfg)), dtype)
-           for _, c, n, _ in stacks(cfg)}
-    out["pos"] = jnp.zeros((), jnp.int32)
-    return out
-
-
-def init_paged_pools(cfg, pool_pages: int, page_size: int, dtype=None):
-    """Latent pages per layer stack, (n, P+1, ps, r + dr); the last page
-    is the trash page. Keys are the dense keys + "p"."""
-    dtype = dtype or cfg.cdtype
-    return {c + "p": jnp.zeros((n, pool_pages + 1, page_size,
-                                row_width(cfg)), dtype)
-            for _, c, n, _ in stacks(cfg)}
-
-
-def _run_stacks(cfg, params, x, cache, pos, suffix, page_table=None):
-    """Every stack's scan over its weights. Dense rows ride as the scan's
-    xs and ys, a layer's rows each; with `page_table` the carry holds the
-    stack's pools, written in place at each layer. Returns (x, new caches,
-    summed held-expert counts, summed per-layer peaks)."""
-    cos, sin = _cos_sin(cfg, _qpos(pos, x.shape[1]))
-    new, total, peak = {}, jnp.int32(0), jnp.int32(0)
-    with jax.named_scope("layers"):
-        for pname, c, n, moe in stacks(cfg):
-            if page_table is None:
-                def body(x, xs, moe=moe):
-                    lp, lc = xs
-                    x, lc, counts = block(cfg, x, lp, cos, sin, moe,
-                                          cache=lc, pos=pos)
-                    return x, (lc, counts if moe
-                               else jnp.zeros((1,), jnp.int32))
-                x, (new[c + suffix], counts) = jax.lax.scan(
-                    body, x, (params[pname], cache[c + suffix]))
-            else:
-                def body(carry, xs, moe=moe):
-                    (x, pool), (lp, layer) = carry, xs
-                    x, pool, counts = block(cfg, x, lp, cos, sin, moe,
-                                            cache=pool, pos=pos,
-                                            page_table=page_table,
-                                            layer=layer)
-                    return (x, pool), (counts if moe
-                                       else jnp.zeros((1,), jnp.int32))
-                (x, new[c + suffix]), counts = jax.lax.scan(
-                    body, (x, cache[c + suffix]),
-                    (params[pname], jnp.arange(n)))
-            if moe:
-                total += counts.sum(dtype=jnp.int32)
-                peak += counts.max(axis=1).sum(dtype=jnp.int32)
-    return x, new, total, peak
-
-
-def _embed(cfg, params, tokens):
-    with jax.named_scope("embed"):
-        return params["embed"][tokens].astype(jnp.float32)
-
-
-def decode_step(params, cache, tokens, cfg, positions=None, last_idx=None):
-    """Dense-row decode or prefill of tokens (B, S) from per-row (or
-    scalar) positions cache["pos"]. Returns (logits (B, V) at each row's
-    `last_idx` or last position, new cache)."""
-    pos0 = cache["pos"]
-    b, s = tokens.shape
-    x = _embed(cfg, params, tokens)
-    pos = jnp.broadcast_to(pos0, (b,))
-    x, new, _, _ = _run_stacks(cfg, params, x, cache, pos, "")
-    if last_idx is not None:
-        x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
-    logits = _head(cfg, params, x[:, -1:])[:, -1]
-    return logits, {**cache, **new, "pos": pos0 + s}
-
-
-def paged_decode_step(params, cache, tokens, cfg):
-    """One decode token a row, tokens (B, 1), against the latent pools
-    (keys of `init_paged_pools`), the (B, max_pages) table "ptab" and the
-    (B,) positions "pos"; the counters, where the state has them, grow by
-    this step's (a row whose pages were released attends to none)."""
-    pos0 = cache["pos"]
-    x = _embed(cfg, params, tokens)
-    x, new, total, peak = _run_stacks(cfg, params, x, cache, pos0, "p",
-                                      page_table=cache["ptab"])
-    logits = _head(cfg, params, x)[:, -1]
-    out = {**cache, **new, "pos": pos0 + 1}
-    if COUNTERS[0] in cache:
-        trash = cache[stacks(cfg)[-1][1] + "p"].shape[1] - 1
-        live = jnp.where(cache["ptab"][:, 0] != trash, pos0 + 1, 0)
-        for name, grow in zip(COUNTERS, (1, total, peak,
-                                         live.sum(dtype=jnp.int32))):
-            out[name] = cache[name] + grow
-    return logits, out

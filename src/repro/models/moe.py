@@ -1,10 +1,10 @@
 """Mixture-of-Experts FFN: top-k routing with capacity-based dispatch.
 
-TPU-native adaptation notes (DESIGN.md §3): instead of the GPU-style
-scatter/gather with dynamic shapes — or the GShard one-hot dispatch einsums,
-whose (tokens x experts x capacity) matmuls inflate HLO FLOPs by orders of
-magnitude and wreck the compute roofline — we use a sort-based static-shape
-dispatch:
+TPU-native adaptation notes (docs/ARCHITECTURE.md, "Model trunk"): instead
+of the GPU-style scatter/gather with dynamic shapes — or the GShard one-hot
+dispatch einsums, whose (tokens x experts x capacity) matmuls inflate HLO
+FLOPs by orders of magnitude and wreck the compute roofline — we use a
+sort-based static-shape dispatch:
 
   1. top-k expert choice per token (router in fp32),
   2. flat (token, expert) assignments sorted by expert id,

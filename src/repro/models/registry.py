@@ -34,7 +34,7 @@ SHAPES = {
 }
 
 # Sub-quadratic archs run long_500k; pure full-attention archs skip it
-# (DESIGN.md §Arch-applicability).
+# (docs/ARCHITECTURE.md, "Model trunk").
 SUBQUADRATIC = {"xlstm-350m", "recurrentgemma-2b"}
 
 

@@ -1,9 +1,17 @@
 """Decoder-only transformer LM covering the dense, MoE, VLM and audio
 architecture families via configuration.
 
+One trunk serves every config: embed -> a list of layer stacks -> final
+norm and head, for the training forward, dense-row decode and prefill, and
+paged decode alike (docs/ARCHITECTURE.md, "Model trunk"). `stacks(cfg)`
+names each stack's params key, cache leaves, layer count and attention
+kind; a kind (`Kind`) gives the block, its weights, its cached row shape,
+its rope angles and the dtype of its residual stream. MHA/GQA is this
+module's `_block`; latent attention is models/mla.py's.
+
 Parameters are plain pytrees with per-layer weights STACKED on a leading L
-axis and the forward pass runs `lax.scan` over layers — essential to keep
-the HLO (and 512-device SPMD compile time) small for the 40-64 layer archs.
+axis and each stack runs `lax.scan` over layers — essential to keep the HLO
+(and 512-device SPMD compile time) small for the 40-64 layer archs.
 
 Supports:
   - GQA/MQA/MHA (+ optional QKV bias), RoPE / M-RoPE / sinusoidal positions
@@ -17,8 +25,8 @@ Supports:
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field, replace
-from typing import Optional
+from dataclasses import dataclass
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -104,7 +112,7 @@ class TransformerConfig:
         return self.experts_held or self.num_experts
 
     def __post_init__(self):
-        if self.moe_d_ff and not self.is_mla:
+        if self.moe_d_ff and not self.kv_lora_rank:
             raise ValueError("held-share experts (moe_d_ff) come with the "
                              "latent-attention block (kv_lora_rank > 0)")
         if self.expert_offset + self.n_held > self.num_experts:
@@ -139,15 +147,70 @@ class TransformerConfig:
 
 
 # --------------------------------------------------------------------------
+# the stack list
+# --------------------------------------------------------------------------
+class Kind(NamedTuple):
+    """What the trunk takes from an attention kind. Its block is
+    (cfg, x, lp, cos, sin, *, cache, pos, page_table, layer) -> (x, cache,
+    per-expert counts or None)."""
+    name: str            # DecodeState's state kind ("kv", "latent")
+    block: Callable
+    init: Callable       # (keys, cfg, n, moe) -> one stack's weights
+    row: Callable        # cfg -> shape of a cache leaf's row per position
+    angles: Callable     # (cfg, positions) -> rope (cos, sin)
+    residual: Optional[str] = None   # residual dtype (None: compute dtype)
+
+
+class Stack(NamedTuple):
+    params: str          # params key of its stacked weights
+    names: tuple         # its dense cache leaves; their pools add "p"
+    n: int               # layers
+    kind: Kind
+    moe: bool            # whether its blocks route to experts
+
+
+def stacks(cfg) -> list:
+    """The layer stacks, in the order the trunk runs them."""
+    if not cfg.is_mla:
+        return [Stack("layers", ("k", "v"), cfg.n_layers, MHA, cfg.is_moe)]
+    f = cfg.first_dense_layers
+    out = [Stack("dense_layers", ("ckv0",), f, LATENT, False)] if f else []
+    return out + [Stack("layers", ("ckv",), cfg.n_layers - f, LATENT,
+                        cfg.moe_d_ff > 0)]
+
+
+def _kind(cfg) -> Kind:
+    """The kind whose residual dtype and rope angles the trunk keeps."""
+    return stacks(cfg)[0].kind
+
+
+def state_kind(cfg) -> str:
+    """DecodeState's tag for this config's cache."""
+    return _kind(cfg).name + ("+experts" if cfg.is_moe else "")
+
+
+def cache_names(cfg: TransformerConfig):
+    """Keys of the cache leaves that grow with the sequence, in the dense
+    layout (L, B, M, ...); their paged pools are keyed name + "p"."""
+    return tuple(n for st in stacks(cfg) for n in st.names)
+
+
+# the paged state's counters (models/decode_state.py), grown by each paged
+# decode step of a config with held experts: sub-steps, assignments to held
+# experts, per layer and sub-step the busiest held expert's count (summed),
+# and per sub-step the cached positions that rows holding pages attend to
+COUNTERS = ("decode_steps", "expert_tokens", "expert_tokens_peak",
+            "latent_positions")
+
+
+# --------------------------------------------------------------------------
 # init
 # --------------------------------------------------------------------------
-def init_params(key, cfg: TransformerConfig):
-    if cfg.is_mla:
-        return mla.init_params(key, cfg)
+def _init_layers(keys, cfg: TransformerConfig, L: int, moe: bool):
+    """One MHA/GQA stack of L blocks, from the next 8 keys of `keys`."""
     dt = cfg.pdtype
-    hd, h, hkv = cfg.hd, cfg.n_heads, cfg.n_kv_heads
-    d, L = cfg.d_model, cfg.n_layers
-    keys = jax.random.split(key, 16)
+    hd, h, hkv, d = cfg.hd, cfg.n_heads, cfg.n_kv_heads, cfg.d_model
+    k = [next(keys) for _ in range(8)]
     s = d ** -0.5
 
     def nrm(k, shape, scale):
@@ -155,10 +218,10 @@ def init_params(key, cfg: TransformerConfig):
 
     layers = {
         "attn_norm": jnp.ones((L, d), dt),
-        "wq": nrm(keys[0], (L, d, h * hd), s),
-        "wk": nrm(keys[1], (L, d, hkv * hd), s),
-        "wv": nrm(keys[2], (L, d, hkv * hd), s),
-        "wo": nrm(keys[3], (L, h * hd, d), (h * hd) ** -0.5),
+        "wq": nrm(k[0], (L, d, h * hd), s),
+        "wk": nrm(k[1], (L, d, hkv * hd), s),
+        "wv": nrm(k[2], (L, d, hkv * hd), s),
+        "wo": nrm(k[3], (L, h * hd, d), (h * hd) ** -0.5),
     }
     if cfg.norm == "layernorm":
         layers["attn_norm_bias"] = jnp.zeros((L, d), dt)
@@ -170,44 +233,50 @@ def init_params(key, cfg: TransformerConfig):
         layers["mlp_norm"] = jnp.ones((L, d), dt)
         if cfg.norm == "layernorm":
             layers["mlp_norm_bias"] = jnp.zeros((L, d), dt)
-    if cfg.is_moe:
-        moe = init_moe_params(keys[4], d, cfg.d_ff, cfg.num_experts, dt)
-        layers["router"] = jnp.broadcast_to(moe["router"],
+    if moe:
+        mp = init_moe_params(k[4], d, cfg.d_ff, cfg.num_experts, dt)
+        layers["router"] = jnp.broadcast_to(mp["router"],
                                             (L, d, cfg.num_experts)).copy()
         for nm in ("wi_gate", "wi_up", "wo"):
-            arr = moe[nm]
+            arr = mp[nm]
             layers["moe_" + nm] = jnp.broadcast_to(
                 arr, (L,) + arr.shape).copy()
     else:
         f = cfg.d_ff
         if cfg.mlp_act == "gelu":
-            layers["wi"] = nrm(keys[5], (L, d, f), s)
+            layers["wi"] = nrm(k[5], (L, d, f), s)
             layers["bi"] = jnp.zeros((L, f), dt)
-            layers["wo_mlp"] = nrm(keys[6], (L, f, d), f ** -0.5)
+            layers["wo_mlp"] = nrm(k[6], (L, f, d), f ** -0.5)
             layers["bo"] = jnp.zeros((L, d), dt)
         else:
-            layers["wi_gate"] = nrm(keys[5], (L, d, f), s)
-            layers["wi_up"] = nrm(keys[7], (L, d, f), s)
-            layers["wo_mlp"] = nrm(keys[6], (L, f, d), f ** -0.5)
+            layers["wi_gate"] = nrm(k[5], (L, d, f), s)
+            layers["wi_up"] = nrm(k[7], (L, d, f), s)
+            layers["wo_mlp"] = nrm(k[6], (L, f, d), f ** -0.5)
+    return layers
 
-    params = {
-        "embed": nrm(keys[8], (cfg.n_codebooks, cfg.vocab_size, d), 1.0)
-        if cfg.n_codebooks > 1 else nrm(keys[8], (cfg.vocab_size, d), 1.0),
-        "layers": layers,
-        "final_norm": jnp.ones((d,), dt),
-    }
+
+def init_params(key, cfg: TransformerConfig):
+    """Each stack's weights (its kind's init), then the embedding normal(0,
+    1), ones for the final norm and, untied, a head normal(0, d^-1/2), all
+    drawing in turn from the splits of `key`."""
+    dt = cfg.pdtype
+    d, v = cfg.d_model, cfg.vocab_size
+    keys = iter(jax.random.split(key, 64))
+    params = {st.params: st.kind.init(keys, cfg, st.n, st.moe)
+              for st in stacks(cfg)}
+    lead = (cfg.n_codebooks,) if cfg.n_codebooks > 1 else ()
+    params["embed"] = jax.random.normal(next(keys), lead + (v, d), dt)
+    params["final_norm"] = jnp.ones((d,), dt)
     if cfg.norm == "layernorm":
         params["final_norm_bias"] = jnp.zeros((d,), dt)
     if not cfg.tie_embeddings:
-        params["lm_head"] = nrm(keys[9],
-                                (cfg.n_codebooks, d, cfg.vocab_size)
-                                if cfg.n_codebooks > 1
-                                else (d, cfg.vocab_size), s)
+        params["lm_head"] = jax.random.normal(
+            next(keys), lead + (d, v), dt) * d ** -0.5
     return params
 
 
 # --------------------------------------------------------------------------
-# forward
+# the MHA/GQA block
 # --------------------------------------------------------------------------
 def _norm(cfg, x, w, b=None):
     if cfg.norm == "layernorm":
@@ -244,13 +313,17 @@ _BLOCK_WSPECS = {
 }
 
 
-def _block(cfg: TransformerConfig, x, lp, cos, sin, *, q_offset=0,
-           cache=None, kv_len=None):
-    """One transformer block. cache: (k, v) of (B, M, Hkv, hd) to update,
-    or for paged decode (kp, vp, page_table, layer): the stacked pools
-    (L, P+1, ps, Hkv, hd), updated at `layer` in place."""
+def _block(cfg: TransformerConfig, x, lp, cos, sin, *, cache=None, pos=None,
+           page_table=None, layer=None):
+    """One MHA/GQA block. cache None: causal attention over x itself
+    (training forward). Else `cache` is (k, v): this layer's dense rows,
+    each (B, M, Hkv, dh), written at positions `pos` (a scalar, or per row
+    (B,)) onward; or, with `page_table`, its stack's pools, each (L, P+1,
+    ps, Hkv, dh), written and read at `layer` in place. Returns (x, new
+    cache, None)."""
     b, s, d = x.shape
     hd, h, hkv = cfg.hd, cfg.n_heads, cfg.n_kv_heads
+    q_offset = 0 if pos is None else pos
     if cfg.fsdp_hints:
         lp = {k: (shard_hint(v, _BLOCK_WSPECS[k]) if k in _BLOCK_WSPECS
                   else v) for k, v in lp.items()}
@@ -263,6 +336,7 @@ def _block(cfg: TransformerConfig, x, lp, cos, sin, *, q_offset=0,
     # norm's f32 internals — 2x wire bytes vs bf16 — but keeps the saved
     # checkpoints sequence-sharded. See EXPERIMENTS.md §Perf iteration 3.)
     with jax.named_scope("attention"):
+        kv_len = None if cache is None else pos + s
         hnb = _norm(cfg, x, lp["attn_norm"], lp.get("attn_norm_bias"))
         hnb = shard_hint(hnb, ("batch", None, None))
         q = hnb @ lp["wq"]
@@ -286,23 +360,19 @@ def _block(cfg: TransformerConfig, x, lp, cos, sin, *, q_offset=0,
         if cfg.pos_embed == "rope":
             q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
 
-        new_cache = None
-        page_table = layer = None
-        if cache is not None and len(cache) == 4:
-            # paged decode (s == 1): the stacked k/v pools (L, P+1, ps, Hkv,
-            # dh), the per-row page table and this block's layer. Each row
-            # writes its token at (layer, table[pos // ps], pos % ps), in
-            # place; rows with no mapped page there (inactive slots) land
-            # on the trash page. Active rows always write distinct pages —
-            # prefix-shared pages only cover positions < prompt_len, below
-            # any decode write.
-            kp, vp, page_table, layer = cache
+        if page_table is not None:
+            # paged decode (s == 1). Each row writes its token at (layer,
+            # table[pos // ps], pos % ps), in place; rows with no mapped
+            # page there (inactive slots) land on the trash page. Active
+            # rows always write distinct pages — prefix-shared pages only
+            # cover positions < prompt_len, below any decode write.
+            kp, vp = cache
             ps = kp.shape[2]
             pids = page_table[jnp.arange(b), q_offset // ps]
             at = (layer, pids, q_offset % ps)
             kp = kp.at[at].set(k[:, 0].astype(kp.dtype))
             vp = vp.at[at].set(v[:, 0].astype(vp.dtype))
-            k, v, new_cache = kp, vp, (kp, vp)
+            k, v, cache = kp, vp, (kp, vp)
         elif cache is not None:
             ck, cv = cache
             if jnp.ndim(q_offset) == 1:   # per-slot positions
@@ -315,7 +385,7 @@ def _block(cfg: TransformerConfig, x, lp, cos, sin, *, q_offset=0,
                     ck, k.astype(ck.dtype), q_offset, axis=1)
                 cv = jax.lax.dynamic_update_slice_in_dim(
                     cv, v.astype(cv.dtype), q_offset, axis=1)
-            k, v, new_cache = ck, cv, (ck, cv)
+            k, v, cache = ck, cv, (ck, cv)
 
         if jnp.ndim(q_offset) == 1:
             # ragged per-slot positions (continuous batching). s == 1
@@ -352,41 +422,117 @@ def _block(cfg: TransformerConfig, x, lp, cos, sin, *, q_offset=0,
                                  ("batch", "model" if cache is None
                                   else None, None))
             x = x + cfg.residual_scale * mlp_out
-    return x, new_cache
+    return x, cache, None
 
 
-def _positions_to_cos_sin(cfg, positions, b, s, dtype):
+def _angles(cfg, positions):
+    """Rope cos, sin at positions (S,) or (B, S), or the (3, B, S) t/h/w
+    positions of M-RoPE; none for sinusoidal positions (added at the
+    embedding)."""
     if cfg.pos_embed != "rope":
         return None, None
     if cfg.mrope_sections is not None:
-        if positions is None:
-            p = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-            positions = jnp.stack([p, p, p])
         return mrope_cos_sin(positions, cfg.hd, cfg.mrope_sections,
-                             cfg.rope_base, dtype)
+                             cfg.rope_base, cfg.cdtype)
+    return rope_cos_sin(positions, cfg.hd, cfg.rope_base, cfg.cdtype)
+
+
+MHA = Kind("kv", _block, _init_layers,
+           lambda cfg: (cfg.n_kv_heads, cfg.hd), _angles)
+LATENT = Kind("latent", mla.block, mla.init_stack,
+              lambda cfg: (mla.row_width(cfg),), mla.rope_angles,
+              mla.RESIDUAL)
+
+
+# --------------------------------------------------------------------------
+# the trunk
+# --------------------------------------------------------------------------
+def _embed(cfg, params, tokens, pos=0):
+    """Token embeddings (B, S, D) in the residual dtype, plus sinusoidal
+    positions from pos (a scalar, or per row (B,)) onward."""
+    with jax.named_scope("embed"):
+        if cfg.n_codebooks > 1:
+            # tokens: (B, n_q, S); sum codebook embeddings (EnCodec stub)
+            x = sum(params["embed"][q][tokens[:, q]]
+                    for q in range(cfg.n_codebooks))
+        else:
+            x = params["embed"][tokens]
+        x = (x * cfg.embed_scale).astype(_kind(cfg).residual or cfg.cdtype)
+        if cfg.pos_embed == "sinusoidal":
+            d, s = cfg.d_model, x.shape[1]
+            p = _qpos(pos, s).astype(jnp.float32)           # ([B,] s)
+            dim = jnp.arange(0, d, 2).astype(jnp.float32)
+            ang = p[..., None] / (10000.0 ** (dim / d))     # ([B,] s, d/2)
+            x = x + jnp.concatenate([jnp.sin(ang), jnp.cos(ang)],
+                                    -1).astype(x.dtype)
+        return x
+
+
+def _run_stacks(cfg, params, x, cache, pos, positions=None,
+                page_table=None):
+    """Every stack's layer scan over x (B, S, D) at positions from `pos`
+    (a scalar, or per row (B,)) onward; `positions` overrides them (the
+    t/h/w positions of M-RoPE). With no cache it is the training forward:
+    each block is rematerialised in the backward pass (cfg.remat) and the
+    residual stream stays sequence-sharded between blocks. With a dense
+    cache each layer's rows ride as the scan's xs and ys; with
+    `page_table` the carry holds the stack's pools (keys name + "p"),
+    written in place at each layer. Returns (x, new cache leaves, held
+    expert assignments, sum of the per-layer busiest expert's)."""
+    b, s = x.shape[0], x.shape[1]
     if positions is None:
-        positions = jnp.arange(s)
-    return rope_cos_sin(positions, cfg.hd, cfg.rope_base, dtype)
+        positions = _qpos(pos, s)
+        if cfg.mrope_sections is not None:               # text: t = h = w
+            p = jnp.broadcast_to(positions, (b, s))
+            positions = jnp.stack([p, p, p])
+    cos, sin = _kind(cfg).angles(cfg, positions)
+    # Megatron-style sequence parallelism: the residual stream (and thus the
+    # per-layer activation checkpoints saved by the scan) shards its SEQUENCE
+    # axis over "model". Per-token ops (norms, projections, MLP) need no
+    # communication; chunked attention gathers only k/v (GQA: 8-64x smaller
+    # than the stream).
+    sp = ("batch", "model", None)
+    if cache is None:
+        x = shard_hint(x, sp)
+    suffix = "" if page_table is None else "p"
+    new, total, peak = {}, jnp.int32(0), jnp.int32(0)
+    with jax.named_scope("layers"):
+        for st in stacks(cfg):
+            blk, names = st.kind.block, [n + suffix for n in st.names]
+            if cache is None:
+                if cfg.remat:
+                    blk = jax.checkpoint(
+                        blk, policy=jax.checkpoint_policies.nothing_saveable,
+                        static_argnums=(0,))
 
-
-def _embed(cfg, params, tokens):
-    if cfg.n_codebooks > 1:
-        # tokens: (B, n_q, S); sum codebook embeddings (EnCodec stub)
-        parts = [params["embed"][q][tokens[:, q]]
-                 for q in range(cfg.n_codebooks)]
-        x = sum(parts)
-    else:
-        x = params["embed"][tokens]
-    return (x * cfg.embed_scale).astype(cfg.cdtype)
-
-
-def _sinusoidal(cfg, s, offset=0):
-    d = cfg.d_model
-    pos = jnp.arange(offset, offset + s)[:, None].astype(jnp.float32)
-    dim = jnp.arange(0, d, 2)[None].astype(jnp.float32)
-    ang = pos / (10000.0 ** (dim / d))
-    pe = jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
-    return pe.astype(cfg.cdtype)
+                def body(x, lp, blk=blk):
+                    return shard_hint(blk(cfg, x, lp, cos, sin)[0], sp), None
+                x, counts = jax.lax.scan(body, x, params[st.params])
+            elif page_table is None:
+                def body(x, xs, blk=blk):
+                    lp, rows = xs
+                    x, rows, counts = blk(cfg, x, lp, cos, sin, cache=rows,
+                                          pos=pos)
+                    return x, (rows, counts)
+                x, (rows, counts) = jax.lax.scan(
+                    body, x, (params[st.params],
+                              tuple(cache[n] for n in names)))
+                new.update(zip(names, rows))
+            else:
+                def body(carry, xs, blk=blk):
+                    (x, pools), (lp, layer) = carry, xs
+                    x, pools, counts = blk(cfg, x, lp, cos, sin, cache=pools,
+                                           pos=pos, page_table=page_table,
+                                           layer=layer)
+                    return (x, pools), counts
+                (x, pools), counts = jax.lax.scan(
+                    body, (x, tuple(cache[n] for n in names)),
+                    (params[st.params], jnp.arange(st.n)))
+                new.update(zip(names, pools))
+            if counts is not None:
+                total += counts.sum(dtype=jnp.int32)
+                peak += counts.max(axis=1).sum(dtype=jnp.int32)
+    return x, new, total, peak
 
 
 def _unembed(cfg, params, x):
@@ -403,43 +549,23 @@ def _unembed(cfg, params, x):
     return logits * cfg.logit_scale
 
 
+def _final_norm(cfg, params, x):
+    """Final norm, its weight in the residual dtype, to the compute dtype."""
+    return _norm(cfg, x, params["final_norm"].astype(x.dtype),
+                 params.get("final_norm_bias")).astype(cfg.cdtype)
+
+
 def _hidden(params, tokens, cfg: TransformerConfig, positions=None):
-    """Common trunk: embeddings -> scan over blocks -> final norm."""
+    """embeddings -> every stack -> final norm."""
     x = _embed(cfg, params, tokens)
-    # Megatron-style sequence parallelism: the residual stream (and thus the
-    # per-layer activation checkpoints saved by the scan) shards its SEQUENCE
-    # axis over "model". Per-token ops (norms, projections, MLP) need no
-    # communication; chunked attention gathers only k/v (GQA: 8-64x smaller
-    # than the stream). Dropped automatically when S % axis != 0 (decode).
-    sp = ("batch", "model", None)
-    x = shard_hint(x, sp)
-    b, s = x.shape[0], x.shape[1]
-    if cfg.pos_embed == "sinusoidal":
-        x = x + _sinusoidal(cfg, s)[None]
-    cos, sin = _positions_to_cos_sin(cfg, positions, b, s, cfg.cdtype)
-
-    blk = _block
-    if cfg.remat:
-        blk = jax.checkpoint(
-            _block, policy=jax.checkpoint_policies.nothing_saveable,
-            static_argnums=(0,))
-
-    def body(x, lp):
-        x, _ = blk(cfg, x, lp, cos, sin)
-        return shard_hint(x, sp), None  # residual stays sequence-sharded
-
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    return _norm(cfg, x, params["final_norm"].astype(cfg.cdtype),
-                 params.get("final_norm_bias"))
+    x, _, _, _ = _run_stacks(cfg, params, x, None, 0, positions)
+    return _final_norm(cfg, params, x)
 
 
 def forward(params, tokens, cfg: TransformerConfig, positions=None):
     """tokens: (B, S) int32 — or (B, n_q, S) for multi-codebook.
     Returns logits (B, S, V) (or (B, n_q, S, V))."""
-    if cfg.is_mla:
-        return mla.forward(params, tokens, cfg, positions)
-    x = _hidden(params, tokens, cfg, positions)
-    return _unembed(cfg, params, x)
+    return _unembed(cfg, params, _hidden(params, tokens, cfg, positions))
 
 
 def loss_fn(params, batch, cfg: TransformerConfig):
@@ -448,7 +574,7 @@ def loss_fn(params, batch, cfg: TransformerConfig):
     With cfg.loss_chunk > 0 (and a single codebook) the (B, S, V) logits are
     never materialized: the xent scans the sequence in chunks."""
     labels = batch["labels"]
-    if cfg.loss_chunk and cfg.n_codebooks == 1 and not cfg.is_mla \
+    if cfg.loss_chunk and cfg.n_codebooks == 1 \
             and labels.shape[-1] % cfg.loss_chunk == 0:
         x = _hidden(params, batch["tokens"], cfg,
                     positions=batch.get("positions"))
@@ -462,154 +588,67 @@ def loss_fn(params, batch, cfg: TransformerConfig):
 
 
 # --------------------------------------------------------------------------
-# serving: prefill + decode with KV cache
+# serving: prefill + decode with a dense or paged cache
 # --------------------------------------------------------------------------
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
                dtype=None, pad_to: int = 128):
-    """KV cache in model layout (L, B, M, Hkv, dh). M is rounded up to a
-    multiple of `pad_to` HERE, once, so the decode-attention kernel (block-
+    """Dense cache: each stack's leaves (n, B, M, *row). M is rounded up to
+    a multiple of `pad_to` HERE, once, so the decode-attention kernel (block-
     strided over M) never pads or transposes the cache on the hot path;
-    positions >= kv_len are masked everywhere downstream. Latent attention
-    keeps latent rows instead (models/mla.py `init_cache`)."""
-    if cfg.is_mla:
-        return mla.init_cache(cfg, batch, max_len, dtype, pad_to)
+    positions >= kv_len are masked everywhere downstream."""
     dtype = dtype or cfg.cdtype
     m = -(-max_len // pad_to) * pad_to
-    shape = (cfg.n_layers, batch, m, cfg.n_kv_heads, cfg.hd)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
-            "pos": jnp.zeros((), jnp.int32)}
+    out = {n: jnp.zeros((st.n, batch, m) + st.kind.row(cfg), dtype)
+           for st in stacks(cfg) for n in st.names}
+    out["pos"] = jnp.zeros((), jnp.int32)
+    return out
 
 
-def _decode_embed(cfg, params, tokens, pos0):
-    """Token embeddings (plus sinusoidal positions) of a decode or prefill
-    step whose first position is pos0: a scalar or a (B,) per-slot vector
-    (continuous batching). The angles are computed directly at pos0 +
-    arange(s) rather than sliced out of a (max) table."""
-    with jax.named_scope("embed"):
-        x = _embed(cfg, params, tokens)
-        if cfg.pos_embed == "sinusoidal":
-            d, s = cfg.d_model, x.shape[1]
-            p = _qpos(pos0, s).astype(jnp.float32)
-            if p.ndim == 1:
-                p = p[None]                                 # (B|1, s)
-            dim = jnp.arange(0, d, 2).astype(jnp.float32)
-            ang = p[..., None] / (10000.0 ** (dim / d))     # (B|1, s, d/2)
-            x = x + jnp.concatenate([jnp.sin(ang), jnp.cos(ang)],
-                                    -1).astype(x.dtype)
-        return x
+def init_paged_pools(cfg: TransformerConfig, pool_pages: int, page_size: int,
+                     dtype=None):
+    """{name + "p": pool} of every cache leaf (see `cache_names`), each (n,
+    P+1, page_size, *row). The last page id (pool_pages) is the trash page
+    absorbing unmapped reads/writes — allocatable pages are
+    0..pool_pages-1."""
+    dtype = dtype or cfg.cdtype
+    return {n + "p": jnp.zeros((st.n, pool_pages + 1, page_size)
+                               + st.kind.row(cfg), dtype)
+            for st in stacks(cfg) for n in st.names}
 
 
 def decode_step(params, cache, tokens, cfg: TransformerConfig,
                 positions=None, last_idx=None):
     """One decode step: tokens (B, S_new) (S_new=1 for pure decode, >1 for
-    prefill). Returns (logits_last (B, [n_q,] V), new_cache).
+    prefill) from positions cache["pos"] (a scalar, or per row (B,)).
+    Returns (logits_last (B, [n_q,] V), new_cache).
+
+    A cache holding "ptab" (B, max_pages) is paged: one token a row, the
+    pools (keys of `init_paged_pools`) read through the table, each layer
+    writing its rows' new position in place; the same arithmetic as the
+    dense rows, so paged == dense bitwise. The `COUNTERS` a cache holds
+    grow by this step's (a row whose pages were released attends to none).
 
     `last_idx`: optional (B,) per-row index of the position whose logits to
     return (ragged bucketed prefill: rows padded to a shared bucket length
     read their logits at prompt_len - 1, not at the pad tail)."""
-    if cfg.is_mla:
-        return mla.decode_step(params, cache, tokens, cfg, positions,
-                               last_idx)
     pos0 = cache["pos"]
-    x = _decode_embed(cfg, params, tokens, pos0)
-    b, s = x.shape[0], x.shape[1]
-    if positions is None:
-        pos_ids = _qpos(pos0, s)      # per-slot vector or scalar offset
-        if cfg.mrope_sections is not None:
-            p = jnp.broadcast_to(pos_ids, (b, s))
-            positions = jnp.stack([p, p, p])
-        else:
-            positions = pos_ids
-    cos, sin = _positions_to_cos_sin(cfg, positions, b, s, cfg.cdtype)
-    kv_len = pos0 + s
-
-    def body(x, xs):
-        lp, ck, cv = xs
-        x, new_cache = _block(cfg, x, lp, cos, sin, q_offset=pos0,
-                              cache=(ck, cv), kv_len=kv_len)
-        return x, new_cache
-
-    with jax.named_scope("layers"):
-        x, (nk, nv) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
+    ptab = cache.get("ptab")
+    x = _embed(cfg, params, tokens, pos0)
+    s = x.shape[1]
+    x, new, total, peak = _run_stacks(cfg, params, x, cache, pos0,
+                                      positions, ptab)
     with jax.named_scope("head"):
-        x = _norm(cfg, x, params["final_norm"].astype(cfg.cdtype),
-                  params.get("final_norm_bias"))
-        if last_idx is not None:
-            assert cfg.n_codebooks == 1, "last_idx requires a single codebook"
-            # gather each row's last real position BEFORE the unembed so
-            # the (B, S, V) prefill logits are never materialized
-            x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
-            logits = _unembed(cfg, params, x)[:, -1]
-        elif cfg.n_codebooks > 1:
-            logits = _unembed(cfg, params, x)[:, :, -1]      # (B, n_q, V)
-        else:
-            logits = _unembed(cfg, params, x[:, -1:])[:, -1]  # (B, V)
-    return logits, {"k": nk, "v": nv, "pos": pos0 + s}
-
-
-def init_paged_pool(cfg: TransformerConfig, pool_pages: int, page_size: int,
-                    dtype=None):
-    """Paged KV pool in layout (L, P+1, page_size, Hkv, dh). The last page
-    id (pool_pages) is the trash page absorbing unmapped reads/writes —
-    allocatable pages are 0..pool_pages-1."""
-    dtype = dtype or cfg.cdtype
-    shape = (cfg.n_layers, pool_pages + 1, page_size, cfg.n_kv_heads,
-             cfg.hd)
-    return jnp.zeros(shape, dtype)
-
-
-def cache_names(cfg: TransformerConfig):
-    """Keys of the cache leaves that grow with the sequence, in the dense
-    layout (L, B, M, ...); their paged pools are keyed name + "p"."""
-    return mla.cache_names(cfg) if cfg.is_mla else ("k", "v")
-
-
-def init_paged_pools(cfg: TransformerConfig, pool_pages: int, page_size: int,
-                     dtype=None):
-    """{pool key: pool} of every paged cache leaf (see `cache_names`)."""
-    if cfg.is_mla:
-        return mla.init_paged_pools(cfg, pool_pages, page_size, dtype)
-    kp = init_paged_pool(cfg, pool_pages, page_size, dtype)
-    return {"kp": kp, "vp": jnp.zeros_like(kp)}
-
-
-def paged_decode_step(params, cache, tokens, cfg: TransformerConfig):
-    """One paged decode step: tokens (B, 1). cache carries "kp"/"vp" pools
-    (L, P+1, ps, Hkv, dh), "ptab" (B, max_pages) int32 and "pos" (B,).
-    Returns (logits (B, V), new cache). Positions/rope/sinusoidal handling
-    mirrors decode_step exactly so paged == dense bitwise. The layer scan
-    carries the stacked pools, so each layer writes its rows' new position
-    in place and the kernel reads its layer from the same buffer."""
-    if cfg.is_mla:
-        return mla.paged_decode_step(params, cache, tokens, cfg)
-    pos0 = cache["pos"]                      # (B,) per-slot positions
-    x = _decode_embed(cfg, params, tokens, pos0)
-    b, s = x.shape[0], x.shape[1]
-    assert s == 1 and cfg.n_codebooks == 1
-    pos_ids = _qpos(pos0, s)
-    if cfg.mrope_sections is not None:
-        p = jnp.broadcast_to(pos_ids, (b, s))
-        positions = jnp.stack([p, p, p])
-    else:
-        positions = pos_ids
-    cos, sin = _positions_to_cos_sin(cfg, positions, b, s, cfg.cdtype)
-    kv_len = pos0 + s
-    ptab = cache["ptab"]
-
-    def body(carry, xs):
-        x, kp, vp = carry
-        lp, layer = xs
-        x, (kp, vp) = _block(cfg, x, lp, cos, sin, q_offset=pos0,
-                             cache=(kp, vp, ptab, layer), kv_len=kv_len)
-        return (x, kp, vp), None
-
-    with jax.named_scope("layers"):
-        (x, nkp, nvp), _ = jax.lax.scan(
-            body, (x, cache["kp"], cache["vp"]),
-            (params["layers"], jnp.arange(cfg.n_layers)))
-    with jax.named_scope("head"):
-        x = _norm(cfg, x, params["final_norm"].astype(cfg.cdtype),
-                  params.get("final_norm_bias"))
-        logits = _unembed(cfg, params, x[:, -1:])[:, -1]
-    return logits, {**cache, "kp": nkp, "vp": nvp, "pos": pos0 + s}
+        # take each row's last real position BEFORE the norm and unembed
+        # so the (B, S, V) prefill logits are never materialized
+        x = (x[:, -1:] if last_idx is None else
+             jnp.take_along_axis(x, last_idx[:, None, None], axis=1))
+        logits = _unembed(cfg, params, _final_norm(cfg, params, x))
+        logits = logits[:, :, -1] if cfg.n_codebooks > 1 else logits[:, -1]
+    out = {**cache, **new, "pos": pos0 + s}
+    if COUNTERS[0] in cache:
+        trash = cache[cache_names(cfg)[0] + "p"].shape[1] - 1
+        live = jnp.where(ptab[:, 0] != trash, pos0 + s, 0)
+        for name, grow in zip(COUNTERS, (1, total, peak,
+                                         live.sum(dtype=jnp.int32))):
+            out[name] = cache[name] + grow
+    return logits, out

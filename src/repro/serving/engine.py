@@ -61,8 +61,9 @@ finished, pages returned). Counters in `stats`, per prefill call:
 `prefill_slot_tokens` (max_batch x bucket, the token rows the program
 computes), so prefill_tokens / prefill_slot_tokens is the useful share of
 prefill work. A model whose decode state keeps counters of its own
-(`DecodeStateSpec.counters`; models/mla.py's `decode_steps`,
-`expert_tokens`, `expert_tokens_peak` and `latent_positions`) has them
+(`DecodeStateSpec.counters`; models/transformer.py's `COUNTERS`:
+`decode_steps`, `expert_tokens`, `expert_tokens_peak` and
+`latent_positions`) has them
 copied into `stats` at each block's drain, fetched with the block's
 tokens. Stamps on each `Request`, on `time.perf_counter()`:
 `arrival` (the caller's, else `submit`'s), `admitted_at` (taken into a

@@ -1,6 +1,7 @@
-"""Latent attention and held-share experts (models/mla.py, models/moe.py) at
-the reduced Moonlight size on the CPU, against the plain reference
-(bench/references/mla_moe.py) on seeded random weights."""
+"""Latent attention and held-share experts (models/mla.py, models/moe.py),
+through the one trunk (models/transformer.py), at the reduced Moonlight
+size on the CPU, against the plain reference (bench/references/mla_moe.py)
+on seeded random weights."""
 import functools
 
 import jax
@@ -9,7 +10,7 @@ import numpy as np
 import pytest
 
 from bench.references import mla_moe as ref
-from repro.models import mla, moe, registry
+from repro.models import mla, moe, registry, transformer
 from repro.models.decode_state import decode_spec, paged_spec
 from repro.models.moe import held_experts_ffn, moe_ffn, sigmoid_route
 
@@ -46,7 +47,7 @@ def _ref_logits(cfg):
 @functools.cache
 def _weights(cfg, seed=3):
     w = ref.make_weights(_ref_cfg(cfg), seed)
-    want = jax.eval_shape(lambda k: mla.init_params(k, cfg),
+    want = jax.eval_shape(lambda k: transformer.init_params(k, cfg),
                           jax.random.PRNGKey(0))
     assert jax.tree.structure(w) == jax.tree.structure(want)
     assert [(a.shape, a.dtype) for a in jax.tree.leaves(w)] == \
@@ -58,7 +59,8 @@ def test_forward_matches_the_reference():
     cfg = CFG
     w = _weights(cfg)
     toks = jax.random.randint(jax.random.PRNGKey(0), (20,), 0, cfg.vocab_size)
-    got = jax.jit(mla.forward, static_argnums=2)(w, toks[None], cfg)[0]
+    got = jax.jit(transformer.forward, static_argnums=2)(w, toks[None],
+                                                      cfg)[0]
     want = _ref_logits(cfg)(w, toks)
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
 

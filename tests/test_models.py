@@ -109,7 +109,8 @@ class TestDecodeParity:
     """Step-by-step decode must equal the parallel forward pass."""
 
     @pytest.mark.parametrize("arch", ["suncatcher-lm-100m", "xlstm-350m",
-                                      "recurrentgemma-2b", "qwen2-vl-2b"])
+                                      "recurrentgemma-2b", "qwen2-vl-2b",
+                                      "moonlight-16b-a3b"])
     def test_decode_matches_forward(self, arch):
         # f32 compute: the test checks algorithmic parity of the two paths,
         # not bf16 accumulation-order noise (which also made the outcome
